@@ -638,7 +638,7 @@ def warmup_cmd(model_dir, server_url, row_sizes, shard, timeout):
 
     ``--dir``: AOT-compile every (signature, row bucket) program for the
     artifacts — run it in a kubernetes init container sharing
-    ``GORDO_COMPILE_CACHE_DIR`` with the server, and the server's own
+    ``JAX_COMPILATION_CACHE_DIR`` with the server, and the server's own
     warmup loads every program from the persistent cache in milliseconds.
     ``--url``: wait for a self-warming server to report ready.
     """

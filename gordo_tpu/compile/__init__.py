@@ -13,9 +13,10 @@ from gordo_tpu.compile.registry import (  # noqa: F401
     ClosureProgram,
     CompileRegistry,
     Program,
+    aot_fallbacks,
     cached_closure,
     closure_program,
-    install_persistent_cache_counters,
+    install_compile_listeners,
     jit,
     program,
     set_warming,
@@ -35,10 +36,11 @@ __all__ = [
     "CompileRegistry",
     "Program",
     "WARMUP_DIR",
+    "aot_fallbacks",
     "cached_closure",
     "closure_program",
     "filter_manifest",
-    "install_persistent_cache_counters",
+    "install_compile_listeners",
     "jit",
     "load_warmup_manifest",
     "program",

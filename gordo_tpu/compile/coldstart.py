@@ -17,7 +17,7 @@ serve), ``first_request_s``, ``second_request_s``, and the
 text ``/metrics`` serves), so the parent can attest compile-cache hits.
 
 Persistent-cache runs are driven by the parent via the normal env
-contract (``GORDO_COMPILE_CACHE=force`` + ``GORDO_COMPILE_CACHE_DIR``):
+contract (``GORDO_COMPILE_CACHE=force`` + ``JAX_COMPILATION_CACHE_DIR``):
 back-to-back children on one machine populate then reuse the on-disk
 cache, measuring cached-restart time-to-ready against the cold one.
 """

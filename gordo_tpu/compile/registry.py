@@ -35,11 +35,13 @@ system concern; this registry makes it one:
   :func:`set_warming`; ``/healthz`` reports ``warming`` vs ``ready`` and
   the coalescer queues new riders behind the warmup instead of letting
   each executor thread block on its own cold compile.
-- persistent-cache counters — when jax's on-disk compilation cache is
-  active (``utils/compile_cache.py``), a ``jax.monitoring`` listener maps
-  its hit/miss events onto ``gordo_compile_cache_hits_total`` /
+- jax's own compile monitoring — ``jax.monitoring`` listeners
+  (:func:`install_compile_listeners`) map the on-disk compilation cache's
+  hit/miss events onto ``gordo_compile_cache_hits_total`` /
   ``misses_total{cache="persistent"}`` so cross-process reuse (server
-  restarts, forked multi-host workers) is attestable in a scrape.
+  restarts, forked multi-host workers) is attestable in a scrape, and sum
+  every jit's trace/lower/backend seconds into
+  ``gordo_compile_jax_seconds_total{stage}``.
 
 Kill switch: ``GORDO_COMPILE_PLANE=off`` routes every :class:`Program`
 call straight through the plain jitted function (today's pre-plane
@@ -87,6 +89,18 @@ _PROGRAMS_GAUGE = telemetry.gauge(
 _WARMING_GAUGE = telemetry.gauge(
     "gordo_compile_warming",
     "1 while a startup warmup is pre-compiling serving programs",
+)
+_JAX_COMPILE_SECONDS = telemetry.counter(
+    "gordo_compile_jax_seconds_total",
+    "Seconds jax spent compiling in this process, by stage "
+    "(trace | lower | backend; backend includes persistent-cache loads)",
+    labels=("stage",),
+)
+_AOT_FALLBACKS = telemetry.counter(
+    "gordo_compile_aot_fallbacks_total",
+    "AOT lower/compile/execute failures that degraded a program to plain "
+    "jit dispatch, by program",
+    labels=("program",),
 )
 
 #: executable-cache bound: power-of-two request buckets keep distinct
@@ -223,6 +237,7 @@ class Program:
                 "compiled executable for %s failed; falling back to jit",
                 self.name,
             )
+            self._registry.note_aot_fallback(self.name)
             self._registry._drop_executable(key)
             return self._jitted(*args, **kwargs)
 
@@ -234,6 +249,7 @@ class Program:
         except Exception as exc:
             if not self._aot_broken:
                 self._aot_broken = True
+                self._registry.note_aot_fallback(self.name)
                 logger.warning(
                     "AOT compile unavailable for program %s (%s); "
                     "dispatching through jit for this process",
@@ -390,6 +406,7 @@ class ClosureProgram:
             self.warm(*args)
         except Exception as exc:
             self._aot_broken = True
+            REGISTRY.note_aot_fallback(self.name)
             logger.warning(
                 "AOT compile unavailable for closure %s (%s); "
                 "dispatching through jit",
@@ -419,6 +436,7 @@ class ClosureProgram:
                 "compiled executable for closure %s failed; "
                 "falling back to jit", self.name,
             )
+            REGISTRY.note_aot_fallback(self.name)
             with self._lock:
                 self._exes.pop(key, None)
             return self._jitted(*args)
@@ -442,6 +460,8 @@ class CompileRegistry:
         self.max_executables = max_executables
         self.max_closures = max_closures
         self._warming = False
+        #: AOT→jit degradations (lower/compile/execute failures)
+        self._aot_fallbacks = 0
 
     # -- program index -------------------------------------------------------
     def _register_program(self, program: Program) -> None:
@@ -507,6 +527,16 @@ class CompileRegistry:
             _PROGRAMS_GAUGE.set(0.0, "aot")
             _PROGRAMS_GAUGE.set(0.0, "closure")
 
+    # -- AOT→jit degradations -------------------------------------------------
+    def note_aot_fallback(self, program_name: str) -> None:
+        with self._lock:
+            self._aot_fallbacks += 1
+        _AOT_FALLBACKS.inc(1.0, program_name)
+
+    def aot_fallbacks(self) -> int:
+        with self._lock:
+            return self._aot_fallbacks
+
     # -- warming state -------------------------------------------------------
     def set_warming(self, warming: bool) -> None:
         with self._lock:
@@ -566,6 +596,15 @@ def closure_program(
     return ClosureProgram(fn, name=name, **jit_kwargs)
 
 
+def aot_fallbacks() -> int:
+    """How many times this process degraded a program from its AOT
+    executable to plain ``jit`` (lower/compile refused, or a cached
+    executable stopped matching).  Serving keeps answering either way;
+    the build summary and ``/healthz`` publish the count so the
+    degradation is visible from outside the process."""
+    return REGISTRY.aot_fallbacks()
+
+
 def warming() -> bool:
     return REGISTRY.warming()
 
@@ -575,38 +614,52 @@ def set_warming(value: bool) -> None:
 
 
 # ---------------------------------------------------------------------------
-# persistent-cache counter bridge
+# jax.monitoring bridge: persistent-cache hits/misses + compile seconds
 # ---------------------------------------------------------------------------
 
 _MONITORING_INSTALLED = False
 _PERSISTENT_EVENTS = {
-    "/jax/compilation_cache/cache_hits": ("hits", "persistent"),
-    "/jax/compilation_cache/cache_misses": ("misses", "persistent"),
+    "/jax/compilation_cache/cache_hits": _CACHE_HITS,
+    "/jax/compilation_cache/cache_misses": _CACHE_MISSES,
+}
+_COMPILE_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
 }
 
 
-def install_persistent_cache_counters() -> bool:
-    """Map jax's on-disk compilation-cache hit/miss monitoring events onto
-    the ``gordo_compile_cache_*_total{cache="persistent"}`` counters, so a
-    ``/metrics`` scrape attests cross-process compile reuse.  Idempotent;
-    returns True when the listener is installed.  Never raises — an old
-    jax without the monitoring surface just leaves the counters at 0."""
+def install_compile_listeners() -> None:
+    """Bridge jax's own compile monitoring onto the compile plane's
+    series (idempotent; the entry points call it through
+    ``enable_persistent_compile_cache``):
+
+    - on-disk compilation-cache hit/miss events →
+      ``gordo_compile_cache_*_total{cache="persistent"}``, so a scrape
+      attests cross-process compile reuse.  jax records a "miss" when it
+      WRITES an entry, so misses count programs written;
+    - trace / lower / backend-compile durations of EVERY jit in the
+      process (the fleet build programs compile through plain jit, which
+      ``gordo_compile_seconds`` never sees) →
+      ``gordo_compile_jax_seconds_total{stage}``: what a run paid to
+      compile, apart from what it paid to compute.  ``backend`` includes
+      the load time of persistent-cache hits.
+    """
     global _MONITORING_INSTALLED
     if _MONITORING_INSTALLED:
-        return True
-    try:
-        from jax import monitoring
+        return
+    from jax import monitoring
 
-        def _listener(event: str, **kw) -> None:
-            mapped = _PERSISTENT_EVENTS.get(event)
-            if mapped is None:
-                return
-            which, cache = mapped
-            (_CACHE_HITS if which == "hits" else _CACHE_MISSES).inc(1.0, cache)
+    def _on_event(event: str, **kw) -> None:
+        counter = _PERSISTENT_EVENTS.get(event)
+        if counter is not None:
+            counter.inc(1.0, "persistent")
 
-        monitoring.register_event_listener(_listener)
-        _MONITORING_INSTALLED = True
-        return True
-    except Exception as exc:
-        logger.debug("persistent-cache counters unavailable: %s", exc)
-        return False
+    def _on_duration(event: str, duration_secs: float, **kw) -> None:
+        stage = _COMPILE_STAGE_EVENTS.get(event)
+        if stage is not None:
+            _JAX_COMPILE_SECONDS.inc(duration_secs, stage)
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _MONITORING_INSTALLED = True

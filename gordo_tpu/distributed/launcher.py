@@ -1,5 +1,10 @@
 """Fork-N-local-processes launcher: the simulated multi-host slice.
 
+A CPU SIMULATION by design: every worker is pinned to ``JAX_PLATFORMS=cpu``
+with virtual devices, so nothing here competes for an accelerator.  The
+launching parent must not touch a jax backend before it forks (it only
+builds environments and ``Popen``s; keep it that way).
+
 Real deployments get one process per host from the orchestrator (the
 Indexed-Job manifest ``workflow generate --multihost N`` emits).  For
 development and the CPU dryrun, this module IS the orchestrator: it forks
@@ -55,7 +60,7 @@ def worker_env(
     is process-granular).
 
     ``compile_cache_dir``: point every worker's persistent XLA
-    compilation cache (``GORDO_COMPILE_CACHE_DIR``) at one shared path,
+    compilation cache (``JAX_COMPILATION_CACHE_DIR``) at one shared path,
     so the N forked processes compile each fleet program ONCE between
     them instead of N times — the same wiring the generated multi-host
     Indexed Job gets from its shared cache volume.
@@ -68,7 +73,7 @@ def worker_env(
     if barrier_timeout is not None:
         env[ENV_BARRIER_TIMEOUT] = str(barrier_timeout)
     if compile_cache_dir is not None:
-        env["GORDO_COMPILE_CACHE_DIR"] = compile_cache_dir
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
     env["JAX_PLATFORMS"] = "cpu"
     # replace (not append) any inherited device-count flag: each worker
     # must see exactly its own count

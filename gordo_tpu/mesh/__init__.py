@@ -24,7 +24,9 @@ from gordo_tpu.mesh.fleet import (
 )
 from gordo_tpu.mesh.placement import (
     PlacementSpec,
+    array_devices,
     data_sharding,
+    device_doc,
     model_sharding,
     place,
     replicated_sharding,
@@ -39,7 +41,9 @@ __all__ = [
     "PartitionSpec",
     "FleetMesh",
     "PlacementSpec",
+    "array_devices",
     "data_sharding",
+    "device_doc",
     "fleet_mesh",
     "global_fleet_mesh",
     "model_sharding",

@@ -17,7 +17,7 @@ keep working unchanged on the sharded path.
 
 from __future__ import annotations
 
-from typing import Any, Iterable, Optional
+from typing import Any, Dict, Iterable, Optional, Set
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -102,17 +102,32 @@ class PlacementSpec:
         return jax.tree_util.tree_map(self.leaf, host_tree)
 
 
-def _iter_sharding_devices(sharding: Any) -> Iterable[jax.Device]:
-    """Union of devices named by ``sharding`` (a Sharding or a pytree of
-    them); empty for ``None`` / non-sharding leaves."""
-    seen = set()
-    for s in jax.tree_util.tree_leaves(sharding):
-        device_set = getattr(s, "device_set", None)
-        if device_set:
-            for d in device_set:
-                if d not in seen:
-                    seen.add(d)
-                    yield d
+def array_devices(tree: Any) -> Set[jax.Device]:
+    """The devices that hold ``tree``'s jax arrays, read from the arrays
+    themselves — what was placed, not what a sharding asked for."""
+    held: Set[jax.Device] = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            held.update(leaf.devices())
+    return held
+
+
+def device_doc(held: Iterable[jax.Device] = ()) -> Dict[str, Any]:
+    """The ``device`` object every result carries (build summary,
+    ``/healthz``): ``platform`` and ``device_kind`` of the devices in
+    ``held`` — pass :func:`array_devices` of the arrays the work ran on —
+    ``count`` of the devices jax sees in this process, and ``used``, how
+    many of them ``held`` names.  With nothing held (a fully cached build)
+    platform and kind are the first visible device's and ``used`` is 0."""
+    visible = jax.devices()
+    held = set(held)
+    first = min(held, key=lambda d: d.id) if held else visible[0]
+    return {
+        "platform": first.platform,
+        "device_kind": first.device_kind,
+        "count": len(visible),
+        "used": len(held),
+    }
 
 
 def place(tree: Any, sharding: Any = None) -> Any:
@@ -121,20 +136,20 @@ def place(tree: Any, sharding: Any = None) -> Any:
     ``sharding`` may be ``None`` (default single-device placement — the
     degenerate path), one sharding broadcast over the tree, or a pytree of
     shardings matching ``tree``.  Counts one placement per call
-    (``gordo_fleet_placements_total{kind}``) and the per-device leaf
-    transfers (``gordo_mesh_device_transfers_total{device}``).
+    (``gordo_fleet_placements_total{kind}``) and, per device, the leaves
+    that landed on it (``gordo_mesh_device_transfers_total{device}``) —
+    both read from the placed arrays' own devices.
     """
     if sharding is None:
         out = jax.device_put(tree)
     else:
         out = jax.device_put(tree, sharding)
     if telemetry.enabled():
-        devices = list(_iter_sharding_devices(sharding))
-        sharded = len(devices) > 1
-        _PLACEMENTS.inc(1.0, "sharded" if sharded else "single")
-        n_leaves = len(jax.tree_util.tree_leaves(tree))
-        if not devices:
-            devices = jax.devices()[:1]
-        for d in devices:
-            _DEVICE_TRANSFERS.inc(float(n_leaves), str(d.id))
+        per_device: Dict[int, int] = {}
+        for leaf in jax.tree_util.tree_leaves(out):
+            for d in leaf.devices():
+                per_device[d.id] = per_device.get(d.id, 0) + 1
+        _PLACEMENTS.inc(1.0, "sharded" if len(per_device) > 1 else "single")
+        for device_id, n_leaves in per_device.items():
+            _DEVICE_TRANSFERS.inc(float(n_leaves), str(device_id))
     return out

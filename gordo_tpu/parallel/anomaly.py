@@ -60,6 +60,7 @@ from gordo_tpu.ops.scalers import (
 from gordo_tpu.mesh import (
     MODEL_AXIS,
     Mesh,
+    array_devices,
     model_sharding,
     pad_to_multiple,
 )
@@ -415,6 +416,9 @@ class PendingFleetBuild:
         self._detectors: Optional[List[DiffBasedAnomalyDetector]] = None
         self.fetch_seconds = 0.0
         self.assemble_seconds = 0.0
+        #: devices that held the groups' result arrays (filled by collect;
+        #: the build summary's ``device`` object reads it)
+        self.devices: set = set()
 
     def collect(self) -> List[DiffBasedAnomalyDetector]:
         """Fetch + assemble every dispatched group (blocking; an async XLA
@@ -425,6 +429,7 @@ class PendingFleetBuild:
                 [None] * self._n
             )
             for g in self._groups:
+                self.devices |= array_devices(g.out)
                 for i, det in zip(g.indices, self._builder._collect_group(g)):
                     detectors[i] = det
                 self.fetch_seconds += g.fetch_seconds

@@ -32,6 +32,7 @@ from aiohttp import web
 
 import gordo_tpu
 from gordo_tpu import artifacts, faults, serializer, telemetry
+from gordo_tpu import compile as compile_plane
 from gordo_tpu.telemetry.fleet_health import drift_top_k
 from gordo_tpu.serve import codec
 from gordo_tpu.serve import coalesce as coalesce_mod
@@ -357,6 +358,20 @@ class ModelCollection:
         telemetry.FLEET_HEALTH.load_baselines(
             {name: e.metadata for name, e in entries.items()}
         )
+
+    def device_doc(self) -> Dict[str, Any]:
+        """The ``device`` object of ``/healthz``: read from the stacked
+        serving arrays' own devices once the fleet scorer exists (warmup
+        or the first stacked dispatch builds it); ``used`` is 0 before."""
+        from gordo_tpu.mesh import array_devices, device_doc
+
+        with self._lock:
+            scorer = self._fleet_scorer
+        held: set = set()
+        if scorer is not None:
+            for bucket in scorer.buckets:
+                held |= array_devices(bucket.params)
+        return device_doc(held)
 
     @property
     def fleet_scorer(self):
@@ -1516,8 +1531,12 @@ async def healthz(request: web.Request) -> web.Response:
     doc: Dict[str, Any] = {
         "state": state,
         "gordo-server-version": gordo_tpu.__version__,
+        # programs that lost their AOT executable and now dispatch through
+        # plain jit (compile plane) — serving continues, this says so
+        "aot_fallbacks": compile_plane.aot_fallbacks(),
     }
     if collection is not None:
+        doc["device"] = collection.device_doc()
         doc["fleet-generation"] = collection.generation
         if collection.quarantined:
             doc["quarantined"] = sorted(collection.quarantined)
@@ -1819,7 +1838,6 @@ def build_app(
     app[STREAM_HUB_KEY] = stream_mod.StreamHub(collection)
 
     if warmup:
-        from gordo_tpu import compile as compile_plane
 
         async def _warmup(app: web.Application):
             # a DAEMON thread, not the loop's executor: compiles can't be
@@ -2073,6 +2091,12 @@ def run_server(
     env var, else 5; 0 disables — the coarse rescan still reloads, just
     slower and via a full restack).
     """
+    # before the first compile: loading and stacking the collection below
+    # already compiles, and what compiles before the cache is on is never
+    # written to it
+    from gordo_tpu.utils.compile_cache import enable_persistent_compile_cache
+
+    enable_persistent_compile_cache()
     if health_rollup_interval is None:
         try:
             health_rollup_interval = float(
@@ -2096,19 +2120,15 @@ def run_server(
         from gordo_tpu.mesh import FleetMesh
 
         fm = FleetMesh.resolve(mesh_devices)  # honors GORDO_MESH_DEVICES
-        if fm.is_sharded:
-            serve_mesh = fm.mesh
-            logger.info(
-                "Model-parallel serving over %d devices", fm.n_devices
+        if not fm.is_sharded:
+            raise ValueError(
+                "--model-parallel needs more than one device to shard "
+                f"over, but the mesh resolved to 1 ({fm.devices[0]}); "
+                "check device visibility and GORDO_MESH_DEVICES, or serve "
+                "without --model-parallel"
             )
-        else:
-            logger.warning(
-                "--model-parallel requested but only 1 device is visible "
-                "(%s) — serving single-device; check the TPU runtime/"
-                "device visibility (or GORDO_MESH_DEVICES) if a slice "
-                "was expected",
-                fm.devices[0].platform,
-            )
+        serve_mesh = fm.mesh
+        logger.info("Model-parallel serving over %d devices", fm.n_devices)
     # crash-safe writer audit before loading: sweep orphaned tmp files a
     # killed build left behind and re-publish a stale GENERATION sidecar;
     # unrepairable findings (truncated packs) are logged here and then

@@ -5,8 +5,7 @@ model-artifact level (``serializer.dump`` + the config-hash build cache);
 there is no mid-training checkpointing.  The TPU build keeps the artifact
 cache (it is load-bearing for fleet re-runs) and adds optional mid-fit
 checkpointing for long fits: the epoch loop is chunked, and after each
-chunk ``(params, opt_state, history, epochs_done)`` land on disk via Orbax
-(pickle fallback when Orbax is unavailable).
+chunk ``(params, opt_state, history, epochs_done)`` land on disk via Orbax.
 
 Contracts:
 
@@ -31,7 +30,6 @@ import hashlib
 import json
 import logging
 import os
-import pickle
 import shutil
 from typing import Any, Optional, Tuple
 
@@ -72,21 +70,12 @@ def fit_fingerprint(module, cfg: TrainConfig, X, y, rng: jax.Array) -> str:
 
 
 def _save_tree(path: str, tree: Any) -> None:
-    try:
-        import orbax.checkpoint as ocp
+    import orbax.checkpoint as ocp
 
-        ocp.PyTreeCheckpointer().save(os.path.abspath(path), to_host(tree))
-    except ImportError:
-        os.makedirs(path, exist_ok=True)
-        with open(os.path.join(path, "tree.pkl"), "wb") as f:
-            pickle.dump(to_host(tree), f)
+    ocp.PyTreeCheckpointer().save(os.path.abspath(path), to_host(tree))
 
 
 def _load_tree(path: str, target: Any = None) -> Any:
-    pkl = os.path.join(path, "tree.pkl")
-    if os.path.exists(pkl):
-        with open(pkl, "rb") as f:
-            return pickle.load(f)
     import orbax.checkpoint as ocp
 
     # restoring against a concrete target preserves pytree node types

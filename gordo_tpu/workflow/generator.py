@@ -314,7 +314,8 @@ def _compile_cache_volume(project: str) -> Dict:
 
 
 def _compile_cache_env() -> Dict[str, str]:
-    return {"name": "GORDO_COMPILE_CACHE_DIR", "value": COMPILE_CACHE_MOUNT}
+    # jax reads the variable itself; the program sets no directory in code
+    return {"name": "JAX_COMPILATION_CACHE_DIR", "value": COMPILE_CACHE_MOUNT}
 
 
 def _serve_dtype_env(serve_dtype: Optional[str]) -> List[Dict[str, str]]:

@@ -957,7 +957,8 @@ def lint_file(path: str) -> List[Finding]:
 
 
 def main(argv: List[str]) -> int:
-    paths = argv or ["gordo_tpu", "tests", "bench.py", "__graft_entry__.py"]
+    paths = argv or ["gordo_tpu", "tests", "bench.py", "chip_smoke.py",
+                     "__graft_entry__.py"]
     missing = [p for p in paths if not os.path.exists(p)]
     if missing:
         print(f"lint: path(s) do not exist: {missing}", file=sys.stderr)

@@ -1,6 +1,10 @@
 #!/usr/bin/env python
 """Simulated-multiprocess dryrun of the multi-host build path.
 
+A CPU SIMULATION by design: parent and workers are pinned to
+``JAX_PLATFORMS=cpu`` with virtual devices, so nothing here competes for
+an accelerator, and the parent touches no backend before it forks.
+
 Forks N real worker processes (default 2), each with its own
 ``--xla_force_host_platform_device_count`` virtual-CPU backend, wired
 into ONE ``jax.distributed`` job via the ``GORDO_*`` env contract — the

@@ -362,7 +362,7 @@ class TestRefreshCron:
         volumes = {v["name"] for v in pod["volumes"]}
         assert {"models", "project-config", "compile-cache"} <= volumes
         env = {e["name"] for e in container["env"]}
-        assert {"PROJECT_NAME", "GORDO_COMPILE_CACHE_DIR",
+        assert {"PROJECT_NAME", "JAX_COMPILATION_CACHE_DIR",
                 "GORDO_REFRESH_HYSTERESIS"} <= env
 
     def test_malformed_schedule_is_refused(self):

@@ -321,7 +321,7 @@ def test_scrape_annotations_opt_out():
 
 def test_compile_cache_volume_on_builder_and_server():
     """Builder Job and server Deployment share one per-project compile
-    cache: GORDO_COMPILE_CACHE_DIR points both at the same mounted PVC,
+    cache: JAX_COMPILATION_CACHE_DIR points both at the same mounted PVC,
     so a rescheduled server loads executables the builder (or a previous
     server) already compiled (ISSUE 5 satellite)."""
     docs = generate_workflow(_config())
@@ -335,7 +335,7 @@ def test_compile_cache_volume_on_builder_and_server():
         pod = doc["spec"]["template"]["spec"]
         container = pod["containers"][0]
         env = {e["name"]: e["value"] for e in container["env"]}
-        assert env["GORDO_COMPILE_CACHE_DIR"] == "/compile-cache"
+        assert env["JAX_COMPILATION_CACHE_DIR"] == "/compile-cache"
         mounts = {m["name"]: m for m in container["volumeMounts"]}
         assert mounts["compile-cache"]["mountPath"] == "/compile-cache"
         assert not mounts["compile-cache"].get("readOnly")
@@ -355,5 +355,5 @@ def test_multihost_workers_share_the_compile_cache_path():
         e["name"]: e["value"]
         for e in job["spec"]["template"]["spec"]["containers"][0]["env"]
     }
-    assert env["GORDO_COMPILE_CACHE_DIR"] == "/compile-cache"
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/compile-cache"
     assert env["GORDO_NUM_PROCESSES"] == "2"
