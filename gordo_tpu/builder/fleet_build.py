@@ -26,7 +26,9 @@ the high-water mark so tests can hold the bound.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
+import functools
 import hashlib
 import logging
 import math
@@ -42,6 +44,10 @@ import numpy as np
 
 from gordo_tpu import artifacts, serializer, telemetry
 from gordo_tpu.mesh import Mesh
+from gordo_tpu.builder.timeline import (
+    STAGE_SECONDS as _PIPE_STAGE_SECONDS,
+    BuildTimeline,
+)
 from gordo_tpu.builder.build_model import (
     assemble_metadata,
     build_model,
@@ -55,7 +61,7 @@ from gordo_tpu.parallel.anomaly import (
     _model_axis_pad,
     analyze_definition,
 )
-from gordo_tpu.utils import disk_registry, profiling
+from gordo_tpu.utils import disk_registry
 from gordo_tpu.workflow.config import Machine
 
 logger = logging.getLogger(__name__)
@@ -80,13 +86,9 @@ _DATA_LOAD_SECONDS = telemetry.histogram(
     "Per-machine dataset load+assembly seconds (loader pool)",
 )
 
-# -- build-pipeline instruments (docs/perf.md "Build pipeline") -------------
-_PIPE_STAGE_SECONDS = telemetry.histogram(
-    "gordo_build_pipeline_stage_seconds",
-    "Busy seconds per pipeline stage unit "
-    "(load: one machine, device: one chunk, write: one artifact)",
-    labels=("stage",),
-)
+# -- build-pipeline instruments (docs/perf.md "Build pipeline"); the stage
+#    histogram and the device-idle counter live with the chunk timeline
+#    (builder/timeline.py) ------------------------------------------------
 _PIPE_STALL_SECONDS = telemetry.counter(
     "gordo_build_pipeline_stall_seconds",
     "Seconds the pipeline drive loop stalled on a stage "
@@ -102,17 +104,6 @@ _PIPE_CHUNKS_TOTAL = telemetry.counter(
     "Fleet chunks driven to completion, by execution path",
     labels=("path",),  # pipelined | serial
 )
-_PIPE_DEVICE_IDLE_SECONDS = telemetry.counter(
-    "gordo_build_device_idle_seconds",
-    "Seconds the drive loop held NO dispatched fleet program in flight "
-    "(host-side lower bound on device idle: load/fetch/assemble/write "
-    "time the pipeline failed to hide behind device compute)",
-)
-_PIPE_DEVICE_INFLIGHT = telemetry.gauge(
-    "gordo_build_device_inflight",
-    "Fleet chunk programs dispatched but not yet collected",
-)
-
 
 # -- incremental refresh knobs (docs/configuration.md) ----------------------
 #: fraction of the configured epochs a warm-start rebuild trains for —
@@ -231,6 +222,7 @@ class _ArtifactWriter:
     def __init__(
         self,
         write_fn: Callable[..., None],
+        timeline: BuildTimeline,
         max_workers: int = 1,
         max_queued: int = 512,
     ):
@@ -238,6 +230,7 @@ class _ArtifactWriter:
         # writer threads buy no parallelism and cost switch churn on
         # small hosts (the bench container is 1-core)
         self._write_fn = write_fn
+        self._timeline = timeline
         self._pool = ThreadPoolExecutor(
             max_workers=max_workers, thread_name_prefix="gordo-artifact-writer"
         )
@@ -246,10 +239,11 @@ class _ArtifactWriter:
         self._depth = 0
         self._futures: List[Any] = []
 
-    def submit(self, items: Sequence[Tuple]) -> None:
-        """Queue one chunk's artifact writes as a single pool task (one
-        handoff per chunk, not per machine).  Blocks for queue slots —
-        one per artifact — when the writer is ``max_queued`` behind."""
+    def submit(self, items: Sequence[Tuple], chunk: int) -> None:
+        """Queue chunk number ``chunk``'s artifact writes as a single pool
+        task (one handoff per chunk, not per machine).  Blocks for queue
+        slots — one per artifact — when the writer is ``max_queued``
+        behind."""
         t0 = time.time()
         for _ in items:
             self._slots.acquire()
@@ -259,19 +253,22 @@ class _ArtifactWriter:
         with self._lock:
             self._depth += len(items)
             _PIPE_WRITER_QUEUE_DEPTH.set(float(self._depth))
-        self._futures.append(self._pool.submit(self._run, list(items)))
+        # in a copy of this context: the write spans keep the build's
+        # trace id and parent span on the pool's thread
+        self._futures.append(self._pool.submit(
+            contextvars.copy_context().run, self._run, list(items), chunk
+        ))
 
-    def _run(self, items: List[Tuple]) -> None:
+    def _run(self, items: List[Tuple], chunk: int) -> None:
         for args in items:
-            t0 = time.time()
             try:
-                self._write_fn(*args)
+                with self._timeline.phase("write", chunk):
+                    self._write_fn(*args)
             finally:
                 self._slots.release()
                 with self._lock:
                     self._depth -= 1
                     _PIPE_WRITER_QUEUE_DEPTH.set(float(self._depth))
-                _PIPE_STAGE_SECONDS.observe(time.time() - t0, "write")
 
     def drain(self) -> None:
         """Block until every queued write has completed, then shut the
@@ -383,10 +380,15 @@ class ProjectBuildResult:
         #: whether the pipelined drive loop ran (False: serial path via
         #: the GORDO_BUILD_PIPELINE=off kill switch or pipeline=False)
         self.pipelined: bool = False
-        #: seconds the drive loop held no dispatched fleet program in
-        #: flight (see ``_DeviceOccupancy``) — the pipeline's
-        #: dispatch-overlap headroom, measurable even on CPU
+        #: seconds between one fleet program's end and the next one's
+        #: start, by the host's stamps (``timeline.DeviceOccupancy``; the
+        #: first gap runs from build start).  0.0 with telemetry off: no
+        #: watcher thread stamps the programs' ends then
         self.device_idle_seconds: float = 0.0
+        #: one dict per fleet chunk — every phase's intervals, programs,
+        #: device gaps and their split (``builder/timeline.py``); also
+        #: written to ``.gordo-telemetry/timeline-*.json``
+        self.timeline: List[Dict[str, Any]] = []
         #: artifact format this build wrote ("v1" per-machine dirs, "v2"
         #: memory-mapped bucket packs — see gordo_tpu/artifacts/)
         self.artifact_format: str = "v1"
@@ -467,39 +469,6 @@ class _LoadTracker:
             self.current -= n
 
 
-class _DeviceOccupancy:
-    """Tracks dispatched-but-uncollected chunk programs on the drive
-    thread and accumulates the windows where NO program was in flight —
-    the ``gordo_build_device_idle_seconds`` series.
-
-    This is a host-side LOWER bound on true device idle (the device may
-    also starve while a dispatched program's inputs stream — only device
-    profiling sees that), but it is exactly the quantity the
-    dispatch/collect split exists to shrink: serial drives count every
-    between-chunk fetch/assemble/write gap as idle; the pipelined drive
-    should count little beyond the first chunk's load."""
-
-    def __init__(self):
-        self._inflight = 0
-        self._idle_since: Optional[float] = time.time()
-        self.idle_seconds = 0.0
-
-    def dispatched(self) -> None:
-        if self._inflight == 0 and self._idle_since is not None:
-            dt = time.time() - self._idle_since
-            self.idle_seconds += dt
-            _PIPE_DEVICE_IDLE_SECONDS.inc(dt)
-            self._idle_since = None
-        self._inflight += 1
-        _PIPE_DEVICE_INFLIGHT.set(float(self._inflight))
-
-    def collected(self) -> None:
-        self._inflight -= 1
-        if self._inflight == 0:
-            self._idle_since = time.time()
-        _PIPE_DEVICE_INFLIGHT.set(float(self._inflight))
-
-
 @dataclasses.dataclass
 class _PendingChunk:
     """One chunk between its dispatch and its collect: the in-flight
@@ -510,6 +479,7 @@ class _PendingChunk:
     gate must read results before deciding on in-chunk cold rebuilds), so
     they arrive with ``detectors`` already set and ``pending`` None."""
 
+    index: int
     key: Tuple
     ok_chunk: List[Machine]
     loaded: Dict[str, Tuple]
@@ -560,6 +530,27 @@ def _config_widths(dataset_cfg: Dict[str, Any]) -> Optional[Tuple[int, int]]:
     return len(tags), len(targets)
 
 
+def _traced_build(fn):
+    """Run a build under one trace id (the caller's, else a new one) and
+    one root span, ``gordo.build.project``: every phase span of the build,
+    on whichever thread, has it as its parent."""
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        minted = telemetry.current_trace_id() is None
+        if minted:
+            telemetry.set_trace_id(telemetry.new_trace_id())
+        try:
+            with telemetry.span("gordo.build.project"):
+                return fn(*args, **kwargs)
+        finally:
+            if minted:
+                telemetry.set_trace_id(None)
+
+    return traced
+
+
+@_traced_build
 def build_project(
     machines: Sequence[Union[Machine, Dict[str, Any]]],
     output_dir: str,
@@ -719,7 +710,7 @@ def build_project(
     result.loader_workers = int(data_workers)
     result.ingest = {"enabled": use_ingest} if use_ingest else None
     tracker = _LoadTracker()
-    occupancy = _DeviceOccupancy()
+    timeline = BuildTimeline(t_start)
     warm_resolved: Dict[str, Tuple[Any, Optional[float]]] = {}
     #: per-machine warm-start attestation, stamped into artifact metadata
     warm_info_by_name: Dict[str, Dict[str, Any]] = {}
@@ -886,44 +877,52 @@ def build_project(
         for start in range(0, len(bucket), size):
             chunks.append((key, bucket[start : start + size]))
 
-    def _load(m: Machine):
-        t0 = time.time()
-        dataset = GordoBaseDataset.from_dict(dict(m.dataset))
-        X, y = dataset.get_data()
-        X = np.asarray(X, np.float32)
-        y = np.asarray(y, np.float32)
-        if align_lengths and len(X) >= align_lengths:  # validated >= 2
-            keep = (len(X) // align_lengths) * align_lengths
-            # newest rows win: industrial sensor history is trained most-
-            # recent-first relevant, so the truncation drops the head
-            X, y = X[len(X) - keep:], y[len(y) - keep:]
-        _DATA_LOAD_SECONDS.observe(time.time() - t0)
-        _PIPE_STAGE_SECONDS.observe(time.time() - t0, "load")
-        entry = (X, y, dataset.get_metadata(), time.time() - t0)
+    def _load(i: int, m: Machine):
+        t0 = time.time()  # query seconds are artifact metadata, not telemetry
+        with timeline.phase("load", i):
+            dataset = GordoBaseDataset.from_dict(dict(m.dataset))
+            X, y = dataset.get_data()
+            X = np.asarray(X, np.float32)
+            y = np.asarray(y, np.float32)
+            if align_lengths and len(X) >= align_lengths:  # validated >= 2
+                keep = (len(X) // align_lengths) * align_lengths
+                # newest rows win: industrial sensor history is trained
+                # most-recent-first relevant, so the truncation drops the
+                # head
+                X, y = X[len(X) - keep:], y[len(y) - keep:]
+        query_seconds = time.time() - t0
+        _DATA_LOAD_SECONDS.observe(query_seconds)
+        entry = (X, y, dataset.get_metadata(), query_seconds)
         tracker.acquire()  # arrays are live from here until freed
         return entry
 
-    def _load_chunk_ingest(chunk: List[Machine]) -> Dict[str, Any]:
+    def _load_chunk_ingest(i: int, chunk: List[Machine]) -> Dict[str, Any]:
         """One loader-pool task per CHUNK: the build-ingest plane's
         fingerprint-deduped, fleet-vectorized assembly
         (gordo_tpu/ingest/plane.py).  The capacity callable hands the
         dispatch plane's model-axis padding down so the stacked buffer
         the plane fills IS the ``(m_pad, n, tags)`` array the fleet
         program stages — no re-stack, no pad copy."""
-        t0 = time.time()
-        entries = ingest_plane.load_chunk(
-            chunk,
-            align_lengths=align_lengths,
-            capacity=(lambda mm: _model_axis_pad(mm, mesh)),
-            stats=result.ingest,
-        )
-        _PIPE_STAGE_SECONDS.observe(time.time() - t0, "load")
-        return entries
+        with timeline.phase("load", i, machines=len(chunk)):
+            return ingest_plane.load_chunk(
+                chunk,
+                align_lengths=align_lengths,
+                capacity=(lambda mm: _model_axis_pad(mm, mesh)),
+                stats=result.ingest,
+            )
 
-    def _submit(pool, chunk: List[Machine]):
+    def _submit(pool, i: int):
+        """Chunk ``i``'s load on the loader pool, each task in a copy of
+        this context so its span keeps the build's trace id and parent."""
+        chunk = chunks[i][1]
         if use_ingest:
-            return pool.submit(_load_chunk_ingest, chunk)
-        return {m.name: pool.submit(_load, m) for m in chunk}
+            return pool.submit(
+                contextvars.copy_context().run, _load_chunk_ingest, i, chunk
+            )
+        return {
+            m.name: pool.submit(contextvars.copy_context().run, _load, i, m)
+            for m in chunk
+        }
 
     def _collect(chunk: List[Machine], futures) -> Dict[str, Tuple]:
         loaded: Dict[str, Tuple] = {}
@@ -1002,34 +1001,42 @@ def build_project(
         warm_info_by_name[name] = {"warm": False, "fallback": reason}
         logger.warning("warm-start fallback for %s: %s", name, reason)
 
-    def _train_chunk(spec_obj, cv, ok_chunk, loaded, warm_list=None):
+    def _dispatch_chunk(i, spec_obj, cv, ok_chunk, loaded, warm_list=None):
+        """Launch chunk ``i``'s fleet program(s) and return the pending
+        handle without blocking.  With telemetry on the builder gets the
+        chunk's clock, so its phase spans land on the chunk's timeline
+        row and each program's end is stamped by a watcher thread; off,
+        no thread starts.  Part of the lint-enforced D2H-free dispatch
+        window."""
         builder = FleetDiffBuilder(
-            spec_obj, cv=cv, mesh=mesh, pad_lengths=pad_lengths
+            spec_obj, cv=cv, mesh=mesh, pad_lengths=pad_lengths,
+            clock=timeline.clock(i) if telemetry.enabled() else None,
         )
-        with profiling.trace(f"fleet_bucket/{len(ok_chunk)}"):
-            pending = builder.dispatch(
-                [loaded[m.name][0] for m in ok_chunk],
-                [loaded[m.name][1] for m in ok_chunk],
-                warm_params=warm_list,
-            )
+        return builder.dispatch(
+            [loaded[m.name][0] for m in ok_chunk],
+            [loaded[m.name][1] for m in ok_chunk],
+            warm_params=warm_list,
+        )
+
+    def _collect_chunk(i, pending):
+        """Blocking half: fetch + assemble chunk ``i``'s dispatched
+        programs.  They have ended whether the collect returns or raises,
+        so their ready stamps are in when this is left."""
+        try:
             detectors = pending.collect()
+        finally:
+            if not pending.settle():
+                logger.warning("chunk %d: a program's ready stamp is "
+                               "missing from the timeline", i)
         result.devices |= pending.devices
         return detectors
 
-    def _dispatch_chunk(spec_obj, cv, ok_chunk, loaded):
-        """Async half of _train_chunk (cold builds): launch the chunk's
-        fleet program(s), return the pending handle without blocking.
-        Part of the lint-enforced D2H-free dispatch window."""
-        builder = FleetDiffBuilder(
-            spec_obj, cv=cv, mesh=mesh, pad_lengths=pad_lengths
+    def _train_chunk(i, spec_obj, cv, ok_chunk, loaded, warm_list=None):
+        return _collect_chunk(
+            i, _dispatch_chunk(i, spec_obj, cv, ok_chunk, loaded, warm_list)
         )
-        with profiling.trace(f"fleet_dispatch/{len(ok_chunk)}"):
-            return builder.dispatch(
-                [loaded[m.name][0] for m in ok_chunk],
-                [loaded[m.name][1] for m in ok_chunk],
-            )
 
-    def _build_chunk_warm(spec, cv, ok_chunk, loaded):
+    def _build_chunk_warm(i, spec, cv, ok_chunk, loaded):
         """One chunk in warm_start mode: machines with resolved previous
         params run the warm program under a reduced-epoch config, the
         parity gate demotes stragglers, and everything else (plus gate
@@ -1050,7 +1057,7 @@ def build_project(
             warm_spec = dataclasses.replace(spec, train_cfg=warm_cfg)
             try:
                 warm_dets = _train_chunk(
-                    warm_spec, cv, warm_ms, loaded,
+                    i, warm_spec, cv, warm_ms, loaded,
                     warm_list=[warm_resolved[m.name][0] for m in warm_ms],
                 )
             except Exception:
@@ -1092,7 +1099,7 @@ def build_project(
                     cold_names.add(m.name)
         cold_ms = [m for m in ok_chunk if m.name in cold_names]
         if cold_ms:
-            for m, det in zip(cold_ms, _train_chunk(spec, cv, cold_ms,
+            for m, det in zip(cold_ms, _train_chunk(i, spec, cv, cold_ms,
                                                     loaded)):
                 dets[m.name] = det
         return [dets[m.name] for m in ok_chunk]
@@ -1117,7 +1124,7 @@ def build_project(
         _free(loaded, [m.name for m in ok_chunk])
 
     def _dispatch_bucket(
-        key: Tuple, chunk: List[Machine], loaded: Dict[str, Tuple]
+        i: int, key: Tuple, chunk: List[Machine], loaded: Dict[str, Tuple]
     ) -> Optional[_PendingChunk]:
         """Width-validate + DISPATCH one chunk's fleet program(s); returns
         a pending record (or None when every machine demoted).  Cold
@@ -1153,29 +1160,27 @@ def build_project(
         cv = ok_chunk[0].evaluation.get("cv")
         t0 = time.time()
         if warm_start:
-            occupancy.dispatched()
             try:
-                detectors = _build_chunk_warm(spec, cv, ok_chunk, loaded)
+                detectors = _build_chunk_warm(i, spec, cv, ok_chunk, loaded)
             except Exception as exc:
                 _demote_chunk(ok_chunk, loaded, "warm build", exc)
                 return None
-            finally:
-                occupancy.collected()
             return _PendingChunk(
-                key=key, ok_chunk=ok_chunk, loaded=loaded, t0=t0,
+                index=i, key=key, ok_chunk=ok_chunk, loaded=loaded, t0=t0,
                 detectors=detectors,
             )
         try:
-            pending = _dispatch_chunk(spec, cv, ok_chunk, loaded)
+            pending = _dispatch_chunk(i, spec, cv, ok_chunk, loaded)
         except Exception as exc:
             # host-side failure (trace/compile/stacking) — async XLA
             # failures surface at collect and demote in _finish_bucket
             _demote_chunk(ok_chunk, loaded, "dispatch", exc)
             return None
-        occupancy.dispatched()
+        # width checks, group context, staging and enqueue together: not
+        # one span's phase, and the start of the chunk's fleet seconds
         _PIPE_STAGE_SECONDS.observe(time.time() - t0, "dispatch")
         return _PendingChunk(
-            key=key, ok_chunk=ok_chunk, loaded=loaded, t0=t0,
+            index=i, key=key, ok_chunk=ok_chunk, loaded=loaded, t0=t0,
             pending=pending,
         )
 
@@ -1187,20 +1192,21 @@ def build_project(
         fleet_seconds)`` or None."""
         ok_chunk, loaded = rec.ok_chunk, rec.loaded
         detectors = rec.detectors
-        if rec.pending is not None:
-            try:
-                with profiling.trace(f"fleet_collect/{len(ok_chunk)}"):
-                    detectors = rec.pending.collect()
-            except Exception as exc:
-                _demote_chunk(ok_chunk, loaded, "collect", exc)
-                return None
-            finally:
-                occupancy.collected()
-            result.devices |= rec.pending.devices
-            _PIPE_STAGE_SECONDS.observe(rec.pending.fetch_seconds, "fetch")
-            _PIPE_STAGE_SECONDS.observe(
-                rec.pending.assemble_seconds, "assemble"
+        try:
+            if rec.pending is not None:
+                detectors = _collect_chunk(rec.index, rec.pending)
+        except Exception as exc:
+            _demote_chunk(ok_chunk, loaded, "collect", exc)
+            return None
+        finally:
+            # once per chunk, whatever its groups and however it ended:
+            # the per-group phases summed, and the device side of its row
+            timeline.observe(
+                rec.index, "stage", "enqueue", "fetch", "assemble"
             )
+            timeline.chunk_collected(rec.index)
+        # dispatch to collected: the chunk's fleet seconds, which artifact
+        # metadata records; on the chip it spans the next program too
         fleet_seconds = time.time() - rec.t0
         _BUILD_BUCKET_SECONDS.observe(fleet_seconds)
         _PIPE_STAGE_SECONDS.observe(fleet_seconds, "device")
@@ -1215,7 +1221,15 @@ def build_project(
         if out is None:
             return
         ok_chunk, detectors, fleet_seconds = out
-        loaded = rec.loaded
+        with timeline.phase("handoff", rec.index):
+            _hand_off(rec, ok_chunk, detectors, fleet_seconds, writer)
+
+    def _hand_off(rec: _PendingChunk, ok_chunk, detectors, fleet_seconds,
+                  writer: Optional[_ArtifactWriter]) -> None:
+        """What follows a chunk's collect on the drive thread: manifest
+        row, fleet-health baselines, metadata, and the writes handed to
+        the writer pool (or done inline on the serial drive)."""
+        key, loaded = rec.key, rec.loaded
         _record_manifest(key, ok_chunk)
         _PIPE_CHUNKS_TOTAL.inc(1.0, "pipelined" if writer else "serial")
         if artifact_fmt == "v2":
@@ -1224,7 +1238,7 @@ def build_project(
             if writer is not None:
                 # v2: the chunk IS the write unit — one pack per chunk
                 # rides the writer queue as a single item
-                writer.submit([payload])
+                writer.submit([payload], rec.index)
             else:
                 _write_chunk(*payload)
             return
@@ -1275,21 +1289,22 @@ def build_project(
             batch.append(
                 (m.name, det, metadata, per_machine, chunk_definition)
             )
-        writer.submit(batch)  # one handoff per chunk
+        writer.submit(batch, rec.index)  # one handoff per chunk
 
     def _drive_serial(pool) -> None:
         """The pre-pipeline drive loop (GORDO_BUILD_PIPELINE=off): loads
         still prefetch one chunk ahead, but dispatch and collect run back
         to back (no overlap) and artifact dumps run inline on the
         critical path after each chunk trains."""
-        next_futures = _submit(pool, chunks[0][1]) if chunks else None
+        next_futures = _submit(pool, 0) if chunks else None
         for i, (key, chunk) in enumerate(chunks):
-            loaded = _collect(chunk, next_futures)
+            with timeline.phase("load_wait", i):
+                loaded = _collect(chunk, next_futures)
             # prefetch the NEXT chunk now — it loads while this one trains
             next_futures = (
-                _submit(pool, chunks[i + 1][1]) if i + 1 < len(chunks) else None
+                _submit(pool, i + 1) if i + 1 < len(chunks) else None
             )
-            rec = _dispatch_bucket(key, chunk, loaded)
+            rec = _dispatch_bucket(i, key, chunk, loaded)
             if rec is not None:
                 _finish_chunk(rec, None)
 
@@ -1313,18 +1328,18 @@ def build_project(
         ``_finish_bucket`` via ``PendingFleetBuild.collect``."""
         if not chunks:
             return
-        futures = _submit(pool, chunks[0][1])
+        futures = _submit(pool, 0)
         prev: Optional[_PendingChunk] = None
         for i, (key, chunk) in enumerate(chunks):
-            t_wait = time.time()
-            loaded = _collect(chunk, futures)
-            _PIPE_STALL_SECONDS.inc(time.time() - t_wait, "load")
-            rec = _dispatch_bucket(key, chunk, loaded)
+            with timeline.phase("load_wait", i) as waited:
+                loaded = _collect(chunk, futures)
+            _PIPE_STALL_SECONDS.inc(waited.get("seconds", 0.0), "load")
+            rec = _dispatch_bucket(i, key, chunk, loaded)
             if prev is not None:
                 _finish_chunk(prev, writer)  # overlaps chunk i's compute
             prev = rec
             futures = (
-                _submit(pool, chunks[i + 1][1]) if i + 1 < len(chunks) else None
+                _submit(pool, i + 1) if i + 1 < len(chunks) else None
             )
         if prev is not None:
             _finish_chunk(prev, writer)
@@ -1483,7 +1498,8 @@ def build_project(
     with ThreadPoolExecutor(max_workers=data_workers) as pool:
         if use_pipeline:
             writer = _ArtifactWriter(
-                _write_chunk if artifact_fmt == "v2" else _write_one
+                _write_chunk if artifact_fmt == "v2" else _write_one,
+                timeline,
             )
             try:
                 _drive_pipeline(pool, writer)
@@ -1569,8 +1585,9 @@ def build_project(
             shard_state.finish()
     result.seconds = time.time() - t_start
     result.peak_loaded = tracker.peak
-    result.device_idle_seconds = occupancy.idle_seconds
-    _write_telemetry_snapshot(output_dir, result.shard)
+    result.device_idle_seconds = timeline.occupancy.idle_seconds
+    result.timeline = timeline.rows()
+    _write_telemetry_snapshot(output_dir, result.shard, timeline)
     try:
         # the (signature, bucket) set this build materialized — what the
         # server (or `gordo warmup`) pre-compiles before going ready.  A
@@ -1603,22 +1620,24 @@ def build_project(
 
 
 def _write_telemetry_snapshot(
-    output_dir: str, shard: Optional[Tuple[int, int]]
+    output_dir: str, shard: Optional[Tuple[int, int]],
+    timeline: BuildTimeline,
 ) -> None:
     """Shard-local metric snapshot under ``<output_dir>/.gordo-telemetry/``
     — one file per process of a (multi-host) build, merged later by
-    ``gordo telemetry dump --dir`` / watchman.  Process-id-keyed filenames
-    mean a re-run of the same shard overwrites its own snapshot and never
-    a peer's."""
+    ``gordo telemetry dump --dir`` / watchman — and beside it the build's
+    chunk timeline (``timeline-*.json``, which the merge passes over).
+    Process-id-keyed filenames mean a re-run of the same shard overwrites
+    its own files and never a peer's."""
     if not telemetry.enabled():
         return
     pid, n = shard or (0, 1)
-    path = os.path.join(
-        output_dir, telemetry.SNAPSHOT_DIR,
-        f"shard-{pid:03d}-of-{n:03d}.json",
-    )
+    directory = os.path.join(output_dir, telemetry.SNAPSHOT_DIR)
+    path = os.path.join(directory, f"shard-{pid:03d}-of-{n:03d}.json")
     try:
         telemetry.REGISTRY.write_snapshot(path)
+        path = os.path.join(directory, f"timeline-{pid:03d}-of-{n:03d}.json")
+        timeline.write(path)
     except Exception:  # telemetry must never fail a build
         logger.exception("telemetry snapshot write failed: %s", path)
 

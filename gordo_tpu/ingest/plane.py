@@ -37,12 +37,13 @@ fallback — the hot path must stay columnar numpy.
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import logging
 import os
 import time
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 import pandas as pd
@@ -92,6 +93,22 @@ _STAGE_SECONDS = telemetry.histogram(
     "fallback: one per-machine get_data)",
     labels=("stage",),
 )
+
+
+#: the stages are spans inside the builder's ``gordo.build.load`` span of
+#: the chunk: that one opens before a profiler session that starts at a
+#: chunk completion (the benchmark's traced window) and is then never
+#: recorded, these begin inside it
+SPAN_PREFIX = "gordo.build.load."
+
+
+@contextlib.contextmanager
+def _stage(name: str) -> Iterator[Dict[str, Any]]:
+    """One unit of an ingest stage: the span ``gordo.build.load.<name>``
+    and its seconds in ``gordo_ingest_stage_seconds{stage=name}``."""
+    with telemetry.span(SPAN_PREFIX + name) as sp:
+        yield sp
+    _STAGE_SECONDS.observe(sp.get("seconds", 0.0), name)
 
 
 def resolve_enabled(flag: Optional[bool] = None) -> bool:
@@ -150,16 +167,16 @@ def _load_fallback(dataset, align_lengths: Optional[int]):
     """Per-machine ``get_data()`` — the same work the pre-ingest builder
     did per machine, kept as the escape hatch for everything the
     columnar pass cannot express (byte-identical output either way)."""
-    t0 = time.time()
-    X, y = dataset.get_data()
-    X = np.asarray(X, np.float32)
-    y = np.asarray(y, np.float32)
-    if align_lengths and len(X) >= align_lengths:
-        keep = (len(X) // align_lengths) * align_lengths
-        # newest rows win (mirrors the builder's truncation)
-        X, y = X[len(X) - keep:], y[len(y) - keep:]
+    t0 = time.time()  # load seconds are artifact metadata, not telemetry
+    with _stage("fallback"):
+        X, y = dataset.get_data()
+        X = np.asarray(X, np.float32)
+        y = np.asarray(y, np.float32)
+        if align_lengths and len(X) >= align_lengths:
+            keep = (len(X) // align_lengths) * align_lengths
+            # newest rows win (mirrors the builder's truncation)
+            X, y = X[len(X) - keep:], y[len(y) - keep:]
     dt = time.time() - t0
-    _STAGE_SECONDS.observe(dt, "fallback")
     _MACHINES_TOTAL.inc(1.0, "fallback")
     return (X, y, dataset.get_metadata(), dt)
 
@@ -221,33 +238,32 @@ def _fetch_group(g: _FpGroup) -> bool:
     False (no exception) when the fetched shape disqualifies the
     vectorized path — the caller reroutes the group to the fallback."""
     ds = g.dataset
-    t0 = time.time()
-    tags = ds.tag_list  # targets == inputs (checked by _vectorizable)
-    fetched = ds.data_provider.load_arrays(
-        ds.train_start_date, ds.train_end_date, tags
-    )
-    if fetched is None:
-        series_list = list(
-            ds.data_provider.load_series(
-                ds.train_start_date, ds.train_end_date, tags
-            )
+    with _stage("fetch"):
+        tags = ds.tag_list  # targets == inputs (checked by _vectorizable)
+        fetched = ds.data_provider.load_arrays(
+            ds.train_start_date, ds.train_end_date, tags
         )
-        if len(series_list) != len(tags) or not all(
-            len(s) and (
-                s.index is series_list[0].index
-                or s.index.equals(series_list[0].index)
+        if fetched is None:
+            series_list = list(
+                ds.data_provider.load_series(
+                    ds.train_start_date, ds.train_end_date, tags
+                )
             )
-            for s in series_list
-        ):
-            return False
-        index = series_list[0].index
-        values = np.column_stack(
-            [s.to_numpy(dtype=np.float64, copy=False) for s in series_list]
-        )
-    else:
-        index, values = fetched
-    _FETCH_TOTAL.inc(1.0, "fetched")
-    _STAGE_SECONDS.observe(time.time() - t0, "fetch")
+            if len(series_list) != len(tags) or not all(
+                len(s) and (
+                    s.index is series_list[0].index
+                    or s.index.equals(series_list[0].index)
+                )
+                for s in series_list
+            ):
+                return False
+            index = series_list[0].index
+            values = np.column_stack(
+                [s.to_numpy(dtype=np.float64, copy=False) for s in series_list]
+            )
+        else:
+            index, values = fetched
+        _FETCH_TOTAL.inc(1.0, "fetched")
     if (
         len(index) == 0
         or str(index.tz) != "UTC"
@@ -275,43 +291,42 @@ def _assemble_geometry_group(
     per-fingerprint join mask + threshold, stacked-buffer fill, stats and
     metadata — no per-machine pandas anywhere."""
     starts, grid_size, scatter, _label = prep
-    t0 = time.time()
-    if len(groups) == 1:
-        V = groups[0].values
-    else:
-        V = np.concatenate([g.values for g in groups], axis=1)
-    col = 0
-    for g in groups:
-        g.col0 = col
-        col += g.values.shape[1]
-    # the machine-axis extension of _resample_one_arrays: one reduceat
-    # over every tag of every machine in the group (bit-identical per
-    # column — reduction order along axis 0 is the per-tag order)
-    nan_mask = np.isnan(V)
-    had_nan = bool(nan_mask.any())
-    if had_nan:
-        sums = np.add.reduceat(np.where(nan_mask, 0.0, V), starts, axis=0)
-        valid = np.add.reduceat((~nan_mask).astype(np.int64), starts, axis=0)
-        means = np.divide(
-            sums, valid, out=np.full(sums.shape, np.nan), where=valid > 0
-        )
-    else:
-        # NaN-free input: the where-copy and the int64 count pass drop
-        # out; sums/counts divides the identical float64 operands, so
-        # the quotient bits match the masked-divide branch exactly
-        sums = np.add.reduceat(V, starts, axis=0)
-        counts = np.diff(np.append(starts, V.shape[0]))
-        means = sums / counts[:, None]
-    if len(starts) == grid_size:
-        # occupied bins are strictly increasing, so covering every bin
-        # means scatter is the identity — the grid IS the means matrix
-        grid = means
-        clean = not had_nan
-    else:
-        grid = np.full((grid_size, col), np.nan)
-        grid[scatter] = means
-        clean = False
-    _STAGE_SECONDS.observe(time.time() - t0, "resample")
+    with _stage("resample"):
+        if len(groups) == 1:
+            V = groups[0].values
+        else:
+            V = np.concatenate([g.values for g in groups], axis=1)
+        col = 0
+        for g in groups:
+            g.col0 = col
+            col += g.values.shape[1]
+        # the machine-axis extension of _resample_one_arrays: one reduceat
+        # over every tag of every machine in the group (bit-identical per
+        # column — reduction order along axis 0 is the per-tag order)
+        nan_mask = np.isnan(V)
+        had_nan = bool(nan_mask.any())
+        if had_nan:
+            sums = np.add.reduceat(np.where(nan_mask, 0.0, V), starts, axis=0)
+            valid = np.add.reduceat((~nan_mask).astype(np.int64), starts, axis=0)
+            means = np.divide(
+                sums, valid, out=np.full(sums.shape, np.nan), where=valid > 0
+            )
+        else:
+            # NaN-free input: the where-copy and the int64 count pass drop
+            # out; sums/counts divides the identical float64 operands, so
+            # the quotient bits match the masked-divide branch exactly
+            sums = np.add.reduceat(V, starts, axis=0)
+            counts = np.diff(np.append(starts, V.shape[0]))
+            means = sums / counts[:, None]
+        if len(starts) == grid_size:
+            # occupied bins are strictly increasing, so covering every bin
+            # means scatter is the identity — the grid IS the means matrix
+            grid = means
+            clean = not had_nan
+        else:
+            grid = np.full((grid_size, col), np.nan)
+            grid[scatter] = means
+            clean = False
 
     # join mask + n_samples_threshold per fingerprint.  A clean group
     # (NaN-free input, every bin occupied) has no NaN anywhere in the
@@ -346,68 +361,66 @@ def _assemble_geometry_group(
     # row, so each column's result is bit-identical either way)
     grid_stats = None
     if clean and len(alive) > 1:
-        t0 = time.time()
-        with np.errstate(all="ignore"):
-            import warnings
+        with _stage("finalize"):
+            with np.errstate(all="ignore"):
+                import warnings
 
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore", category=RuntimeWarning)
-                grid_stats = (
-                    np.nanmean(grid, axis=0),
-                    np.nanstd(grid, axis=0, ddof=1),
-                    np.nanmin(grid, axis=0),
-                    np.nanmax(grid, axis=0),
-                )
-        _STAGE_SECONDS.observe(time.time() - t0, "finalize")
+                with warnings.catch_warnings():
+                    warnings.simplefilter("ignore", category=RuntimeWarning)
+                    grid_stats = (
+                        np.nanmean(grid, axis=0),
+                        np.nanstd(grid, axis=0, ddof=1),
+                        np.nanmin(grid, axis=0),
+                        np.nanmax(grid, axis=0),
+                    )
 
     # stacked buffers: one per (final row count, tag count) subgroup;
     # every machine (dups included) gets its own slot so the dispatch
     # plane sees consecutive leading-axis views of one base
-    t0 = time.time()
-    by_shape: Dict[Tuple[int, int], List[_FpGroup]] = {}
-    for g in alive:
-        shape = (g.n_rows - g.offset, g.values.shape[1])
-        by_shape.setdefault(shape, []).append(g)
-    for (n_final, n_tags), members in by_shape.items():
-        m_total = sum(len(g.names) for g in members)
-        cap = max(capacity(m_total) if capacity else m_total, m_total)
-        base = np.empty((cap, n_final, n_tags), dtype=np.float32)
-        _register_stack(base)
-        slot = 0
-        for g in members:
-            sub = grid[:, g.col0 : g.col0 + g.values.shape[1]]
-            d64 = sub if g.n_rows == grid_size else sub[g.keep]
-            base[slot] = d64[g.offset:] if g.offset else d64
-            g.slots = [(g.names[0], slot)]
-            lead = slot
-            slot += 1
-            for dup in g.names[1:]:
-                base[slot] = base[lead]  # fingerprint twin: one memcpy
-                g.slots.append((dup, slot))
+    with _stage("assemble"):
+        by_shape: Dict[Tuple[int, int], List[_FpGroup]] = {}
+        for g in alive:
+            shape = (g.n_rows - g.offset, g.values.shape[1])
+            by_shape.setdefault(shape, []).append(g)
+        for (n_final, n_tags), members in by_shape.items():
+            m_total = sum(len(g.names) for g in members)
+            cap = max(capacity(m_total) if capacity else m_total, m_total)
+            base = np.empty((cap, n_final, n_tags), dtype=np.float32)
+            _register_stack(base)
+            slot = 0
+            for g in members:
+                sub = grid[:, g.col0 : g.col0 + g.values.shape[1]]
+                d64 = sub if g.n_rows == grid_size else sub[g.keep]
+                base[slot] = d64[g.offset:] if g.offset else d64
+                g.slots = [(g.names[0], slot)]
+                lead = slot
                 slot += 1
-            # stats/metadata read the pre-truncation float64 rows, exactly
-            # like the per-machine path (align truncation happens in the
-            # builder AFTER get_data there)
-            stats_dict = None
-            if grid_stats is not None:
-                smean, sstd, smin, smax = grid_stats
-                stats_dict = {
-                    t.name: {
-                        "mean": float(smean[g.col0 + k]),
-                        "std": float(sstd[g.col0 + k]),
-                        "min": float(smin[g.col0 + k]),
-                        "max": float(smax[g.col0 + k]),
+                for dup in g.names[1:]:
+                    base[slot] = base[lead]  # fingerprint twin: one memcpy
+                    g.slots.append((dup, slot))
+                    slot += 1
+                # stats/metadata read the pre-truncation float64 rows, exactly
+                # like the per-machine path (align truncation happens in the
+                # builder AFTER get_data there)
+                stats_dict = None
+                if grid_stats is not None:
+                    smean, sstd, smin, smax = grid_stats
+                    stats_dict = {
+                        t.name: {
+                            "mean": float(smean[g.col0 + k]),
+                            "std": float(sstd[g.col0 + k]),
+                            "min": float(smin[g.col0 + k]),
+                            "max": float(smax[g.col0 + k]),
+                        }
+                        for k, t in enumerate(g.dataset.tag_list)
                     }
-                    for k, t in enumerate(g.dataset.tag_list)
-                }
-            g.meta = _group_metadata(g, d64, grid_size, stats_dict)
-            for i, (name, s) in enumerate(g.slots):
-                X = base[s]
-                meta = g.meta if i == 0 else copy.deepcopy(g.meta)
-                out[name] = (X, X, meta, 0.0)
-                _MACHINES_TOTAL.inc(1.0, "vectorized" if i == 0 else "deduped")
-        _set_live_slots(base, slot)
-    _STAGE_SECONDS.observe(time.time() - t0, "assemble")
+                g.meta = _group_metadata(g, d64, grid_size, stats_dict)
+                for i, (name, s) in enumerate(g.slots):
+                    X = base[s]
+                    meta = g.meta if i == 0 else copy.deepcopy(g.meta)
+                    out[name] = (X, X, meta, 0.0)
+                    _MACHINES_TOTAL.inc(1.0, "vectorized" if i == 0 else "deduped")
+            _set_live_slots(base, slot)
 
 
 def _group_metadata(
@@ -419,35 +432,34 @@ def _group_metadata(
     """The exact metadata dict ``get_data`` + ``get_metadata`` would
     record for this fingerprint (same keys, same insertion order — the
     metadata JSON is a byte-parity artifact)."""
-    t0 = time.time()
-    ds = g.dataset
-    n_raw = len(g.index)
-    names = [t.name for t in ds.tag_list]
-    meta: Dict[str, Any] = {
-        "tag_loading_metadata": {
-            name: {
-                "original_length": int(n_raw),
-                "resampled_length": int(grid_size),
-            }
-            for name in names
-        },
-        "train_start_date": str(ds.train_start_date),
-        "train_end_date": str(ds.train_end_date),
-        "resolution": ds.resolution,
-        "row_filter": ds.row_filter,
-        "rows_after_join": int(g.n_rows),
-        "rows_after_filter": int(g.n_rows),
-        "filtered_periods": 0,
-        "tag_list": [t.to_json() for t in ds.tag_list],
-        "target_tag_list": [t.to_json() for t in ds.target_tag_list],
-        "data_provider": ds.data_provider.to_dict(),
-        "summary_statistics": (
-            stats
-            if stats is not None
-            else summary_statistics_arrays(d64, names)
-        ),
-    }
-    _STAGE_SECONDS.observe(time.time() - t0, "finalize")
+    with _stage("finalize"):
+        ds = g.dataset
+        n_raw = len(g.index)
+        names = [t.name for t in ds.tag_list]
+        meta: Dict[str, Any] = {
+            "tag_loading_metadata": {
+                name: {
+                    "original_length": int(n_raw),
+                    "resampled_length": int(grid_size),
+                }
+                for name in names
+            },
+            "train_start_date": str(ds.train_start_date),
+            "train_end_date": str(ds.train_end_date),
+            "resolution": ds.resolution,
+            "row_filter": ds.row_filter,
+            "rows_after_join": int(g.n_rows),
+            "rows_after_filter": int(g.n_rows),
+            "filtered_periods": 0,
+            "tag_list": [t.to_json() for t in ds.tag_list],
+            "target_tag_list": [t.to_json() for t in ds.target_tag_list],
+            "data_provider": ds.data_provider.to_dict(),
+            "summary_statistics": (
+                stats
+                if stats is not None
+                else summary_statistics_arrays(d64, names)
+            ),
+        }
     return meta
 
 

@@ -23,6 +23,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gordo_tpu.telemetry import metrics as telemetry
+from gordo_tpu.telemetry.spans import add_to_span
 
 from .fleet import DATA_AXIS, MODEL_AXIS, FleetMesh
 
@@ -138,7 +139,9 @@ def place(tree: Any, sharding: Any = None) -> Any:
     shardings matching ``tree``.  Counts one placement per call
     (``gordo_fleet_placements_total{kind}``) and, per device, the leaves
     that landed on it (``gordo_mesh_device_transfers_total{device}``) —
-    both read from the placed arrays' own devices.
+    both read from the placed arrays' own devices — and adds the
+    transfer's ``bytes`` and ``leaves`` onto the span the caller has open
+    (``gordo.build.stage`` in a project build).
     """
     if sharding is None:
         out = jax.device_put(tree)
@@ -146,9 +149,12 @@ def place(tree: Any, sharding: Any = None) -> Any:
         out = jax.device_put(tree, sharding)
     if telemetry.enabled():
         per_device: Dict[int, int] = {}
-        for leaf in jax.tree_util.tree_leaves(out):
+        leaves = jax.tree_util.tree_leaves(out)
+        for leaf in leaves:
             for d in leaf.devices():
                 per_device[d.id] = per_device.get(d.id, 0) + 1
+        add_to_span(bytes=sum(leaf.nbytes for leaf in leaves),
+                    leaves=len(leaves))
         _PLACEMENTS.inc(1.0, "sharded" if len(per_device) > 1 else "single")
         for device_id, n_leaves in per_device.items():
             _DEVICE_TRANSFERS.inc(float(n_leaves), str(device_id))

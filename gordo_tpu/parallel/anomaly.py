@@ -37,18 +37,21 @@ falls back to the per-machine builder.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import hashlib
 import logging
 import pickle
+import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from gordo_tpu import compile as compile_plane
+from gordo_tpu import telemetry
 from gordo_tpu.anomaly.diff import SMOOTHING_WINDOW, DiffBasedAnomalyDetector
 from gordo_tpu.models.estimator import BaseJaxEstimator
 from gordo_tpu.ops.scalers import (
@@ -355,6 +358,40 @@ def _stack_warm_params(params_list: Sequence[Any], m_pad: int):
 # The fleet builder
 # ---------------------------------------------------------------------------
 
+SPAN_PREFIX = "gordo.build."
+PROGRAM_WAIT_JOIN_SECONDS = 5.0
+
+
+def _watch_program(out: Any, clock: Any,
+                   enqueued_at: float) -> threading.Thread:
+    """Start the thread that stamps a just-enqueued program's end for the
+    chunk's ``clock``.  It holds the program's SMALLEST output leaf and nothing else of ``out``,
+    so the collect side's ``g.out = None`` still frees the buffers; on a
+    mesh that leaf is sharded like the rest and is ready when every
+    device's part is.  The wait enqueues nothing on the device, so unlike
+    the fetch's on-device slices it does not queue behind the next
+    program.  Started in a copy of the caller's context: the span it
+    opens shares the build's trace id and parent."""
+    leaf = min(jax.tree.leaves(out), key=lambda a: a.size)
+    thread = threading.Thread(
+        target=contextvars.copy_context().run,
+        args=(_await_leaf, leaf, clock, clock.enqueued(enqueued_at)),
+        name="gordo-program-wait", daemon=True,
+    )
+    thread.start()
+    return thread
+
+
+def _await_leaf(leaf: jax.Array, clock: Any,
+                on_ready: Callable[[float], None]) -> None:
+    with clock.span("program_wait") as sp:
+        try:
+            leaf.block_until_ready()
+        except Exception as exc:  # surfaces at collect, which demotes
+            sp["error"] = type(exc).__name__
+    on_ready(sp.get("end", time.time()))
+
+
 @dataclasses.dataclass
 class _GroupContext:
     """Static per-group program context shared by dispatch and warm."""
@@ -381,8 +418,8 @@ class _PendingGroup:
     k_folds: int
     t0: float
     pad_built: bool = False
-    fetch_seconds: float = 0.0
-    assemble_seconds: float = 0.0
+    #: the thread that stamps this program's end (None: nobody asked)
+    watcher: Optional[threading.Thread] = None
     #: fetched HOST result tree, kept after collect — the stacked arrays
     #: the per-machine detectors hold views into, re-exposed whole so a
     #: downstream consumer (fleet-health baseline scoring) can adopt them
@@ -399,9 +436,8 @@ class PendingFleetBuild:
     :meth:`collect` blocks on the device results, runs the (partial) D2H
     fetch and per-machine assembly, and caches the detectors — idempotent,
     so the drive loop can hold one of these per chunk and collect behind
-    the next chunk's dispatch.  ``fetch_seconds``/``assemble_seconds``
-    accumulate where collect time went (the pipeline's stage-attribution
-    telemetry reads them).
+    the next chunk's dispatch.  Where collect time went is on the
+    ``gordo.build.fetch`` and ``gordo.build.assemble`` spans.
     """
 
     def __init__(
@@ -414,8 +450,6 @@ class PendingFleetBuild:
         self._n = n
         self._groups = groups
         self._detectors: Optional[List[DiffBasedAnomalyDetector]] = None
-        self.fetch_seconds = 0.0
-        self.assemble_seconds = 0.0
         #: devices that held the groups' result arrays (filled by collect;
         #: the build summary's ``device`` object reads it)
         self.devices: set = set()
@@ -432,10 +466,21 @@ class PendingFleetBuild:
                 self.devices |= array_devices(g.out)
                 for i, det in zip(g.indices, self._builder._collect_group(g)):
                     detectors[i] = det
-                self.fetch_seconds += g.fetch_seconds
-                self.assemble_seconds += g.assemble_seconds
             self._detectors = detectors  # type: ignore[assignment]
         return self._detectors  # type: ignore[return-value]
+
+    def settle(self, timeout: float = PROGRAM_WAIT_JOIN_SECONDS) -> bool:
+        """Wait for the groups' ready stamps (their watcher threads).  After
+        :meth:`collect` has returned or raised the programs have ended, so
+        this returns at once; the timeout is for a watcher that hangs all
+        the same.  True when every stamp is in."""
+        for g in self._groups:
+            if g.watcher is not None:
+                g.watcher.join(timeout)
+        return not any(
+            g.watcher is not None and g.watcher.is_alive()
+            for g in self._groups
+        )
 
     def prestacked(self, names: List[str]) -> Optional[Dict[str, Any]]:
         """The collected groups' stacked host arrays as a serving
@@ -502,10 +547,17 @@ class FleetDiffBuilder:
         cv: Any = None,
         mesh: Optional[Mesh] = None,
         pad_lengths: Optional[int] = None,
+        clock: Optional[Any] = None,
     ):
         self.spec = spec
         self.splitter = build_splitter(cv)
         self.mesh = mesh
+        #: the drive loop's recorder for this chunk
+        #: (``builder.timeline.ChunkClock``): the phase spans land on its
+        #: row and every enqueued program gets a watcher thread that
+        #: stamps its end.  None (everyone but ``build_project`` with
+        #: telemetry on): plain spans, no thread
+        self.clock = clock
         #: pad-up mode: machines grouped by row count rounded UP to a
         #: multiple of this, padded with weight-masked rows — every real
         #: row trains, and a ragged bucket needs one program per ALIGNED
@@ -608,19 +660,20 @@ class FleetDiffBuilder:
         for i in idxs:
             by_len.setdefault(int(Xs[i].shape[0]), []).append(i)
         for group in by_len.values():
-            X_g = _stack_machine_axis([Xs[i] for i in group])
-            if ys is None or all(ys[i] is Xs[i] for i in group):
-                # the ingest plane hands targets == inputs as the SAME
-                # array object — one stacked buffer serves both
-                y_g = X_g
-            else:
-                y_g = _stack_machine_axis([ys[i] for i in group])
+            def stacked(group=group):
+                X_g = _stack_machine_axis([Xs[i] for i in group])
+                if ys is None or all(ys[i] is Xs[i] for i in group):
+                    # the ingest plane hands targets == inputs as the SAME
+                    # array object — one stacked buffer serves both
+                    return X_g, X_g, None
+                return X_g, _stack_machine_axis([ys[i] for i in group]), None
+
             warm_g = (
                 None
                 if warm_params is None
                 else [warm_params[i] for i in group]
             )
-            g = self._dispatch_group(X_g, y_g, warm=warm_g)
+            g = self._dispatch_group(stacked, warm=warm_g)
             g.indices = list(group)
             groups.append(g)
 
@@ -698,23 +751,26 @@ class FleetDiffBuilder:
         )
 
         for n_pad, idxs in by_pad.items():
-            m = len(idxs)
-            n_feat = Xs[idxs[0]].shape[1]
-            n_out = n_feat if ys is None else ys[idxs[0]].shape[1]
-            X = np.full((m, n_pad, n_feat), np.nan, np.float32)
-            y = np.full((m, n_pad, n_out), np.nan, np.float32)
-            lens = np.zeros((m,), np.int32)
-            for j, i in enumerate(idxs):
-                L = Xs[i].shape[0]
-                lens[j] = L
-                X[j, :L] = Xs[i]
-                y[j, :L] = Xs[i] if ys is None else ys[i]
+            def stacked(n_pad=n_pad, idxs=idxs):
+                m = len(idxs)
+                n_feat = Xs[idxs[0]].shape[1]
+                n_out = n_feat if ys is None else ys[idxs[0]].shape[1]
+                X = np.full((m, n_pad, n_feat), np.nan, np.float32)
+                y = np.full((m, n_pad, n_out), np.nan, np.float32)
+                lens = np.zeros((m,), np.int32)
+                for j, i in enumerate(idxs):
+                    L = Xs[i].shape[0]
+                    lens[j] = L
+                    X[j, :L] = Xs[i]
+                    y[j, :L] = Xs[i] if ys is None else ys[i]
+                return X, y, lens
+
             warm_g = (
                 None
                 if warm_params is None
                 else [warm_params[i] for i in idxs]
             )
-            g = self._dispatch_group(X, y, lens=lens, warm=warm_g)
+            g = self._dispatch_group(stacked, warm=warm_g)
             g.indices = list(idxs)
             # distinguishes genuinely pad-built artifacts from the
             # exact-fallback ones above (fleet_build stamps metadata
@@ -827,54 +883,69 @@ class FleetDiffBuilder:
             )
         return program.warm(X_av, y_av, seeds_av)
 
+    def _span(self, phase: str, **attrs: Any):
+        """The span of one phase of this builder's chunk: on the chunk's
+        row where the drive loop handed a clock, a plain span otherwise."""
+        if self.clock is not None:
+            return self.clock.span(phase, **attrs)
+        return telemetry.span(SPAN_PREFIX + phase, **attrs)
+
     def _dispatch_group(
         self,
-        X: np.ndarray,
-        y: np.ndarray,
-        lens: Optional[np.ndarray] = None,
+        stacked: Callable[[], Tuple[np.ndarray, np.ndarray,
+                                    Optional[np.ndarray]]],
         warm: Optional[Sequence[Any]] = None,
     ) -> _PendingGroup:
         """Launch one length-homogeneous group's device program and return
-        WITHOUT blocking (``lens`` given: the masked pad-up program;
-        ``warm`` given: the warm program resuming from stacked previous
-        params).  Inputs go through the placement seam (async H2D) and the
-        jitted call returns device futures; the blocking fetch lives in
-        :meth:`_collect_group`.  Lint-enforced dispatch window: no
-        blocking D2H here (scripts/lint.py)."""
+        WITHOUT blocking.  ``stacked()`` gives the group's ``(X, y, lens)``
+        with a leading machine axis (``lens`` set: the masked pad-up
+        program; ``warm`` given: the warm program resuming from stacked
+        previous params); it runs inside the ``stage`` span with the
+        model-axis padding and the placement seam's async H2D, then the
+        jitted call alone is the ``enqueue`` span — a re-trace or a
+        compile shows there — and returns device futures; the blocking
+        fetch lives in :meth:`_collect_group`.  Lint-enforced dispatch
+        window: no blocking D2H here (scripts/lint.py)."""
         spec = self.spec
         t0 = time.time()
-        m, n_rows = X.shape[:2]
-        ctx = self._group_context(n_rows, X.shape[2], y.shape[2])
+        with self._span("stage") as staged:
+            X, y, lens = stacked()
+            m, n_rows = X.shape[:2]
+            ctx = self._group_context(n_rows, X.shape[2], y.shape[2])
 
-        # Pad the model axis (dummy copies; results discarded): next power
-        # of two + mesh multiple, so distinct machine counts share one
-        # compiled program per (module, length) — see _model_axis_pad.
-        m_pad = _model_axis_pad(m, self.mesh)
-        if m_pad != m:
-            y_is_x = y is X
-            X = _pad_models_capacity(X, m_pad)
-            y = X if y_is_x else _pad_models_capacity(y, m_pad)
-            if lens is not None:
-                # host ints → int32 view (this scope's lint gate reserves
-                # the np.asarray spelling for D2H misuse)
-                lens = fleet_mod._pad_models(
-                    lens.astype(np.int32, copy=False), m_pad
-                )
+            # Pad the model axis (dummy copies; results discarded): next
+            # power of two + mesh multiple, so distinct machine counts
+            # share one compiled program per (module, length) — see
+            # _model_axis_pad.
+            m_pad = _model_axis_pad(m, self.mesh)
+            if m_pad != m:
+                y_is_x = y is X
+                X = _pad_models_capacity(X, m_pad)
+                y = X if y_is_x else _pad_models_capacity(y, m_pad)
+                if lens is not None:
+                    # host ints → int32 view (this scope's lint gate
+                    # reserves the np.asarray spelling for D2H misuse)
+                    lens = fleet_mod._pad_models(
+                        lens.astype(np.int32, copy=False), m_pad
+                    )
 
-        seeds = np.full((m_pad,), spec.seed, dtype=np.uint32)
-        params0 = (
-            _stack_warm_params(warm, m_pad) if warm is not None else None
-        )
-        program = self._group_program(
-            ctx, padded=lens is not None, warm=params0 is not None
-        )
-        host_args = (X, y, seeds) if lens is None else (X, y, lens, seeds)
-        args = fleet_mod.stage_inputs(host_args, self.mesh)
-        if params0 is not None:
-            params0 = fleet_mod.stage_inputs(params0, self.mesh)
-            out = program(*args, params0)
-        else:
+            seeds = np.full((m_pad,), spec.seed, dtype=np.uint32)
+            params0 = (
+                _stack_warm_params(warm, m_pad) if warm is not None else None
+            )
+            program = self._group_program(
+                ctx, padded=lens is not None, warm=params0 is not None
+            )
+            host_args = (X, y, seeds) if lens is None else (X, y, lens, seeds)
+            args = fleet_mod.stage_inputs(host_args, self.mesh)
+            if params0 is not None:
+                args = (*args, fleet_mod.stage_inputs(params0, self.mesh))
+            staged["machines"] = m
+        with self._span("enqueue") as enqueued:
             out = program(*args)
+        watcher = None
+        if self.clock is not None and "end" in enqueued:
+            watcher = _watch_program(out, self.clock, enqueued["end"])
 
         return _PendingGroup(
             indices=[],
@@ -883,6 +954,7 @@ class FleetDiffBuilder:
             built_kwargs=ctx.built_kwargs,
             k_folds=ctx.k_folds,
             t0=t0,
+            watcher=watcher,
         )
 
     def _collect_group(
@@ -893,50 +965,35 @@ class FleetDiffBuilder:
         assemble per-machine detectors.  An async XLA failure from
         dispatch surfaces here."""
         out = g.out
-        t0 = time.time()
-        host = {
-            # fold axis: slot -1 is the final full-data fit — the only slot
-            # _assemble reads, so slice on device and fetch (K+1)x fewer
-            # bytes than the stacked per-fold stats
-            "scaler_stats": [
-                {stat: np.asarray(val[:, -1]) for stat, val in step.items()}
-                for step in out["scaler_stats"]
-            ],
-            "det_scaler_stats": to_host(out["det_scaler_stats"]),
-            "final_params": to_host(out["final_params"]),
-            "final_history": np.asarray(out["final_history"]),
-            "feature_thresholds": np.asarray(out["feature_thresholds"]),
-            "aggregate_threshold": np.asarray(out["aggregate_threshold"]),
-            "metrics": {
-                name: np.asarray(v) for name, v in out["metrics"].items()
-            },
-        }
-        g.out = None  # free the device buffers now, not at pending teardown
-        g.host = host  # views of these back the detectors; no extra copy
+        with self._span("fetch"):
+            host = {
+                # fold axis: slot -1 is the final full-data fit — the only
+                # slot _assemble reads, so slice on device and fetch (K+1)x
+                # fewer bytes than the stacked per-fold stats
+                "scaler_stats": [
+                    {stat: np.asarray(val[:, -1]) for stat, val in step.items()}
+                    for step in out["scaler_stats"]
+                ],
+                "det_scaler_stats": to_host(out["det_scaler_stats"]),
+                "final_params": to_host(out["final_params"]),
+                "final_history": np.asarray(out["final_history"]),
+                "feature_thresholds": np.asarray(out["feature_thresholds"]),
+                "aggregate_threshold": np.asarray(out["aggregate_threshold"]),
+                "metrics": {
+                    name: np.asarray(v) for name, v in out["metrics"].items()
+                },
+            }
+            g.out = None  # free the device buffers now, not at pending teardown
+            g.host = host  # views of these back the detectors; no extra copy
         fleet_seconds = time.time() - g.t0
-        g.fetch_seconds = time.time() - t0
-        t1 = time.time()
-        detectors = self._assemble(
-            host, g.m, g.built_kwargs, fleet_seconds, g.k_folds
-        )
-        if g.pad_built:
-            for det in detectors:
-                det.pad_built_ = True
-        g.assemble_seconds = time.time() - t1
+        with self._span("assemble"):
+            detectors = self._assemble(
+                host, g.m, g.built_kwargs, fleet_seconds, g.k_folds
+            )
+            if g.pad_built:
+                for det in detectors:
+                    det.pad_built_ = True
         return detectors
-
-    def _build_group(
-        self,
-        X: np.ndarray,
-        y: np.ndarray,
-        lens: Optional[np.ndarray] = None,
-        warm: Optional[Sequence[Any]] = None,
-    ) -> List[DiffBasedAnomalyDetector]:
-        """One length-homogeneous group, dispatch + collect back to back —
-        the synchronous seam the split grew out of."""
-        return self._collect_group(
-            self._dispatch_group(X, y, lens=lens, warm=warm)
-        )
 
     # -- unpacking into per-machine detector objects ------------------------
     def _assemble(

@@ -7,8 +7,9 @@ The telemetry plane every layer reports through:
   (``serve/server.py`` mounts it at ``GET /metrics``); JSON snapshots the
   multi-host builder writes per shard and watchman/CLI merge.
 - :mod:`gordo_tpu.telemetry.spans` — wall-clock trace spans with a
-  context-propagated trace id (``X-Gordo-Trace-Id`` header), layered on
-  top of the opt-in ``utils/profiling.trace`` jax-profiler hook.
+  context-propagated trace id (``X-Gordo-Trace-Id`` header) and parent
+  span; each also holds a ``jax.profiler.TraceAnnotation``, so it shows
+  in any profiler session that is open.
 - :mod:`gordo_tpu.telemetry.fleet_health` — per-machine anomaly-score
   distribution sketches (mergeable log-bucket histograms), build-time
   baselines, and the baseline-vs-live drift signal behind the
@@ -51,9 +52,11 @@ from gordo_tpu.telemetry.fleet_health import (  # noqa: F401
 from gordo_tpu.telemetry.spans import (  # noqa: F401
     DEADLINE_HEADER,
     TRACE_HEADER,
+    add_to_span,
     current_trace_id,
     ensure_trace_id,
     new_trace_id,
+    record_span,
     set_trace_id,
     span,
 )
@@ -72,6 +75,7 @@ __all__ = [
     "ScoreSketch",
     "TRACE_HEADER",
     "add_instance_label",
+    "add_to_span",
     "counter",
     "drift_score",
     "current_trace_id",
@@ -89,6 +93,7 @@ __all__ = [
     "normalize_health_doc",
     "baselines_from_archive",
     "read_rollups",
+    "record_span",
     "render",
     "render_snapshot",
     "set_enabled",

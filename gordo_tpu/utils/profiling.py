@@ -1,18 +1,15 @@
-"""Profiling & step-metrics hooks.
+"""Opt-in ``jax.profiler`` dumps around named sections.
 
 Reference status (SURVEY.md §6.1): essentially absent — the reference only
 records build wall-times into metadata.  The TPU build keeps that
-metadata-first design and adds opt-in ``jax.profiler`` tracing: set
-``GORDO_PROFILE_DIR`` (or pass ``profile_dir``) and every wrapped section
-dumps a Perfetto/TensorBoard-loadable trace.
-
-Since the telemetry plane landed, ``trace`` is no longer a pure no-op
-without the profiler: every wrapped section ALWAYS records its wall time
-into the ``gordo_profile_section_seconds`` histogram (label = the section
-name's leading component, so ``fleet_bucket/512`` and ``fleet_bucket/64``
-share a bounded series), and emits a span (``telemetry.spans``) carrying
-the full section name.  The jax-profiler dump stays opt-in — it is the
-expensive microscope; the histogram is the always-on clock.
+metadata-first design: ``trace`` is a thin caller of ``telemetry.span``
+(the span ``profile.<head>`` feeds ``gordo_span_seconds`` and the span
+log, and shows in any open profiler session), and with
+``GORDO_PROFILE_DIR`` set (or ``directory`` passed) it also opens a
+profiler session of its own around the section and dumps a
+Perfetto/TensorBoard-loadable trace.  jax allows one session per process:
+do not combine ``GORDO_PROFILE_DIR`` with an outer session (the
+benchmark's ``--trace 1``, or another ``trace`` section around this one).
 """
 
 from __future__ import annotations
@@ -20,7 +17,6 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
-import time
 from typing import Iterator, Optional
 
 from gordo_tpu import telemetry
@@ -29,13 +25,6 @@ logger = logging.getLogger(__name__)
 
 ENV_VAR = "GORDO_PROFILE_DIR"
 
-_SECTION_SECONDS = telemetry.histogram(
-    "gordo_profile_section_seconds",
-    "Wall-clock duration of profiling.trace sections (always recorded; "
-    "label is the section name before any '/')",
-    labels=("section",),
-)
-
 
 def profile_dir() -> Optional[str]:
     return os.environ.get(ENV_VAR) or None
@@ -43,26 +32,22 @@ def profile_dir() -> Optional[str]:
 
 @contextlib.contextmanager
 def trace(section: str, directory: Optional[str] = None) -> Iterator[None]:
-    """Wrap a section: wall time always lands in the telemetry histogram;
-    additionally, when profiling is enabled (``GORDO_PROFILE_DIR``), a
-    ``jax.profiler`` trace dumps to ``<dir>/<section>/`` (one subdir per
-    section so repeated builds don't clobber each other)."""
+    """Wrap a section in the span ``profile.<head>`` (``head``: the
+    section name before any '/', so ``fit/m-1`` and ``fit/m-2`` share one
+    bounded histogram series; the full name reaches the span log).  When
+    profiling is enabled (``GORDO_PROFILE_DIR``), a ``jax.profiler`` trace
+    also dumps to ``<dir>/<section>/`` (one subdir per section so repeated
+    builds don't clobber each other)."""
     directory = directory or profile_dir()
-    # bounded histogram label: 'fleet_bucket/512' -> 'fleet_bucket'; the
-    # exact section name still reaches the span log when enabled
     head = section.split("/", 1)[0]
-    t0 = time.perf_counter()
-    try:
-        with telemetry.span("profile." + head, section=section):
-            if not directory:
-                yield
-                return
+    with contextlib.ExitStack() as stack:
+        if directory:
             import jax
 
             dest = os.path.join(directory, section.replace("/", "_"))
             os.makedirs(dest, exist_ok=True)
             logger.info("Profiling %r -> %s", section, dest)
-            with jax.profiler.trace(dest):
-                yield
-    finally:
-        _SECTION_SECONDS.observe(time.perf_counter() - t0, head)
+            # the session opens first, so the span's annotation lands in it
+            stack.enter_context(jax.profiler.trace(dest))
+        stack.enter_context(telemetry.span("profile." + head, section=section))
+        yield

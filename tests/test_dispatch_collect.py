@@ -205,7 +205,7 @@ class TestLazyFetchParity:
         spec = analyze_definition(from_definition(DETECTOR_DEF))
         builder = FleetDiffBuilder(spec)
         X = np.stack(Xs)
-        g = builder._dispatch_group(X, X)
+        g = builder._dispatch_group(lambda: (X, X, None))
 
         # eager reference: the FULL device tree, fetched before collect
         # runs its partial reads (fetch is idempotent — same buffers)

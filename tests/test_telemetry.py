@@ -337,17 +337,18 @@ class TestSpans:
 
 
 def test_profiling_trace_records_without_profile_dir(monkeypatch):
-    """Satellite: profiling.trace is no longer a pure no-op without
-    GORDO_PROFILE_DIR — section wall time always reaches the registry,
-    with the pre-'/' head as the bounded label."""
+    """profiling.trace is a thin caller of telemetry.span: without
+    GORDO_PROFILE_DIR the section's wall time still reaches
+    gordo_span_seconds, once, with the pre-'/' head as the bounded label."""
     monkeypatch.delenv("GORDO_PROFILE_DIR", raising=False)
     from gordo_tpu.utils import profiling
 
-    h = profiling._SECTION_SECONDS
-    before = h.snapshot_series("unit_test_section")["count"]
+    h = telemetry.REGISTRY.get("gordo_span_seconds")
+    before = h.snapshot_series("profile.unit_test_section")["count"]
     with profiling.trace("unit_test_section/512"):
         pass
-    assert h.snapshot_series("unit_test_section")["count"] == before + 1
+    after = h.snapshot_series("profile.unit_test_section")["count"]
+    assert after == before + 1
 
 
 def test_events_are_counted_and_single_line(caplog):
