@@ -56,6 +56,9 @@ GAP_SPAN = SPAN_PREFIX + "device_gap"
 #: the three that finish the previous chunk under one name
 DRIVE_PHASES = ("load_wait", "stage", "enqueue", "fetch", "assemble", "handoff")
 FINISH_PHASES = ("fetch", "assemble", "handoff")
+#: what ``telemetry.span`` itself puts on a span; the rest is what callers
+#: and ``add_to_span`` counted onto it
+SPAN_FIELDS = frozenset({"id", "parent", "start", "end", "seconds", "chunk"})
 
 STAGE_SECONDS = telemetry.histogram(
     "gordo_build_pipeline_stage_seconds",
@@ -214,7 +217,10 @@ class BuildTimeline:
     @contextlib.contextmanager
     def phase(self, name: str, chunk: int, observe: bool = True,
               **attrs: Any) -> Iterator[Dict[str, Any]]:
-        """The span ``gordo.build.<name>`` of ``chunk``, kept on its row;
+        """The span ``gordo.build.<name>`` of ``chunk``, kept on its row
+        with the numbers counted onto it (``counts``: ``bytes`` and
+        ``leaves`` placed in ``stage``, ``carry_leaves`` of the fits traced
+        in ``enqueue``), summed where a phase comes more than once;
         ``observe`` puts its seconds into the stage histogram at once
         (phases that come once per group are summed per chunk instead, by
         :meth:`observe`)."""
@@ -225,8 +231,13 @@ class BuildTimeline:
         finally:
             if "end" in sp:  # telemetry was on when the span opened
                 with self._lock:
-                    self._row(chunk)["phases"].setdefault(name, []).append(
+                    row = self._row(chunk)
+                    row["phases"].setdefault(name, []).append(
                         (sp["start"], sp["end"]))
+                    for key, value in sp.items():
+                        if key not in SPAN_FIELDS and type(value) in (int, float):
+                            counts = row.setdefault("counts", {}).setdefault(name, {})
+                            counts[key] = counts.get(key, 0) + value
                 if observe:
                     STAGE_SECONDS.observe(sp["seconds"], name)
 

@@ -60,33 +60,32 @@ class _GateParams(nn.Module):
         return kernel, bias
 
 
-class _FusedLSTMCellParams(nn.Module):
-    """Owns one LSTM layer's params under the exact OptimizedLSTMCell tree
-    (gates concatenated in flax's ``i, f, g, o`` order)."""
+GATES = "ifgo"  # flax's gate order inside a fused kernel
+
+
+class _LSTMCellParams(nn.Module):
+    """Owns one LSTM layer's params under the exact OptimizedLSTMCell tree:
+    one ``_GateParams`` per gate, ``ii if ig io`` (kernels) and ``hi hf hg
+    ho`` (kernels and biases)."""
 
     features: int
 
     @nn.compact
     def __call__(self, in_features: int):
-        ks_i, ks_h, biases = [], [], []
-        for c in "ifgo":
+        cell = {}
+        for c in GATES:
             k, _ = _GateParams(
                 self.features, False, nn.initializers.lecun_normal(),
                 name=f"i{c}",
             )(in_features)
-            ks_i.append(k)
-        for c in "ifgo":
+            cell[f"i{c}"] = {"kernel": k}
+        for c in GATES:
             k, b = _GateParams(
                 self.features, True, nn.initializers.orthogonal(),
                 name=f"h{c}",
             )(self.features)
-            ks_h.append(k)
-            biases.append(b)
-        return (
-            jnp.concatenate(ks_i, axis=-1),   # (in, 4H)
-            jnp.concatenate(ks_h, axis=-1),   # (H, 4H)
-            jnp.concatenate(biases, axis=-1),  # (4H,)
-        )
+            cell[f"h{c}"] = {"kernel": k, "bias": b}
+        return cell
 
 
 def _fused_lstm_layer(
@@ -128,9 +127,11 @@ def _fused_lstm_layer(
         h = o * jnp.tanh(c)
         return (c, h), h
 
-    # plain scan, no unroll: measured on the fleet build (8-machine CPU
-    # sweep), unroll=4 was ~20% SLOWER warm and slower to compile — the
-    # step body is already one fused matmul + elementwise
+    # plain scan, no unroll: XLA does not fuse across the recurrence.  By
+    # the count of the program compiled for a v5e (ISSUE 27; the tool is
+    # scripts/fleet_program_ops.py) unroll=3 is 10-23 operations a time
+    # step for 13, and unroll=12 an optimiser step of 2,843 operations for
+    # 3,400 at twice the compile (hourglass), 3,417 for 3,190 (symmetric)
     _, hs = jax.lax.scan(step, (c0, h0), jnp.swapaxes(xp, 0, 1))
     return jnp.swapaxes(hs, 0, 1)                   # (B, T, H)
 
@@ -157,21 +158,82 @@ class LSTMAutoEncoderModule(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        # x: (batch, lookback, n_features)
+        # x: (batch, lookback, n_features).  Declares the public tree,
+        # whose shapes need the feature count alone, and runs the one
+        # forward there is on it.
+        in_features = x.shape[-1]
+        params = {}
+        for i, d in enumerate(self.dims):
+            name = f"OptimizedLSTMCell_{i}"
+            params[name] = _LSTMCellParams(int(d), name=name)(in_features)
+            in_features = int(d)
+        # nn.Dense's own parameters (same names, initializers and RNG path)
+        kernel, bias = _GateParams(
+            self.out_dim, True, nn.initializers.lecun_normal(), name="out"
+        )(in_features)
+        params["out"] = {"kernel": kernel, "bias": bias}
+        return self.apply_packed(self.pack(params), x)
+
+    @nn.nowrap
+    def pack(self, params):
+        """The public tree in the layout the layers compute in: per layer
+        ``kernel_i (F, 4H)``, ``kernel_h (H, 4H)`` and ``bias (4H,)``, gates
+        concatenated in flax's ``i, f, g, o`` order; the head as it is.
+        20 leaves for 74 at six layers.  A fit that carries this tree
+        (``train.fit.make_fit_fn``) concatenates once, not every step."""
+        packed = {}
+        for i in range(len(self.dims)):
+            cell = params[f"OptimizedLSTMCell_{i}"]
+            packed[f"OptimizedLSTMCell_{i}"] = {
+                "kernel_i": jnp.concatenate(
+                    [cell[f"i{c}"]["kernel"] for c in GATES], axis=-1),
+                "kernel_h": jnp.concatenate(
+                    [cell[f"h{c}"]["kernel"] for c in GATES], axis=-1),
+                "bias": jnp.concatenate(
+                    [cell[f"h{c}"]["bias"] for c in GATES], axis=-1),
+            }
+        packed["out"] = dict(params["out"])
+        return packed
+
+    @nn.nowrap
+    def unpack(self, packed):
+        """Inverse of :meth:`pack`: the ``OptimizedLSTMCell_{k}/{ii..ho}``
+        tree every artifact stores."""
+        params = {}
+        for i in range(len(self.dims)):
+            layer = packed[f"OptimizedLSTMCell_{i}"]
+            cell = {}
+            for c, k in zip(GATES, jnp.split(layer["kernel_i"], 4, axis=-1)):
+                cell[f"i{c}"] = {"kernel": k}
+            for c, k, b in zip(GATES,
+                               jnp.split(layer["kernel_h"], 4, axis=-1),
+                               jnp.split(layer["bias"], 4, axis=-1)):
+                cell[f"h{c}"] = {"kernel": k, "bias": b}
+            params[f"OptimizedLSTMCell_{i}"] = cell
+        params["out"] = dict(packed["out"])
+        return params
+
+    @nn.nowrap
+    def apply_packed(self, packed, x: jnp.ndarray) -> jnp.ndarray:
+        """The forward pass as a pure function of a :meth:`pack` tree."""
         squeeze = x.ndim == 2
         if squeeze:  # single window
             x = x[None]
         x = x.astype(self.compute_dtype)
         for i, (d, f) in enumerate(zip(self.dims, self.funcs)):
-            d = int(d)
-            ki, kh, b = _FusedLSTMCellParams(
-                d, name=f"OptimizedLSTMCell_{i}"
-            )(x.shape[-1])
-            x = _fused_lstm_layer(x, ki, kh, b, d, self.compute_dtype)
+            layer = packed[f"OptimizedLSTMCell_{i}"]
+            x = _fused_lstm_layer(
+                x, layer["kernel_i"], layer["kernel_h"], layer["bias"],
+                int(d), self.compute_dtype,
+            )
             x = resolve_activation(f)(x)
-        out = nn.Dense(self.out_dim, dtype=jnp.float32, name="out")(
-            x[:, -1, :].astype(jnp.float32)
-        )
+        # nn.Dense(out_dim, dtype=float32), written out
+        head = packed["out"]
+        out = jax.lax.dot_general(
+            x[:, -1, :].astype(jnp.float32),
+            head["kernel"].astype(jnp.float32),
+            (((1,), (0,)), ((), ())),
+        ) + head["bias"].astype(jnp.float32)
         out = resolve_activation(self.out_func)(out)
         return out[0] if squeeze else out
 

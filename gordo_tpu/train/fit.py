@@ -29,6 +29,7 @@ import numpy as np
 import optax
 
 from gordo_tpu import compile as compile_plane
+from gordo_tpu import telemetry
 
 # _fit_jit donates params/X/y/w.  Only params can alias an output, so XLA
 # reports X/y/w as "not usable" donations — donating them is still the
@@ -47,6 +48,22 @@ OPTIMIZERS: Dict[str, Callable[..., optax.GradientTransformation]] = {
     "nadam": optax.nadam,
     "lamb": optax.lamb,
 }
+
+#: optimisers whose update of an element reads that element's own
+#: gradient and state alone, so that it gives the same numbers whether
+#: leaves are carried apart or concatenated.  Not ``lamb``: its trust
+#: ratio is a norm over each leaf.
+ELEMENTWISE_OPTIMIZERS = frozenset(
+    {"adam", "adamw", "sgd", "rmsprop", "adagrad", "nadam"}
+)
+
+_FIT_LAYOUT = telemetry.counter(
+    "gordo_fit_layout_total",
+    "Fits traced, by the layout their parameters are carried in through "
+    "the epochs: packed (the layout the module's layers compute in) or "
+    "public (the tree artifacts store)",
+    labels=("layout",),
+)
 
 
 def _mse(pred, target):
@@ -214,26 +231,63 @@ def make_stateful_fit_fn(module, cfg: TrainConfig, steps: int, bs: int) -> Calla
     return fit_fn
 
 
+def packed_layout(module, cfg: TrainConfig) -> bool:
+    """Whether a fit of ``module`` under ``cfg`` may carry the module's
+    packed parameters (``pack`` / ``unpack`` / ``apply_packed``, as
+    ``LSTMAutoEncoderModule`` has them) for the public tree and give the
+    same numbers: the optimiser is elementwise, and none of its keyword
+    arguments can address a leaf of the public tree (a mask, a per-leaf
+    schedule: anything that is not a plain number, string or None)."""
+    return (
+        hasattr(module, "pack")
+        and cfg.optimizer.lower() in ELEMENTWISE_OPTIMIZERS
+        and all(
+            v is None or isinstance(v, (int, float, str))
+            for _, v in cfg.optimizer_kwargs
+        )
+    )
+
+
 def make_fit_fn(module, cfg: TrainConfig, steps: int, bs: int) -> Callable:
     """The whole multi-epoch fit as ONE pure function
     ``(params, X, y, w, rng) -> (params, history)``.
 
     This is the unit the fleet engine vmaps across stacked models
     (``gordo_tpu.parallel.fleet``) and the single-model path jits directly.
+
+    Where :func:`packed_layout` allows, the parameters are packed once,
+    every epoch and step (loss, gradient, optimiser state, update) runs on
+    the packed tree, and the public tree is unpacked once after the last
+    epoch: a step then carries and updates a few large arrays instead of
+    concatenating, slicing and updating one small array per gate.
     """
     tx = make_optimizer(cfg)
-    loss_fn = make_loss_fn(module.apply, cfg.loss)
+    packed = packed_layout(module, cfg)
+    if packed:
+        def apply_fn(variables, x):
+            return module.apply_packed(variables["params"], x)
+    else:
+        apply_fn = module.apply
+    loss_fn = make_loss_fn(apply_fn, cfg.loss)
     epoch = make_epoch_fn(loss_fn, tx, steps, bs, cfg.shuffle)
 
     def fit_fn(params, X, y, w, rng):
+        if packed:
+            params = module.pack(params)
         opt_state = tx.init(params)
+        # runs where the fit is traced: once per program and fit
+        _FIT_LAYOUT.inc(1.0, "packed" if packed else "public")
+        telemetry.add_to_span(
+            fit_traces=1,
+            carry_leaves=len(jax.tree.leaves((params, opt_state))),
+        )
         keys = jax.random.split(rng, cfg.epochs)
 
         def body(carry, key):
             return epoch(carry, key, X, y, w)
 
         (params, _), history = jax.lax.scan(body, (params, opt_state), keys)
-        return params, history
+        return (module.unpack(params) if packed else params), history
 
     return fit_fn
 
