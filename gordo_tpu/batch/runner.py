@@ -57,6 +57,7 @@ from gordo_tpu.ingest.fingerprint import provider_fingerprint
 from gordo_tpu.serve import precision
 from gordo_tpu.serve.shard import shard_slices
 from gordo_tpu.serve.fleet_scorer import FleetScorer
+from gordo_tpu.serve.scorer import refuse_sequence_model
 
 logger = logging.getLogger(__name__)
 
@@ -244,6 +245,8 @@ def run_backfill(cfg: BackfillConfig) -> Dict[str, Any]:
     # models + metadata at the serving precision (the server's exact
     # resolution order: env > warmup-manifest dtype > float32)
     models = {r.name: r.load_model() for r in refs}
+    for name, model in models.items():
+        refuse_sequence_model(model, name, "the backfill runner")
     metas = {r.name: (r.load_metadata() or {}) for r in refs}
     manifest_dtype = (load_warmup_manifest(cfg.model_dir) or {}).get("dtype")
     dtype = precision.serve_dtype(default=manifest_dtype)

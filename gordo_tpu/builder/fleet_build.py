@@ -340,15 +340,68 @@ def estimate_ragged_compile_seconds(machines: Sequence[Machine]) -> float:
     return max(0.0, extra * COMPILE_SECONDS_PER_LENGTH)
 
 
-def default_bucket_size(spec) -> int:
+#: what the exact fleet program holds per float32 parameter and machine:
+#: ``params0``, the running fit's weights, their gradient, Adam's two moments
+#: (``parallel.anomaly._exact_fleet_program``)
+FIT_BYTES_PER_PARAMETER = 20
+
+#: a chunk's parameters and optimiser state may take this share of a device;
+#: the rest is for the data, the activations and the next chunk's inputs
+PARAMETER_MEMORY_SHARE = 0.25
+
+#: where the backend reports no limit (XLA:CPU): one v5e chip
+ASSUMED_DEVICE_BYTES = 16 * 2 ** 30
+
+
+def _parameter_count(spec, widths) -> Optional[int]:
+    """Parameters of one machine's model, from shapes alone
+    (``jax.eval_shape`` of the module's init); None where the spec or the
+    widths do not say.  A factory that cannot be sized raises: a chunk
+    planned without the count may be five hundred models that do not fit."""
+    est = getattr(spec, "estimator_proto", None)
+    if est is None or widths is None:
+        return None
+    import jax
+    import jax.numpy as jnp
+
+    from gordo_tpu.registry import lookup_factory
+
+    module = lookup_factory(est.model_type, est.kind)(
+        n_features=int(widths[0]), n_features_out=int(widths[1]),
+        **spec.factory_kwargs,
+    )
+    # the estimator's own windowing gives the input's rank
+    rows = jnp.zeros(
+        (int(getattr(est, "lookback_window", 1)) + 1, int(widths[0])),
+        jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), est._make_inputs(rows)[:1]))
+    return sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(shapes))
+
+
+def _device_bytes() -> int:
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or ASSUMED_DEVICE_BYTES)
+
+
+def default_bucket_size(spec, widths: Optional[Tuple[int, int]] = None) -> int:
     """Per-signature ``max_bucket_size`` default: recurrent estimators
     (``lookback_window > 1`` — LSTM family) chunk at
     ``DEFAULT_MAX_BUCKET_LSTM``, everything else at
-    ``DEFAULT_MAX_BUCKET``."""
+    ``DEFAULT_MAX_BUCKET`` — and never more machines than whose parameters
+    and optimiser state, at ``FIT_BYTES_PER_PARAMETER``, fit a quarter of a
+    device: a model of half a billion parameters is a chunk of one."""
     est = getattr(spec, "estimator_proto", None)
+    size = DEFAULT_MAX_BUCKET
     if getattr(est, "lookback_window", 1) > 1:
-        return DEFAULT_MAX_BUCKET_LSTM
-    return DEFAULT_MAX_BUCKET
+        size = DEFAULT_MAX_BUCKET_LSTM
+    params = _parameter_count(spec, widths)
+    if params:
+        budget = PARAMETER_MEMORY_SHARE * _device_bytes()
+        size = max(1, min(size, int(budget // (FIT_BYTES_PER_PARAMETER * params))))
+    return size
 
 
 class ProjectBuildResult:
@@ -873,7 +926,7 @@ def build_project(
     #    pool while chunk k trains; free arrays as artifacts dump.
     chunks: List[Tuple[Tuple, List[Machine]]] = []
     for key, bucket in buckets.items():
-        size = max_bucket_size or default_bucket_size(specs[key])
+        size = max_bucket_size or default_bucket_size(specs[key], key[1])
         for start in range(0, len(bucket), size):
             chunks.append((key, bucket[start : start + size]))
 

@@ -27,7 +27,7 @@ import numpy as np
 from gordo_tpu.models.base import GordoBase
 from gordo_tpu.ops.scalers import as_float2d
 from gordo_tpu.ops.metrics import explained_variance_score
-from gordo_tpu.ops.windows import make_windows
+from gordo_tpu.ops.windows import make_sequences, make_windows, sequences_to_rows
 from gordo_tpu.registry import lookup_factory
 from gordo_tpu.train.fit import TrainConfig, fit as fit_model
 from gordo_tpu.utils.args import ParamsMixin, capture_args
@@ -69,6 +69,15 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
     def _make_targets(self, X: jnp.ndarray, y: Optional[jnp.ndarray]) -> jnp.ndarray:
         return X if y is None else y
 
+    def _sample_weights(self, X: jnp.ndarray) -> Optional[jnp.ndarray]:
+        """Weight of every training sample (or position); None: all 1."""
+        return None
+
+    def _rows_from_outputs(self, out: jnp.ndarray, n_rows: int) -> jnp.ndarray:
+        """The model's outputs over ``_make_inputs`` as one row per
+        predicted input row."""
+        return out
+
     # -- estimator surface ---------------------------------------------------
     def fit(self, X, y=None, **fit_kwargs):
         t0 = time.time()
@@ -81,6 +90,7 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
         cfg, factory_kwargs = TrainConfig.from_kwargs(merged)
         inputs = self._make_inputs(X)
         targets = self._make_targets(X, y_arr)
+        weights = self._sample_weights(X)
 
         factory = lookup_factory(self.model_type, self.kind)
         built_kwargs = dict(
@@ -93,6 +103,11 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
         self._train_cfg = cfg
 
         seed = int(factory_kwargs.get("seed", 0) or 0)
+        if checkpoint_dir and weights is not None:
+            raise NotImplementedError(
+                f"{type(self).__name__} has no checkpointed fit: "
+                "train.checkpoint carries no per-position weights"
+            )
         if checkpoint_dir:
             # mid-fit checkpoint/resume for long fits (SURVEY.md §6.4)
             from gordo_tpu.train.checkpoint import fit_checkpointed
@@ -105,7 +120,8 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
             )
         else:
             params, history = fit_model(
-                self.module_, inputs, targets, cfg, rng=jax.random.PRNGKey(seed)
+                self.module_, inputs, targets, cfg, rng=jax.random.PRNGKey(seed),
+                w=weights,
             )
         self.params_ = params
         self.history_ = np.asarray(history)
@@ -122,7 +138,8 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
             raise RuntimeError(f"{type(self).__name__} is not fitted")
         if self.module_ is None:
             self._rebuild_module()
-        inputs = self._make_inputs(as_float2d(X))
+        X = as_float2d(X)
+        inputs = self._make_inputs(X)
         if self._predict_jit is None:
             # shared across instances, keyed on the (hashable, structurally
             # equal) flax module — same reasoning as _fit_jit: a fleet of
@@ -131,7 +148,8 @@ class BaseJaxEstimator(ParamsMixin, GordoBase):
             # XLA:CPU recompile also segfaulted jax 0.9 under accumulated
             # compile state)
             self._predict_jit = _predict_jit_for(self.module_)
-        return np.asarray(self._predict_jit({"params": self.params_}, inputs))
+        out = self._predict_jit({"params": self.params_}, inputs)
+        return np.asarray(self._rows_from_outputs(out, int(X.shape[0])))
 
     def score(self, X, y=None, sample_weight=None) -> float:
         """Explained variance of the model's output vs its targets
@@ -229,6 +247,57 @@ class LSTMForecast(LSTMAutoEncoder):
     def _make_targets(self, X, y):
         base = X if y is None else y
         return base[self.lookback_window:]
+
+
+class SequenceForecast(BaseJaxEstimator):
+    """Next-row forecast by a sequence backbone that reads the series once
+    (``kind: kimi_linear``, ``models/factories/backbone.py``): every
+    position of a ``context``-row sequence forecasts its next row.
+
+    Training cuts the rows into sequences of ``context`` rows at ``stride``
+    (``ops.windows.make_sequences``); the target of the position that reads
+    row r is row r + 1, and padding weighs 0 in the loss.  Prediction covers
+    every row from ``offset`` = 1 on exactly once
+    (``ops.windows.sequences_to_rows``): a row's forecast has every earlier
+    row as context up to row ``context``, and from there on at least
+    ``context - stride`` rows, so at worst row 1 is forecast from row 0 alone.
+
+    The fleet builder runs it (window mode ``"sequence"``); the stacked
+    scorer, the streaming scorer and the backfill runner do not carry a
+    sequence model's state yet and refuse it by name.
+    """
+
+    model_type = "SequenceForecast"
+    offset = 1
+
+    def __init__(self, kind: str = "kimi_linear", **kwargs):
+        super().__init__(kind=kind, **kwargs)
+
+    @property
+    def context(self) -> int:
+        return int(self.kwargs.get("context", 1024))
+
+    @property
+    def stride(self) -> int:
+        return int(self.kwargs.get("stride", self.context // 2 or 1))
+
+    def _make_inputs(self, X):
+        return make_sequences(X, X, self.context, self.stride)[0]
+
+    def _make_targets(self, X, y):
+        base = X if y is None else y
+        return make_sequences(X, base, self.context, self.stride)[1]
+
+    def _sample_weights(self, X):
+        return make_sequences(X, X, self.context, self.stride)[2]
+
+    def _rows_from_outputs(self, out, n_rows):
+        return sequences_to_rows(out, n_rows, self.context, self.stride)
+
+    def score(self, X, y=None, sample_weight=None) -> float:
+        X = as_float2d(X)
+        truth = (X if y is None else as_float2d(y))[self.offset:]
+        return float(explained_variance_score(truth, self.predict(X)))
 
 
 # Parity aliases (reference class names).

@@ -1,3 +1,4 @@
+from gordo_tpu.models.factories.backbone import kimi_linear  # noqa: F401
 from gordo_tpu.models.factories.feedforward import (  # noqa: F401
     feedforward_hourglass,
     feedforward_model,

@@ -1,0 +1,663 @@
+"""A sequence backbone from a current open model's block: ``kimi_linear``.
+
+No reference equivalent: upstream's factories are Keras feed-forward and
+LSTM stacks.  This one is the block of Kimi-Linear-48B-A3B (``model_type``
+``kimi_linear``, arXiv:2510.26692) as the encoder of a per-machine next-row
+forecaster: input ``(S, T, F)`` scaled sensor rows, output ``(S, T, F_out)``
+where position t forecasts row t + 1.  The token embedding and the language
+model head have no counterpart for real-valued rows, so ``h_0 = X W_in`` and
+``Y = RMSNorm(h_L) W_out + b``.
+
+Every block is pre-norm residual: ``h += Mixer(RMSNorm(h))``, ``h +=
+FFN(RMSNorm(h))``.  Layers are numbered from 1 as the source does: every
+fourth is MLA, the others KDA; layer 1's feed-forward is dense, the others'
+is the expert layer.
+
+- **KDA** (Kimi Delta Attention): ``q, k, v`` each through a depthwise causal
+  convolution and SiLU, ``q, k`` L2-normalised per head; a per-channel
+  forget gate ``g_t = -exp(A_log) softplus(W_f x_t + dt_bias)``, a per-head
+  write strength ``beta_t``; state ``S_t = (I - beta_t k_t k_t^T)
+  Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T`` and ``o_t = S_t^T q_t / sqrt(d_k)``.
+  Computed chunk-wise (:func:`kda_chunked`): within a chunk the delta rule
+  in matrix form (one triangular solve), between chunks the state through a
+  ``lax.scan``.
+- **MLA** without rotary positions: keys and values from a 512-wide latent,
+  64 key channels shared by all heads, a causal softmax.
+- **Expert layer** (:func:`expert_layer`): a sigmoid router over ALL the
+  model's experts, the 8 largest kept and renormalised; the module is told
+  which contiguous range of experts it holds and computes the sum over the
+  selected experts it holds (the absent experts' terms are left out, as one
+  chip of an expert-parallel deployment would before the exchange), plus the
+  shared expert.  Positions are sorted by expert and multiplied through
+  ``lax.ragged_dot``: no capacity, so no pair is ever dropped.  The
+  selection bias of the source is a buffer held at 0 and is not stored.
+
+Parameters are float32; matmul operands are cast to ``compute_dtype``
+(bfloat16 on a TPU under ``auto``) and accumulated in float32; norms,
+softmax, router scores, the KDA state and its decays, the loss and the
+optimiser are float32.
+
+Parameters of one kind of part are stacked over the layers that have it
+(``kda_wq`` is ``(KDA layers, D, H dk)``, ``moe_wg`` ``(expert layers, held,
+D, W)``, the two norms ``(layers, D)``).  The leading dense layers are
+traced one by one and the expert layers run as ONE ``lax.scan`` over their
+stacked parameters, whose body chooses its layer's mixer by ``lax.cond``:
+the program holds the expert layer, MLA and the scan's KDA once however many
+layers there are, which is what keeps a model of this size compilable in a
+build's set-up and its executable in a compile cache.  (A ``lax.cond``
+between the dense and the expert feed-forward would put layer 1 into the
+scan too; the TPU compiler's conditional code motion crashes on its
+gradient at the published widths, PERF.md section 6.)  Each feed-forward is
+under ``jax.checkpoint`` and each mixer's backward is written out
+(:func:`_mixer`), so backward keeps the residual stream alone and recomputes
+each part once; a mixer reads ``mixer_group`` sequences at a time.
+
+The module has no packed layout (``train.fit.packed_layout`` is False for
+it: it has no ``pack``), and it asks the fleet program to run machines one
+after another (``fleet_axis = "map"``): one model fills the chip.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Dict, List, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from gordo_tpu.models.factories.feedforward import resolve_compute_dtype
+from gordo_tpu.registry import register_model_builder
+
+F32 = jnp.float32
+#: bound on a decay exponent taken against the middle of a chunk; only a
+#: channel that forgets by more than e^-80 within half a chunk reaches it
+_EXP_CLIP = 80.0
+
+
+@dataclasses.dataclass(frozen=True)
+class BackboneConfig:
+    """Shapes of the block, hashable (the module is a static jit argument)."""
+
+    n_features: int
+    n_features_out: int
+    num_layers: int = 5
+    hidden_size: int = 2304
+    num_heads: int = 32
+    kda_head_dim: int = 128
+    kda_gate_rank: int = 128          # low-rank width of W_f and W_g
+    short_conv_kernel_size: int = 4
+    kda_chunk: int = 64
+    mixer_group: int = 2              # sequences a mixer reads at a time
+    full_attn_every: int = 4          # layers 4, 8, ... are MLA
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64        # carried without rotation (NoPE)
+    v_head_dim: int = 128
+    intermediate_size: int = 9216
+    first_k_dense_replace: int = 1
+    moe_intermediate_size: int = 1024
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    experts_held_from: int = 0
+    experts_held: int = 8
+    rms_norm_eps: float = 1e-5
+    compute_dtype: Any = jnp.float32
+
+    def mixer(self, layer: int) -> str:
+        return "mla" if layer % self.full_attn_every == 0 else "kda"
+
+    def ffn(self, layer: int) -> str:
+        return "dense" if layer <= self.first_k_dense_replace else "moe"
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        return self.layers_of("moe")
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """The layers (numbered from 1) whose mixer or feed-forward is
+        ``kind``, in order: a layer's place here is its slot in the stack."""
+        return tuple(l for l in range(1, self.num_layers + 1)
+                     if kind in (self.mixer(l), self.ffn(l)))
+
+
+# ---------------------------------------------------------------------------
+# parameters: one flat dict, created in this order
+# ---------------------------------------------------------------------------
+
+#: the kinds of part a layer is made of: two mixers, two feed-forwards
+KINDS = ("kda", "mla", "dense", "moe")
+
+
+def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
+    """``(name, shape, init)`` of every parameter, in creation order; the
+    leading axis of a ``kda_`` / ``mla_`` / ``dense_`` / ``moe_`` parameter
+    runs over the layers of that kind (:meth:`BackboneConfig.layers_of`), a
+    kind no layer has is left out.  ``init``: ``fan_in`` (normal, std
+    ``shape[-2] ** -0.5``; a convolution's fan-in is its width), ``ones``,
+    ``zeros``, ``a_log`` (log of uniform(1, 16)), ``dt_bias`` (inverse
+    softplus of a step drawn log-uniformly from [1e-3, 1e-1])."""
+    d, h = cfg.hidden_size, cfg.num_heads
+    dk, r = cfg.kda_head_dim, cfg.kda_gate_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    conv = cfg.short_conv_kernel_size
+    w, e = cfg.moe_intermediate_size, cfg.experts_held
+    ws = w * cfg.num_shared_experts
+    by_kind = {
+        "kda": [
+            ("kda_wq", (d, h * dk), "fan_in"),
+            ("kda_wk", (d, h * dk), "fan_in"),
+            ("kda_wv", (d, h * dk), "fan_in"),
+            ("kda_conv_q", (conv, h * dk), "fan_in"),
+            ("kda_conv_k", (conv, h * dk), "fan_in"),
+            ("kda_conv_v", (conv, h * dk), "fan_in"),
+            ("kda_wf_down", (d, r), "fan_in"),
+            ("kda_wf_up", (r, h * dk), "fan_in"),
+            ("kda_a_log", (h,), "a_log"),
+            ("kda_dt_bias", (h * dk,), "dt_bias"),
+            ("kda_wbeta", (d, h), "fan_in"),
+            ("kda_wg_down", (d, r), "fan_in"),
+            ("kda_wg_up", (r, h * dk), "fan_in"),
+            ("kda_out_norm", (dk,), "ones"),
+            ("kda_wo", (h * dk, d), "fan_in"),
+        ],
+        "mla": [
+            ("mla_wq", (d, h * qk), "fan_in"),
+            ("mla_wkv_a", (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), "fan_in"),
+            ("mla_kv_norm", (cfg.kv_lora_rank,), "ones"),
+            ("mla_wkv_b", (cfg.kv_lora_rank,
+                           h * (cfg.qk_nope_head_dim + cfg.v_head_dim)), "fan_in"),
+            ("mla_wo", (h * cfg.v_head_dim, d), "fan_in"),
+        ],
+        "dense": [
+            ("dense_wg", (d, cfg.intermediate_size), "fan_in"),
+            ("dense_wu", (d, cfg.intermediate_size), "fan_in"),
+            ("dense_wd", (cfg.intermediate_size, d), "fan_in"),
+        ],
+        "moe": [
+            ("moe_router", (d, cfg.num_experts), "fan_in"),
+            ("moe_shared_wg", (d, ws), "fan_in"),
+            ("moe_shared_wu", (d, ws), "fan_in"),
+            ("moe_shared_wd", (ws, d), "fan_in"),
+            ("moe_wg", (e, d, w), "fan_in"),
+            ("moe_wu", (e, d, w), "fan_in"),
+            ("moe_wd", (e, w, d), "fan_in"),
+        ],
+    }
+    specs: List[Tuple[str, Tuple[int, ...], str]] = [
+        ("in_proj", (cfg.n_features, d), "fan_in"),
+        ("mixer_norm", (cfg.num_layers, d), "ones"),
+        ("ffn_norm", (cfg.num_layers, d), "ones"),
+    ]
+    for kind in KINDS:
+        n = len(cfg.layers_of(kind))
+        if n:
+            specs += [(name, (n,) + shape, init) for name, shape, init in by_kind[kind]]
+    specs += [
+        ("out_norm", (d,), "ones"),
+        ("out_proj", (d, cfg.n_features_out), "fan_in"),
+        ("out_bias", (cfg.n_features_out,), "zeros"),
+    ]
+    return specs
+
+
+def _initializer(kind: str):
+    def init(key, shape, dtype=F32):
+        if kind == "ones":
+            return jnp.ones(shape, dtype)
+        if kind == "zeros":
+            return jnp.zeros(shape, dtype)
+        if kind == "fan_in":
+            return jax.random.normal(key, shape, dtype) * (shape[-2] ** -0.5)
+        if kind == "a_log":
+            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+        if kind == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(
+                key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+            return dt + jnp.log(-jnp.expm1(-dt))
+        raise ValueError(kind)
+
+    return init
+
+
+# ---------------------------------------------------------------------------
+# pure pieces
+# ---------------------------------------------------------------------------
+
+def _mm(x, w, cd):
+    """``x @ w`` with operands in the compute dtype, accumulated in float32."""
+    return jnp.matmul(x.astype(cd), w.astype(cd), preferred_element_type=F32)
+
+
+def rms_norm(x, weight, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * weight
+
+
+def swiglu(x, wg, wu, wd, cd):
+    return _mm(jax.nn.silu(_mm(x, wg, cd)) * _mm(x, wu, cd), wd, cd)
+
+
+def short_conv(x, w):
+    """Depthwise causal convolution over time: ``x`` (B, T, C), ``w`` (K, C);
+    ``y_t = sum_j w[j] x_{t-K+1+j}`` with zeros before the sequence."""
+    k, t = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(xp[:, j:j + t] * w[j] for j in range(k))
+
+
+def l2_normalize(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int, cd):
+    """The gated delta rule over whole sequences, chunk by chunk.
+
+    ``q, k`` (B, H, T, dk), ``v`` (B, H, T, dv), ``g`` (B, H, T, dk) the log
+    of the per-channel decay (<= 0), ``beta`` (B, H, T); ``q`` arrives
+    scaled.  Returns ``o`` (B, H, T, dv), float32.  ``T`` is a multiple of
+    ``chunk``.
+
+    With ``G`` the running sum of ``g`` inside a chunk and ``S0`` the state
+    entering it, ``S_t = Diag(e^{G_t}) S0 + sum_{s<=t} Diag(e^{G_t-G_s}) k_s
+    u_s^T`` where the pseudo-values ``U`` solve ``(I + Diag(beta)
+    tril(A, -1)) U = Diag(beta) (V - (K e^G) S0)``, ``A_ts = sum_c k_tc k_sc
+    e^{G_tc - G_sc}``.  ``A`` is formed as a product of two factors taken
+    against the chunk's middle row, so that neither exponent exceeds half a
+    chunk's decay; the exponents are clipped at +-80, which only a channel
+    that forgets by more than e^-80 within half a chunk can reach."""
+    b, h, t, dk = k.shape
+    dv = v.shape[-1]
+    nc = t // chunk
+    shape = lambda a: a.reshape(b, h, nc, chunk, *a.shape[3:])  # noqa: E731
+    q, k, v, g, beta = (shape(a.astype(F32)) for a in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=3)                                  # inclusive
+    m = max(chunk // 2 - 1, 0)
+    mid = G[:, :, :, m: m + 1]
+    e_plus = jnp.exp(jnp.clip(G - mid, -_EXP_CLIP, _EXP_CLIP))
+    e_minus = jnp.exp(jnp.clip(mid - G, -_EXP_CLIP, _EXP_CLIP))
+    k_minus = (k * e_minus).astype(cd)
+    pair = lambda a: jnp.einsum(  # noqa: E731
+        "bhntc,bhnsc->bhnts", a.astype(cd), k_minus, preferred_element_type=F32)
+    rows = jnp.arange(chunk)
+    strict = rows[:, None] > rows[None, :]
+    lower = rows[:, None] >= rows[None, :]
+    A = jnp.where(strict, pair(k * e_plus), 0.0) * beta[..., None]
+    Aqk = jnp.where(lower, pair(q * e_plus), 0.0)
+    decay = jnp.exp(G)                                         # from chunk start
+    rhs = jnp.concatenate([v, k * decay], axis=-1) * beta[..., None]
+    solved = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(chunk, dtype=F32), rhs, lower=True, unit_diagonal=True)
+    U0, W = solved[..., :dv], solved[..., dv:]
+    G_end = G[:, :, :, -1:]
+    k_end = k * jnp.exp(G_end - G)                             # to chunk end
+    q_start = q * decay
+
+    def step(S, xs):
+        U0_n, W_n, Aqk_n, q_n, k_n, d_n = xs
+        Sc = S.astype(cd)
+        U = U0_n - jnp.matmul(W_n.astype(cd), Sc, preferred_element_type=F32)
+        o = jnp.matmul(q_n.astype(cd), Sc, preferred_element_type=F32) + jnp.matmul(
+            Aqk_n.astype(cd), U.astype(cd), preferred_element_type=F32)
+        S = S * d_n[..., None] + jnp.einsum(
+            "bhtc,bhtv->bhcv", k_n.astype(cd), U.astype(cd),
+            preferred_element_type=F32)
+        return S, o
+
+    by_chunk = lambda a: jnp.moveaxis(a, 2, 0)  # noqa: E731
+    S0 = jnp.zeros((b, h, dk, dv), F32)
+    xs = tuple(by_chunk(a) for a in (
+        U0, W, Aqk, q_start, k_end, jnp.exp(G_end[:, :, :, 0])))
+    _, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, t, dv)
+
+
+def kda_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    cd, h, dk = cfg.compute_dtype, cfg.num_heads, cfg.kda_head_dim
+    b, t, _ = x.shape
+    chunk = min(cfg.kda_chunk, t)
+    if t % chunk:
+        raise ValueError(f"sequence length {t} is not a multiple of the KDA chunk {chunk}")
+    heads = lambda a: a.reshape(b, t, h, dk).transpose(0, 2, 1, 3)  # noqa: E731
+    q, k, v = (
+        heads(jax.nn.silu(short_conv(_mm(x, p[f"kda_w{n}"], cd), p[f"kda_conv_{n}"])))
+        for n in "qkv"
+    )
+    q = l2_normalize(q) * (dk ** -0.5)
+    k = l2_normalize(k)
+    f = _mm(_mm(x, p["kda_wf_down"], cd), p["kda_wf_up"], cd) + p["kda_dt_bias"]
+    g = -jnp.exp(p["kda_a_log"])[None, :, None, None] * heads(jax.nn.softplus(f))
+    beta = jax.nn.sigmoid(_mm(x, p["kda_wbeta"], cd)).transpose(0, 2, 1)
+    with jax.named_scope("backbone.kda.scan"):
+        o = kda_chunked(q, k, v, g, beta, chunk, cd)
+    gate = jax.nn.sigmoid(_mm(_mm(x, p["kda_wg_down"], cd), p["kda_wg_up"], cd))
+    o = rms_norm(o, p["kda_out_norm"], cfg.rms_norm_eps)
+    o = o.transpose(0, 2, 1, 3).reshape(b, t, h * dk) * gate
+    return _mm(o, p["kda_wo"], cd)
+
+
+def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    cd, h = cfg.compute_dtype, cfg.num_heads
+    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim, cfg.kv_lora_rank)
+    b, t, _ = x.shape
+    q = _mm(x, p["mla_wq"], cd).reshape(b, t, h, dn + dr)
+    kv_a = _mm(x, p["mla_wkv_a"], cd)
+    c = rms_norm(kv_a[..., :r], p["mla_kv_norm"], cfg.rms_norm_eps)
+    k_r = kv_a[..., r:]                                   # shared by all heads
+    kv = _mm(c, p["mla_wkv_b"], cd).reshape(b, t, h, dn + dv)
+    k_n, v = kv[..., :dn], kv[..., dn:]
+    scores = jnp.einsum("bthc,bshc->bhts", q[..., :dn].astype(cd), k_n.astype(cd),
+                        preferred_element_type=F32)
+    scores += jnp.einsum("bthc,bsc->bhts", q[..., dn:].astype(cd), k_r.astype(cd),
+                         preferred_element_type=F32)
+    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v.astype(cd),
+                   preferred_element_type=F32)
+    return _mm(o.reshape(b, t, h * dv), p["mla_wo"], cd)
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    """``x[order]`` for a permutation ``order`` whose inverse is given: the
+    backward pass is a gather by the inverse, not a scatter."""
+    return x[order]
+
+
+def _permute_fwd(x, order, inverse):
+    return x[order], (order, inverse)
+
+
+def _permute_bwd(res, ct):
+    order, inverse = res
+    return ct[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def route(cfg: BackboneConfig, router, x):
+    """``(experts, weights)`` each (N, k): the selected experts of every
+    position and their renormalised, scaled weights.  Float32 throughout."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(F32), router, precision=jax.lax.Precision.HIGHEST))
+    top, experts = jax.lax.top_k(scores, cfg.num_experts_per_token)
+    weights = cfg.routed_scaling_factor * top / (
+        jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return experts, weights
+
+
+def expert_layer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    """``(y, tokens)``: the held experts' part of the routed sum plus the
+    shared expert, for ``x`` (N, D); ``tokens`` (held,) counts the positions
+    each held expert computed."""
+    cd, k, held = cfg.compute_dtype, cfg.num_experts_per_token, cfg.experts_held
+    n, d = x.shape
+    with jax.named_scope("backbone.moe.route"):
+        experts, weights = route(cfg, p["moe_router"], x)
+        local = experts - cfg.experts_held_from
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held).reshape(n * k)  # the absent sort last
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        tokens = jnp.sum(
+            key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+        valid = (jnp.arange(n * k) < jnp.sum(tokens))[:, None]
+    with jax.named_scope("backbone.moe.experts"):
+        xs = jnp.broadcast_to(x.astype(cd)[:, None, :], (n, k, d)).reshape(n * k, d)
+        xs = _permute(xs, order, inverse)
+        # accumulated in float32 inside the kernel, kept in the compute dtype.
+        # Rows past the held pairs belong to no group: the kernel leaves
+        # them unwritten, in the result and in the gradient it hands back,
+        # so both sides of every call are masked
+        def rd(a, w):
+            a = jnp.where(valid, a.astype(cd), 0)
+            out = jax.lax.ragged_dot(a, w.astype(cd), tokens, preferred_element_type=cd)
+            return jnp.where(valid, out, 0)
+
+        mid = jax.nn.silu(rd(xs, p["moe_wg"]).astype(F32)) * rd(xs, p["moe_wu"])
+        ys = _permute(rd(mid, p["moe_wd"]), inverse, order).reshape(n, k, d)
+        y = jnp.sum(ys * jnp.where(mine, weights, 0.0)[..., None], axis=1)
+        y += swiglu(x, p["moe_shared_wg"], p["moe_shared_wu"], p["moe_shared_wd"], cd)
+    return y, tokens
+
+
+def _slice(stack: Dict[str, Any], slot) -> Dict[str, Any]:
+    """One layer's parameters out of a kind's stack (``slot`` a number or a
+    traced index)."""
+    if isinstance(slot, int):
+        return {name: a[slot] for name, a in stack.items()}
+    return {name: jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
+            for name, a in stack.items()}
+
+
+def _mixer_of(cfg: BackboneConfig, kind: str, p: Dict[str, Any], norm, h):
+    """``Mixer(RMSNorm(h))`` of one kind for a group of sequences (G, T, D)."""
+    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    with jax.named_scope("backbone." + kind):
+        return (kda_mixer if kind == "kda" else mla_mixer)(cfg, p, x)
+
+
+def _groups(cfg: BackboneConfig, h):
+    """``h`` (B, T, D) as (groups, ``mixer_group``, T, D)."""
+    b = h.shape[0]
+    group = cfg.mixer_group if b % cfg.mixer_group == 0 else b
+    return h.reshape((b // group, group) + h.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mixer(cfg: BackboneConfig, mixers, norm, which, h):
+    """``Mixer(RMSNorm(h))`` on the stream ``h`` (B, T, D) for one layer of
+    the stacks ``mixers`` (``{"kda": ..., "mla": ...}`` or one of them):
+    ``which`` says whether the layer's mixer is MLA and its slot in each
+    stack (0 where it has none), as traced values.
+
+    A mixer reads ``mixer_group`` sequences at a time (sequences do not see
+    each other, and a mixer's intermediates are what fills the memory).
+    Backward is written out (:func:`_mixer_bwd`): it keeps the layer's input
+    alone, takes the layer's slice of the stacks again, recomputes each
+    group once inside the branch of its kind, and sums the groups' gradients
+    at the size of one layer before it puts them into the stack's shape.
+    Left to ``jax.checkpoint`` around a ``lax.cond``, every intermediate of
+    both kinds crosses from the forward conditional to the backward one, and
+    a gradient in the stack's shape is added up once a group."""
+    p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
+
+    def one(hg):
+        if len(p) == 1:
+            (kind,) = p
+            return _mixer_of(cfg, kind, p[kind], norm, hg)
+        return jax.lax.cond(
+            which["is_mla"],
+            lambda: _mixer_of(cfg, "mla", p["mla"], norm, hg),
+            lambda: _mixer_of(cfg, "kda", p["kda"], norm, hg))
+
+    groups = _groups(cfg, h)
+    out = one(groups[0])[None] if groups.shape[0] == 1 else jax.lax.map(one, groups)
+    return out.reshape(h.shape)
+
+
+def _mixer_fwd(cfg, mixers, norm, which, h):
+    return _mixer(cfg, mixers, norm, which, h), (mixers, norm, which, h)
+
+
+def _mixer_bwd(cfg, res, ct):
+    mixers, norm, which, h = res
+    p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
+
+    def grads(kind, hg, ctg):
+        """This group's gradient for every kind's slice (zeros for the
+        kinds the layer is not), the norm and the group's input."""
+        _, vjp = jax.vjp(functools.partial(_mixer_of, cfg, kind), p[kind], norm, hg)
+        dp, dnorm, dhg = vjp(ctg)
+        return ({k: dp if k == kind else jax.tree.map(jnp.zeros_like, p[k]) for k in p},
+                dnorm), dhg
+
+    def one(acc, pair):
+        hg, ctg = pair
+        if len(p) == 1:
+            (kind,) = p
+            d, dhg = grads(kind, hg, ctg)
+        else:
+            d, dhg = jax.lax.cond(
+                which["is_mla"],
+                lambda: grads("mla", hg, ctg), lambda: grads("kda", hg, ctg))
+        return jax.tree.map(jnp.add, acc, d), dhg
+
+    zero = (jax.tree.map(jnp.zeros_like, p), jnp.zeros_like(norm))
+    (dp, dnorm), dh = jax.lax.scan(one, zero, (_groups(cfg, h), _groups(cfg, ct)))
+    d_mixers = {
+        kind: {name: jax.lax.dynamic_update_index_in_dim(
+            jnp.zeros_like(a), dp[kind][name], which[kind], 0)
+            for name, a in stack.items()}
+        for kind, stack in mixers.items()
+    }
+    no_gradient = {k: np.zeros(v.shape, jax.dtypes.float0) for k, v in which.items()}
+    return d_mixers, dnorm, no_gradient, dh.reshape(h.shape)
+
+
+_mixer.defvjp(_mixer_fwd, _mixer_bwd)
+
+
+def _dense_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
+    """``FFN(RMSNorm(h))`` of a leading dense layer, ``h`` (B, T, D)."""
+    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    with jax.named_scope("backbone.ffn"):
+        return swiglu(x, p["dense_wg"], p["dense_wu"], p["dense_wd"], cfg.compute_dtype)
+
+
+def _expert_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
+    """``(FFN(RMSNorm(h)), tokens)`` of an expert layer for the whole batch
+    ``h`` (B, T, D): an expert sees a step's positions at once."""
+    b, t, d = h.shape
+    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    y, tokens = expert_layer(cfg, p, x.reshape(b * t, d))
+    return y.reshape(b, t, d), tokens
+
+
+def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False):
+    """``x`` (S, T, F) or (T, F) → the forecast of every position's next row.
+    With ``counts``, also ``{"tokens": (moe layers, held), "selected":
+    pairs routed, "held": pairs that fell on held experts}``.
+
+    The leading dense layers are traced one by one; the expert layers are
+    ONE ``lax.scan`` over their stacked parameters, whose body chooses its
+    layer's mixer by ``lax.cond``.  Every feed-forward is under
+    ``jax.checkpoint`` and every mixer has its backward written out
+    (:func:`_mixer`): backward keeps the residual stream alone and
+    recomputes each part once."""
+    squeeze = x.ndim == 2
+    if squeeze:
+        x = x[None]
+    h = _mm(x, params["in_proj"], cfg.compute_dtype)
+    stack = lambda kind: {  # noqa: E731
+        k: v for k, v in params.items() if k.startswith(kind + "_")}
+    mixers = {kind: stack(kind) for kind in ("kda", "mla") if cfg.layers_of(kind)}
+    slot = lambda kind, l: (  # noqa: E731
+        cfg.layers_of(kind).index(l) if l in cfg.layers_of(kind) else 0)
+    which = lambda layers: {  # noqa: E731
+        "is_mla": jnp.asarray([cfg.mixer(l) == "mla" for l in layers]),
+        "kda": jnp.asarray([slot("kda", l) for l in layers], jnp.int32),
+        "mla": jnp.asarray([slot("mla", l) for l in layers], jnp.int32),
+    }
+    of_kinds = lambda layers: {  # noqa: E731  (one kind: no conditional is traced)
+        kind: mixers[kind] for kind in sorted({cfg.mixer(l) for l in layers})}
+    n_dense = len(cfg.layers_of("dense"))
+    for l in range(1, n_dense + 1):
+        h = h + _mixer(cfg, of_kinds([l]), params["mixer_norm"][l - 1],
+                       jax.tree.map(lambda a: a[0], which([l])), h)
+        h = h + jax.checkpoint(functools.partial(_dense_ffn, cfg))(
+            _slice(stack("dense"), l - 1), params["ffn_norm"][l - 1], h)
+    tokens = jnp.zeros((0, cfg.experts_held), jnp.int32)
+    if cfg.moe_layers:
+        rest = cfg.moe_layers
+        rest_mixers = of_kinds(rest)
+
+        def expert_block(h, layer):
+            h = h + _mixer(cfg, rest_mixers, layer["mixer_norm"], layer["which"], h)
+            y, tokens = jax.checkpoint(functools.partial(_expert_ffn, cfg))(
+                layer["moe"], layer["ffn_norm"], h)
+            return h + y, tokens
+
+        h, tokens = jax.lax.scan(expert_block, h, {
+            "mixer_norm": params["mixer_norm"][n_dense:],
+            "ffn_norm": params["ffn_norm"][n_dense:],
+            "which": which(rest),
+            "moe": stack("moe"),
+        })
+    y = _mm(rms_norm(h, params["out_norm"], cfg.rms_norm_eps),
+            params["out_proj"], cfg.compute_dtype) + params["out_bias"]
+    y = y[0] if squeeze else y
+    if not counts:
+        return y
+    positions = h.shape[0] * h.shape[1]
+    return y, {
+        "tokens": tokens,
+        "held": jnp.sum(tokens),
+        "selected": jnp.asarray(
+            positions * cfg.num_experts_per_token * len(cfg.moe_layers), jnp.int32),
+    }
+
+
+class KimiLinearBackbone(nn.Module):
+    """The block as a flax module: parameters in one flat dict
+    (:func:`param_specs`), the forward pass in :func:`forward`."""
+
+    cfg: BackboneConfig
+
+    #: the fleet program runs this module's machines one after another
+    #: (``lax.map``), not side by side under ``vmap``: one model fills the chip
+    fleet_axis = "map"
+
+    @nn.compact
+    def __call__(self, x, counts: bool = False):
+        params = {
+            name: self.param(name, _initializer(init), shape, F32)
+            for name, shape, init in param_specs(self.cfg)
+        }
+        if self.is_initializing():
+            # the parameters' shapes do not depend on a forward pass: none is
+            # traced where the model is only being initialised
+            return jnp.zeros(x.shape[:-1] + (self.cfg.n_features_out,), F32)
+        return forward(self.cfg, params, x, counts)
+
+    def param_count(self) -> int:
+        return sum(math.prod(shape) for _, shape, _ in param_specs(self.cfg))
+
+
+@register_model_builder(type="SequenceForecast")
+def kimi_linear(
+    n_features: int,
+    n_features_out: int = None,
+    compute_dtype: str = "auto",
+    context: int = None,
+    stride: int = None,
+    seed: int = 0,
+    **widths,
+) -> nn.Module:
+    """Kimi-Linear's block at its published widths (every width is a keyword
+    of :class:`BackboneConfig`; tests pass a tiny preset).  ``num_layers``
+    layers from layer 1 on, ``experts_held`` routed experts from
+    ``experts_held_from`` of ``num_experts``.  ``context`` and ``stride`` are
+    the estimator's."""
+    del context, stride, seed
+    known = {f.name for f in dataclasses.fields(BackboneConfig)}
+    unknown = sorted(set(widths) - known)
+    if unknown:
+        raise TypeError(f"kimi_linear got unknown arguments {unknown}")
+    cfg = BackboneConfig(
+        n_features=int(n_features),
+        n_features_out=int(n_features_out or n_features),
+        compute_dtype=resolve_compute_dtype(compute_dtype),
+        **widths,
+    )
+    if not 0 <= cfg.experts_held_from <= cfg.experts_held_from + cfg.experts_held <= cfg.num_experts:
+        raise ValueError("experts_held is not a range of the model's experts")
+    return KimiLinearBackbone(cfg)

@@ -77,6 +77,22 @@ from gordo_tpu.utils.trees import to_host
 
 logger = logging.getLogger(__name__)
 
+_MOE_TOKENS = telemetry.counter(
+    "gordo_moe_tokens_total",
+    "Positions each held expert computed in the optimiser steps of the fleet "
+    "programs' final fits, by expert layer (the source's layer number) and "
+    "expert",
+    labels=("layer", "expert"),
+)
+_MOE_HELD_PAIRS = telemetry.counter(
+    "gordo_moe_held_pairs_total",
+    "Selected (position, expert) pairs that fell on experts held here",
+)
+_MOE_SELECTED_PAIRS = telemetry.counter(
+    "gordo_moe_selected_pairs_total",
+    "(position, expert) pairs the routers selected, held here or not",
+)
+
 #: scalers whose stats are computable by a static pure function (vmappable).
 FLEETABLE_SCALERS = (MinMaxScaler, StandardScaler, RobustScaler)
 
@@ -91,6 +107,16 @@ METRIC_NAMES = (
 # ---------------------------------------------------------------------------
 # Definition analysis
 # ---------------------------------------------------------------------------
+
+def _freeze(value: Any) -> Any:
+    """A hashable stand-in for an estimator argument: lists (YAML's
+    ``dims: [256, 128, 64]``) become tuples, dicts sorted item tuples."""
+    if isinstance(value, (list, tuple)):
+        return tuple(_freeze(v) for v in value)
+    if isinstance(value, dict):
+        return tuple(sorted((k, _freeze(v)) for k, v in value.items()))
+    return value
+
 
 @dataclasses.dataclass
 class FleetSpec:
@@ -120,7 +146,7 @@ class FleetSpec:
             type(self.estimator_proto).__name__,
             self.estimator_proto.kind,
             self.train_cfg,
-            tuple(sorted(self.factory_kwargs.items())),
+            _freeze(self.factory_kwargs),
         )
 
 
@@ -228,13 +254,19 @@ def _make_apply_chain(scaler_opts):
     return apply_chain
 
 
-def _make_windowize(window_mode: str, lookback: int):
+def _make_windowize(window_mode: str, lookback: int, stride: int = 1):
     """Estimator windowing semantics on already-scaled inputs (see the
     estimator classes: "none"=row-wise, "ae"=reconstruct window end,
-    "forecast"=t+1)."""
-    from gordo_tpu.ops.windows import make_windows
+    "forecast"=t+1, "sequence"=every position of a ``lookback``-row
+    sequence forecasts its next row; its padding's weights come from
+    :func:`_sequence_weights`)."""
+    from gordo_tpu.ops.windows import make_sequences, make_windows
 
     def windowize(Xt, y_f):
+        if window_mode == "sequence":
+            return jax.vmap(
+                lambda a, b: make_sequences(a, b, lookback, stride)[:2]
+            )(Xt, y_f)
         if window_mode == "none":
             return Xt, y_f
         if window_mode == "ae":
@@ -246,6 +278,187 @@ def _make_windowize(window_mode: str, lookback: int):
         raise ValueError(f"Unknown window_mode {window_mode!r}")
 
     return windowize
+
+
+def _sequence_weights(n_rows: int, context: int, stride: int) -> np.ndarray:
+    """``(S, T)`` weights of the positions :func:`ops.windows.make_sequences`
+    cuts ``n_rows`` rows into: 1 where a position reads a real row, 0 on the
+    last sequence's padding.  Geometry alone, so a length-group shares it."""
+    from gordo_tpu.ops.windows import num_sequences
+
+    s = num_sequences(n_rows, context, stride)
+    idx = np.arange(s)[:, None] * stride + np.arange(context)[None, :]
+    return (idx < n_rows - 1).astype(np.float32)
+
+
+def _over_machines(module, fn: Callable) -> Callable:
+    """``fn`` over the leading machine axis of its arguments.  Side by side
+    under ``vmap``, as the fleet engine is built; one after another
+    (``lax.map``; a single machine is called as it is) where the module says
+    ``fleet_axis = "map"``: one such model fills the chip, and a ``lax.cond``
+    or a ragged matmul under ``vmap`` would compute every branch and row."""
+    if getattr(module, "fleet_axis", "vmap") != "map":
+        return jax.vmap(fn)
+
+    def mapped(*args):
+        if jax.tree.leaves(args)[0].shape[0] == 1:
+            out = fn(*jax.tree.map(lambda a: a[0], args))
+            return jax.tree.map(lambda a: a[None], out)
+        return jax.lax.map(lambda one: fn(*one), args)
+
+    return mapped
+
+
+def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
+                   counted: bool, start: Callable, starts, fit_keys,
+                   fits: List[Tuple]):
+    """Every fit of a length-group of sequence models (the folds' fits,
+    then the final fit) through ONE traced optimiser step and ONE traced
+    forecast: a ``lax.scan`` over the fits, machines one after another.
+
+    ``fits``: per fit ``(inputs (M, S, T, F), targets, n_rows, held-out
+    inputs (M, S_te, T, F) or None)``; the last is the final fit.  A fit's
+    sequences and minibatches are padded up to the largest fit's with slots
+    that weigh nothing, and the loop over steps runs each fit's own number, so a
+    fold trains exactly on what ``train.fit.make_fit_fn`` would give it (the
+    same shuffle of its own ``steps x bs`` slots, the same loss weights); only
+    the order of a sum's zeros differs.  One model's step is a program of
+    its own to compile: four geometries traced apart cost four times that.
+
+    ``start(starts[m])`` gives machine m's initial parameters and is called
+    where each fit begins: a cold program draws them again from the
+    machine's key for every fit and so holds no copy of them beside the
+    running fit's weights, gradient and two moments (a warm program's are
+    an argument it was handed, and stay).
+
+    Returns ``(final_params, final_history (M, epochs), forecasts per fold
+    [(M, S_te, T, F_out)], what the final fit's steps routed or None)``.
+    """
+    import optax
+
+    from gordo_tpu.train.fit import (
+        _FIT_LAYOUT as fit_layout_counter,
+        batch_geometry, make_loss_fn, make_optimizer, pad_weights,
+    )
+
+    geometry = [batch_geometry(f[0].shape[1], cfg.batch_size) for f in fits]
+    n_cap = max(f[0].shape[1] for f in fits)
+    steps_cap = max(g[0] for g in geometry)
+    bs_cap = max(g[1] for g in geometry)
+    blank = n_cap                      # one more slot: zeros that weigh 0
+    held = [f[3] for f in fits if f[3] is not None]
+    if len({h.shape for h in held}) > 1:
+        raise NotImplementedError(
+            "the folds' held-out blocks cut into different numbers of "
+            "sequences; give the splitter blocks of one length"
+        )
+
+    def padded(a):
+        pad = [(0, 0), (0, n_cap + 1 - a.shape[1])] + [(0, 0)] * (a.ndim - 2)
+        return jnp.pad(a, pad)
+
+    xs = jnp.stack([padded(f[0]) for f in fits], axis=1)      # (M, K, S+1, T, F)
+    ys = jnp.stack([padded(f[1]) for f in fits], axis=1)
+    ws = jnp.stack([
+        pad_weights(f[0].shape[1], n_cap + 1 - f[0].shape[1],
+                    _sequence_weights(f[2], context, stride))
+        for f in fits
+    ])                                                         # (K, S+1, T)
+    te = jnp.stack([
+        f[3] if f[3] is not None else jnp.zeros_like(held[0]) for f in fits
+    ], axis=1)                                                 # (M, K, S_te, T, F)
+    is_fold = jnp.asarray([f[3] is not None for f in fits])
+    live = jnp.asarray([g[0] for g in geometry], jnp.int32)
+    totals = jnp.maximum(jnp.sum(ws, axis=(1, 2)), 1.0)
+
+    tx = make_optimizer(cfg)
+    if counted:
+        grad_fn = jax.value_and_grad(make_loss_fn(
+            lambda variables, x: module.apply(variables, x, counts=True),
+            cfg.loss, aux=True), has_aux=True)
+    else:
+        plain = jax.value_and_grad(make_loss_fn(module.apply, cfg.loss))
+
+        def grad_fn(*args):
+            loss, grads = plain(*args)
+            return (loss, ()), grads
+
+    def schedule(fit_key):
+        """``(K, epochs, steps_cap, bs_cap)`` slot indices: each fit's own
+        shuffles of its own ``steps x bs`` slots, filled up with the blank."""
+        out = []
+        for steps, bs, _ in geometry:
+            per_epoch = []
+            for key in jax.random.split(fit_key, cfg.epochs):
+                order = (jax.random.permutation(key, steps * bs) if cfg.shuffle
+                         else jnp.arange(steps * bs))
+                per_epoch.append(jnp.pad(
+                    order.reshape(steps, bs),
+                    ((0, steps_cap - steps), (0, bs_cap - bs)),
+                    constant_values=blank))
+            out.append(jnp.stack(per_epoch))
+        return jnp.stack(out)
+
+    def forecast(params, x):
+        return module.apply({"params": params}, x)
+
+    def machine(start_m, xs_m, ys_m, te_m, fit_key):
+        def one_fit(_, fit):
+            x, y, w, slots, n_live, total, te_x, fold = fit
+
+            def epoch(carry, slots_e):
+                def step(i, c):
+                    p, s, routed, seen = c
+                    slot = slots_e[i]
+                    bw = w[slot]
+                    (loss, counts), grads = grad_fn(p, x[slot], y[slot], bw)
+                    updates, s = tx.update(grads, s, p)
+                    routed = jax.tree.map(jnp.add, routed, counts)
+                    return (optax.apply_updates(p, updates), s, routed,
+                            seen + loss * jnp.sum(bw))
+
+                # this fit's own number of steps: a loop with a bound read
+                # from the data, not steps that are skipped
+                p, s, routed, seen = jax.lax.fori_loop(
+                    0, n_live, step, (*carry, jnp.zeros((), jnp.float32)))
+                return (p, s, routed), seen / total
+
+            # the scan's carry is the running fit's state, started anew for
+            # every fit: the last fit leaves the final parameters (and what
+            # its steps routed) in it and no fit's result is held beside the
+            # next one's
+            params0_m = start(start_m)
+            (params, opt_state, routed), history = jax.lax.scan(
+                epoch, (params0_m, tx.init(params0_m), no_counts), slots)
+            pred = jax.lax.cond(
+                fold, forecast,
+                lambda p, x: jnp.zeros(pred_shape.shape, pred_shape.dtype),
+                params, te_x)
+            return (params, opt_state, routed), (history, pred)
+
+        like = jax.eval_shape(start, start_m)
+        blank_params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like)
+        pred_shape = jax.eval_shape(forecast, like, te_m[0])
+        no_counts = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(lambda p: module.apply(
+                {"params": p}, xs_m[0, :bs_cap], counts=True)[1], like),
+        ) if counted else ()
+        (final, _, routed), (history, preds) = jax.lax.scan(
+            one_fit, (blank_params, tx.init(blank_params), no_counts),
+            (xs_m, ys_m, ws, schedule(fit_key), live, totals, te_m, is_fold))
+        return final, history[-1], preds, routed
+
+    # runs where the program is traced: once for all the fits
+    fit_layout_counter.inc(1.0, "public")
+    telemetry.add_to_span(fit_traces=1)
+    final_params, final_history, preds, routed = _over_machines(
+        module, machine)(starts, xs, ys, te, fit_keys)
+    return (
+        final_params, final_history,
+        [preds[:, k] for k in range(len(held))],
+        routed if counted else None,
+    )
 
 
 def _model_axis_pad(m: int, mesh) -> int:
@@ -405,6 +618,7 @@ class _GroupContext:
     window_mode: str
     lookback: int
     offset: int
+    stride: int
 
 
 @dataclasses.dataclass
@@ -418,6 +632,8 @@ class _PendingGroup:
     k_folds: int
     t0: float
     pad_built: bool = False
+    #: the group's module (its configuration names what the program counted)
+    module: Any = None
     #: the thread that stamps this program's end (None: nobody asked)
     watcher: Optional[threading.Thread] = None
     #: fetched HOST result tree, kept after collect — the stacked arrays
@@ -811,9 +1027,15 @@ class FleetDiffBuilder:
 
         # Windowing semantics as static flags (see estimator classes):
         # "none"=row-wise FF AE, "ae"=reconstruct window end, "forecast"=t+1.
-        from gordo_tpu.models.estimator import LSTMAutoEncoder, LSTMForecast
+        from gordo_tpu.models.estimator import (
+            LSTMAutoEncoder, LSTMForecast, SequenceForecast,
+        )
 
-        if isinstance(est_proto, LSTMForecast):
+        stride = 1
+        if isinstance(est_proto, SequenceForecast):
+            window_mode, lookback = "sequence", est_proto.context
+            stride = est_proto.stride
+        elif isinstance(est_proto, LSTMForecast):
             window_mode, lookback = "forecast", est_proto.lookback_window
         elif isinstance(est_proto, LSTMAutoEncoder):
             window_mode, lookback = "ae", est_proto.lookback_window
@@ -830,9 +1052,23 @@ class FleetDiffBuilder:
             window_mode=window_mode,
             lookback=int(lookback),
             offset=int(est_proto.offset),
+            stride=int(stride),
         )
 
     def _group_program(self, ctx: _GroupContext, padded: bool, warm: bool):
+        if ctx.window_mode == "sequence":
+            if padded:
+                raise NotImplementedError(
+                    "pad_lengths has no masked program for "
+                    f"{type(self.spec.estimator_proto).__name__}: build its "
+                    "machines at their exact lengths"
+                )
+            return _exact_fleet_program(
+                ctx.module, ctx.scaler_opts, ctx.det_scaler_opts,
+                ctx.window_mode, ctx.lookback, ctx.offset,
+                self.spec.train_cfg, ctx.folds, self.mesh, warm=warm,
+                stride=ctx.stride,
+            )
         fn = _padded_fleet_program if padded else _exact_fleet_program
         return fn(
             ctx.module,
@@ -954,6 +1190,7 @@ class FleetDiffBuilder:
             built_kwargs=ctx.built_kwargs,
             k_folds=ctx.k_folds,
             t0=t0,
+            module=ctx.module,
             watcher=watcher,
         )
 
@@ -983,6 +1220,9 @@ class FleetDiffBuilder:
                     name: np.asarray(v) for name, v in out["metrics"].items()
                 },
             }
+            if "moe" in out:
+                host["moe"] = to_host(out["moe"])
+                self._count_routing(host["moe"], g.m, g.module.cfg)
             g.out = None  # free the device buffers now, not at pending teardown
             g.host = host  # views of these back the detectors; no extra copy
         fleet_seconds = time.time() - g.t0
@@ -994,6 +1234,18 @@ class FleetDiffBuilder:
                 for det in detectors:
                     det.pad_built_ = True
         return detectors
+
+    @staticmethod
+    def _count_routing(moe: Dict[str, Any], m: int, cfg: Any) -> None:
+        """One group's routing counts (``(M, layers, held)`` tokens, ``(M,)``
+        pairs) onto the process's counters."""
+        tokens = moe["tokens"][:m].sum(axis=0)
+        for row, layer in zip(tokens, cfg.moe_layers):
+            for e, count in enumerate(row):
+                _MOE_TOKENS.inc(
+                    float(count), str(layer), str(cfg.experts_held_from + e))
+        _MOE_HELD_PAIRS.inc(float(moe["held"][:m].sum()))
+        _MOE_SELECTED_PAIRS.inc(float(moe["selected"][:m].sum()))
 
     # -- unpacking into per-machine detector objects ------------------------
     def _assemble(
@@ -1078,6 +1330,14 @@ class FleetDiffBuilder:
                 "aggregate_threshold": agg_list[i],
                 "fleet": {"bucket_size": m, "fleet_seconds": fleet_seconds},
             }
+            if "moe" in out:
+                moe = out["moe"]
+                det.cv_metadata_["moe"] = {
+                    "counted_on": "the final fit's optimiser steps",
+                    "tokens_per_held_expert": moe["tokens"][i].tolist(),
+                    "held_pairs": int(moe["held"][i]),
+                    "selected_pairs": int(moe["selected"][i]),
+                }
             detectors.append(det)
         return detectors
 
@@ -1105,6 +1365,7 @@ def _exact_fleet_program(
     folds: Tuple[Tuple[Tuple[int, ...], Tuple[int, ...]], ...],
     mesh,
     warm: bool = False,
+    stride: int = 1,
 ):
     """Return the jitted exact program ``(X, y, seeds) -> out`` for one
     length-group (``warm=True``: ``(X, y, seeds, params0) -> out``).
@@ -1123,6 +1384,21 @@ def _exact_fleet_program(
     model.  Machine-count/length geometry still keys the compile cache the
     same way — warm and cold programs cache independently (``warm`` is part
     of the key) but share XLA lowerings across refresh cycles.
+
+    Memory: the program keeps ``params0`` alive across the fold fits and the
+    final fit (every fit starts from it), beside the running fit's weights,
+    their gradient and Adam's two moments: 20 bytes a float32 parameter and
+    machine.  ``builder.fleet_build.default_bucket_size`` plans a chunk from
+    that figure.  (A cold sequence program draws ``params0`` again where each
+    fit begins and holds 16.)
+
+    Window mode ``"sequence"`` (``SequenceForecast``): the rows are cut into
+    ``lookback``-row sequences at ``stride``, every position is a sample
+    (the loss weighs positions, padding 0), a fold's held-out forecasts are
+    put back into rows, and every fit runs through one traced optimiser step
+    (:func:`_sequence_fits`), machines one after another.  A module that
+    counts what its expert layers routed (``module.apply(..., counts=True)``)
+    has the counts of the final fit's optimiser steps in ``out["moe"]``.
     """
     # Fold indices are digested (they can be tens of thousands of ints —
     # storing them verbatim in every cache key would bloat the cache and
@@ -1139,10 +1415,12 @@ def _exact_fleet_program(
         folds_digest,
         mesh,
         bool(warm),
+        int(stride),
     )
 
     from gordo_tpu.ops import metrics as jmetrics
-    from gordo_tpu.train.fit import batch_geometry
+    from gordo_tpu.ops.windows import sequences_to_rows
+    from gordo_tpu.train.fit import batch_geometry, pad_weights
 
     det_cls, det_opts = det_scaler_opts
     fold_idx = [
@@ -1150,7 +1428,10 @@ def _exact_fleet_program(
     ]
     scale_chain = _make_scale_chain(scaler_opts)
     apply_chain = _make_apply_chain(scaler_opts)
-    windowize = _make_windowize(window_mode, lookback)
+    windowize = _make_windowize(window_mode, lookback, stride)
+    sequence = window_mode == "sequence"
+    counted = sequence and hasattr(module, "cfg") and hasattr(
+        module.cfg, "experts_held")
 
     def one_fit(params0, inputs, targets, fit_keys):
         """vmapped fit with THIS fold's true batch geometry (exactly
@@ -1158,9 +1439,7 @@ def _exact_fleet_program(
         m = inputs.shape[0]
         na = inputs.shape[1]
         steps, bs, n_pad = batch_geometry(na, cfg.batch_size)
-        w = jnp.concatenate(
-            [jnp.ones((na,), jnp.float32), jnp.zeros((n_pad,), jnp.float32)]
-        )
+        w = pad_weights(na, n_pad)
         if n_pad:
             inputs = jnp.concatenate(
                 [inputs, jnp.zeros((m, n_pad) + inputs.shape[2:], inputs.dtype)],
@@ -1190,7 +1469,18 @@ def _exact_fleet_program(
         # Final fit's scaler chain + windows (also provides the init shape).
         full_stats, Xt_full = scale_chain(X)
         inputs_full, targets_full = windowize(Xt_full, y)
-        if warm_params0 is None:
+        if counted:
+            # runs where the program is traced: on the tracing chunk's
+            # enqueue span
+            telemetry.add_to_span(
+                params=module.param_count(),
+                experts_held=module.cfg.experts_held,
+                sequences=inputs_full.shape[1],
+                context=lookback,
+            )
+        if sequence:
+            params0 = None  # drawn where each fit begins, see _sequence_fits
+        elif warm_params0 is None:
             params0 = fleet_mod.fleet_init(
                 module, init_keys, inputs_full[0, :1]
             )
@@ -1200,18 +1490,48 @@ def _exact_fleet_program(
         per_step_stats: List[List[Any]] = [[] for _ in scaler_opts]
         feat_maxes, total_maxes = [], []
         metric_vals: Dict[str, List[Any]] = {n: [] for n in METRIC_NAMES}
+        routed = None
 
-        for tr, te in fold_idx:
+        def train_rows(tr):
             # Materialize the fold exactly as the single path would.
             X_tr, y_tr = jnp.take(X, tr, axis=1), jnp.take(y, tr, axis=1)
             stats_k, Xt = scale_chain(X_tr)
-            inputs, targets = windowize(Xt, y_tr)
-            params_k, _ = one_fit(params0, inputs, targets, fit_keys)
+            return (stats_k, *windowize(Xt, y_tr))
 
-            # Out-of-fold predictions on the materialized test slice.
+        def held_out_rows(stats_k, te):
             X_te, y_te = jnp.take(X, te, axis=1), jnp.take(y, te, axis=1)
             te_inputs, _ = windowize(apply_chain(stats_k, X_te), y_te)
-            pred = vapply(params_k, te_inputs)
+            return te_inputs, y_te
+
+        if sequence:
+            # every fit through one traced step (see _sequence_fits)
+            trained = [train_rows(tr) for tr, _ in fold_idx]
+            held_out = [held_out_rows(t[0], te) for t, (_, te) in zip(trained, fold_idx)]
+            if warm_params0 is None:
+                sample = inputs_full[0, :1]
+                start, starts = (
+                    lambda key: module.init(key, sample)["params"]), init_keys
+            else:
+                start, starts = (lambda given: given), warm_params0
+            final_params, final_history, fold_preds, routed = _sequence_fits(
+                module, cfg, lookback, stride, counted, start, starts, fit_keys,
+                [(t[1], t[2], len(tr), h[0])
+                 for t, h, (tr, _) in zip(trained, held_out, fold_idx)]
+                + [(inputs_full, targets_full, X.shape[1], None)],
+            )
+
+        for k, (tr, te) in enumerate(fold_idx):
+            if sequence:
+                stats_k, y_te = trained[k][0], held_out[k][1]
+                pred = jax.vmap(
+                    lambda o: sequences_to_rows(o, len(te), lookback, stride)
+                )(fold_preds[k])
+            else:
+                stats_k, inputs, targets = train_rows(tr)
+                params_k, _ = one_fit(params0, inputs, targets, fit_keys)
+                # Out-of-fold predictions on the materialized test slice.
+                te_inputs, y_te = held_out_rows(stats_k, te)
+                pred = vapply(params_k, te_inputs)
             y_true = y_te[:, offset:]
 
             for name in METRIC_NAMES:
@@ -1234,9 +1554,10 @@ def _exact_fleet_program(
                 per_step_stats[j].append(st)
 
         # Final full-data fit (fold index -1 in the stats layout).
-        final_params, final_history = one_fit(
-            params0, inputs_full, targets_full, fit_keys
-        )
+        if not sequence:
+            final_params, final_history = one_fit(
+                params0, inputs_full, targets_full, fit_keys
+            )
         for j, st in enumerate(full_stats):
             per_step_stats[j].append(st)
 
@@ -1262,6 +1583,9 @@ def _exact_fleet_program(
                 name: jnp.stack(v, axis=1) for name, v in metric_vals.items()
             },
         }
+        if routed is not None:
+            # what the final fit's optimiser steps routed, per machine
+            out["moe"] = routed
         if mesh is not None:
             out = jax.lax.with_sharding_constraint(out, model_sharding(mesh))
         return out

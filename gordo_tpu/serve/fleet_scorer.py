@@ -35,6 +35,7 @@ from gordo_tpu.serve.scorer import (
     _H2D,
     _bucket_rows,
     _extract_chain,
+    refuse_sequence_model,
     _rolling_median,
     short_rows_message,
 )
@@ -1033,6 +1034,7 @@ class FleetScorer:
         )
         groups: Dict[Tuple, Tuple[List[str], List[Dict]]] = {}
         for name, model in sorted(models.items()):
+            refuse_sequence_model(model, name, "FleetScorer")
             chain = _extract_chain(model)
             sig = _signature(chain) if chain else None
             if sig is None:

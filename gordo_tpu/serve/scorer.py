@@ -37,6 +37,7 @@ from gordo_tpu.models.estimator import (
     BaseJaxEstimator,
     LSTMAutoEncoder,
     LSTMForecast,
+    SequenceForecast,
 )
 from gordo_tpu.ops.windows import make_windows
 from gordo_tpu.pipeline import Pipeline
@@ -105,8 +106,30 @@ def _legacy_pad(X: np.ndarray, bucket: int) -> np.ndarray:
     return np.concatenate([X, np.tile(X[-1:], (bucket - X.shape[0], 1))])
 
 
+class SequenceModelUnsupported(NotImplementedError):
+    """A plane that would have to carry a sequence model's state (per-layer
+    recurrent state, a latent cache) or stack such models was handed one."""
+
+
+def refuse_sequence_model(model, machine: str, plane: str) -> None:
+    """Raise for a model whose estimator is a ``SequenceForecast``: ``plane``
+    (the stacked scorer, the streaming scorer, the backfill runner) scores
+    windowed and row-wise estimators only, and would score this one wrongly.
+    Its own ``anomaly()`` (``CompiledScorer``'s fallback) does score it."""
+    base = getattr(model, "base_estimator", model)
+    est = base._final if isinstance(base, Pipeline) else base
+    if isinstance(est, SequenceForecast):
+        raise SequenceModelUnsupported(
+            f"{plane} cannot score machine {machine!r}: its estimator "
+            f"{type(est).__name__} (kind {est.kind!r}) reads the series as "
+            "sequences; score it through the detector's own anomaly()"
+        )
+
+
 def _extract_chain(model) -> Optional[Dict[str, Any]]:
-    """Pull the pure pieces out of a detector/pipeline/estimator, or None."""
+    """Pull the pure pieces out of a detector/pipeline/estimator, or None
+    (a ``SequenceForecast`` has no fused chain: its detector's own
+    ``anomaly()`` scores it)."""
     detector = None
     base = model
     if isinstance(model, DiffBasedAnomalyDetector):
@@ -126,6 +149,8 @@ def _extract_chain(model) -> Optional[Dict[str, Any]]:
     else:
         est = base
     if not isinstance(est, BaseJaxEstimator) or est.params_ is None:
+        return None
+    if isinstance(est, SequenceForecast):
         return None
     if est.module_ is None:
         est._rebuild_module()

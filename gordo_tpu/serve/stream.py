@@ -74,6 +74,7 @@ from gordo_tpu import faults, telemetry
 from gordo_tpu.anomaly.diff import scores_fn
 from gordo_tpu.ops.windows import make_windows
 from gordo_tpu.serve import precision
+from gordo_tpu.serve.scorer import refuse_sequence_model
 
 logger = logging.getLogger(__name__)
 
@@ -403,6 +404,7 @@ class MachineStream:
 
     def rebind(self, scorer, dtype: Optional[str] = None) -> None:
         """(Re)attach to ``scorer``, carrying the session state across."""
+        refuse_sequence_model(scorer.model, self.name, "MachineStream")
         c = scorer.chain
         if not c or not c.get("detector"):
             raise StreamUnsupported(

@@ -680,6 +680,7 @@ def training_baselines(
     docs: Dict[str, Dict[str, Any]] = {}
     try:
         from gordo_tpu.serve.fleet_scorer import FleetScorer
+        from gordo_tpu.serve.scorer import SequenceModelUnsupported
 
         X_by = {
             name: np.asarray(X, np.float32)[-BASELINE_MAX_ROWS:]
@@ -696,6 +697,10 @@ def training_baselines(
             scores = res.get("total-anomaly-score")
             if scores is not None:
                 docs[name] = sketch_from_scores(scores, ts=0.0).to_doc()
+    except SequenceModelUnsupported as exc:
+        # the stacked scorer refuses sequence models: no baseline sketch
+        logger.info("no training baseline for chunk %s...: %s",
+                    sorted(models)[:3], exc)
     except Exception:
         logger.exception(
             "training baseline sketching failed for chunk %s...",

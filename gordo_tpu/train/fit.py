@@ -133,16 +133,23 @@ def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
     return OPTIMIZERS[name](lr, **kwargs)
 
 
-def make_loss_fn(apply_fn: Callable, loss: str) -> Callable:
-    """Weighted scalar loss of (params, x, y, w); w masks padded rows."""
+def make_loss_fn(apply_fn: Callable, loss: str, aux: bool = False) -> Callable:
+    """Weighted scalar loss of (params, x, y, w); w masks padded rows.
+    ``w`` weighs samples ``(bs,)`` or, for a model that forecasts every
+    position of a sequence, positions ``(bs, T)``: the error is averaged
+    over the axes ``w`` does not have.  With ``aux``, ``apply_fn`` returns
+    ``(prediction, aux)`` and so does the loss: ``(loss, aux)``, for
+    ``jax.value_and_grad(..., has_aux=True)``."""
     if loss not in LOSSES:
         raise ValueError(f"Unknown loss {loss!r}; available: {sorted(LOSSES)}")
     elem = LOSSES[loss]
 
     def loss_fn(params, x, y, w):
         pred = apply_fn({"params": params}, x)
-        per_row = jnp.mean(elem(pred, y), axis=tuple(range(1, pred.ndim)))
-        return jnp.sum(per_row * w) / jnp.maximum(jnp.sum(w), 1.0)
+        pred, extra = pred if aux else (pred, None)
+        per_row = jnp.mean(elem(pred, y), axis=tuple(range(w.ndim, pred.ndim)))
+        value = jnp.sum(per_row * w) / jnp.maximum(jnp.sum(w), 1.0)
+        return (value, extra) if aux else value
 
     return loss_fn
 
@@ -163,11 +170,18 @@ def batch_geometry(n: int, batch_size: int) -> Tuple[int, int, int]:
     return steps, bs, steps * bs - n
 
 
-def _pad_batches(X, y, batch_size: int):
+def pad_weights(n: int, n_pad: int, w=None):
+    """The weight of every sample slot of a padded training set: ``w``
+    (default: ones ``(n,)``) then zeros for the ``n_pad`` padding samples."""
+    w = jnp.ones((n,), jnp.float32) if w is None else jnp.asarray(w, jnp.float32)
+    return jnp.concatenate([w, jnp.zeros((n_pad,) + w.shape[1:], jnp.float32)])
+
+
+def _pad_batches(X, y, batch_size: int, w=None):
     """Pad to a whole number of batches; returns (X, y, w, steps, bs)."""
     n = X.shape[0]
     steps, bs, n_pad = batch_geometry(n, batch_size)
-    w = jnp.concatenate([jnp.ones((n,), jnp.float32), jnp.zeros((n_pad,), jnp.float32)])
+    w = pad_weights(n, n_pad, w)
     if n_pad:
         X = jnp.concatenate([X, jnp.zeros((n_pad,) + X.shape[1:], X.dtype)])
         y = jnp.concatenate([y, jnp.zeros((n_pad,) + y.shape[1:], y.dtype)])
@@ -189,7 +203,7 @@ def make_epoch_fn(loss_fn: Callable, tx: optax.GradientTransformation,
             perm = jnp.arange(n_total)
         xb = X[perm].reshape((steps, bs) + X.shape[1:])
         yb = y[perm].reshape((steps, bs) + y.shape[1:])
-        wb = w[perm].reshape(steps, bs)
+        wb = w[perm].reshape((steps, bs) + w.shape[1:])
 
         def step(c, batch):
             p, s = c
@@ -315,11 +329,14 @@ _fit_jit = compile_plane.jit(
 
 def fit(module, X, y, cfg: TrainConfig,
         rng: Optional[jax.Array] = None,
-        params: Optional[Any] = None) -> Tuple[Any, np.ndarray]:
+        params: Optional[Any] = None,
+        w: Optional[Any] = None) -> Tuple[Any, np.ndarray]:
     """Fit ``module`` on (X, y); returns (params, per-epoch loss history).
 
     The whole multi-epoch loop compiles to a single XLA executable; repeat
-    fits with the same shapes/config reuse the compiled program.
+    fits with the same shapes/config reuse the compiled program.  ``w``
+    weighs the samples (or a sequence model's positions, see
+    :func:`make_loss_fn`); by default every sample weighs 1.
     """
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     X_in, y_in, params_in = X, y, params
@@ -328,7 +345,7 @@ def fit(module, X, y, cfg: TrainConfig,
     if params is None:
         init_rng, rng = jax.random.split(rng)
         params = init_params(module, init_rng, X[:1])
-    Xp, yp, w, steps, bs = _pad_batches(X, y, cfg.batch_size)
+    Xp, yp, w, steps, bs = _pad_batches(X, y, cfg.batch_size, w)
     # _fit_jit donates params/X/y/w; a donated buffer is deleted, so never
     # hand over one the CALLER may still hold.  jnp.asarray copies host
     # arrays and padding copies device arrays — only an unpadded

@@ -1,0 +1,394 @@
+"""The ``kimi_linear`` backbone (``models/factories/backbone.py``) against
+its plain reference (``benchmark/reference/kimi_linear.py``) at a tiny
+preset: hidden 64, 2 heads of 16, 8 experts of which 2 held, sequences of
+32 rows, KDA chunks of 8.  Float32 on the CPU, so agreement is tight; a
+bfloat16 control has to fail the same tolerance."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.kinds import sequence_build as kind  # noqa: E402
+from benchmark.reference import kimi_linear as reference  # noqa: E402
+from gordo_tpu import telemetry  # noqa: E402
+from gordo_tpu.models.estimator import LSTMForecast, SequenceForecast  # noqa: E402
+from gordo_tpu.models.factories import backbone  # noqa: E402
+from gordo_tpu.ops.windows import (  # noqa: E402
+    make_sequences, make_windows, num_sequences, sequences_to_rows,
+)
+
+TINY = dict(hidden_size=64, num_heads=2, kda_head_dim=16, kda_gate_rank=16,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            intermediate_size=128, moe_intermediate_size=32, num_experts=8,
+            num_experts_per_token=2, experts_held=2, experts_held_from=0,
+            num_layers=2, full_attn_every=2)
+F = 5
+SEED = 11
+# float32 against float32 on the CPU: the two fits' changes from the common
+# start are 1e-4 of a change apart (measured here)
+UPDATE_GAP = 3e-3
+
+
+def module_of(**over):
+    return backbone.kimi_linear(F, F, compute_dtype="float32", kda_chunk=8,
+                                **{**TINY, **over})
+
+
+def shape_of(**over):
+    return reference.shape_of({"kind": "kimi_linear", **TINY, **over}, F, F)
+
+
+def start(module, shape):
+    """The program's and the reference's initial weights from one seed."""
+    init_key, _ = jax.random.split(jax.random.PRNGKey(SEED))
+    params = module.init(init_key, jnp.zeros((1, 32, F)))["params"]
+    ref_params, _ = reference.init_params(SEED, shape)
+    return params, ref_params
+
+
+@pytest.fixture(scope="module")
+def x():
+    return jax.random.normal(jax.random.PRNGKey(1), (3, 32, F))
+
+
+# -- 1. forward --------------------------------------------------------------
+
+def test_forward_matches_the_reference_and_a_bfloat16_control_does_not(x):
+    module, shape = module_of(), shape_of()
+    params, ref_params = start(module, shape)
+    assert set(params) == set(ref_params)
+    for name in params:
+        np.testing.assert_allclose(params[name], ref_params[name], atol=1e-6,
+                                   err_msg=name)
+    made = module.apply({"params": params}, x)
+    ref = reference.forward(ref_params, x, shape)
+    low = reference.forward(ref_params, x, shape, reference.bfloat16)
+    tolerance = 1e-4 * float(jnp.abs(ref).max())
+    assert float(jnp.abs(made - ref).max()) < tolerance
+    assert float(jnp.abs(low - ref).max()) > tolerance
+
+
+def test_the_module_says_it_has_no_packed_layout():
+    from gordo_tpu.train.fit import TrainConfig, packed_layout
+
+    assert packed_layout(module_of(), TrainConfig()) is False
+    assert module_of().fleet_axis == "map"
+    assert module_of().param_count() == sum(
+        int(np.prod(s)) for _, s, _ in backbone.param_specs(module_of().cfg))
+
+
+# -- 2. the chunked delta rule -------------------------------------------------
+
+def test_chunked_kda_matches_the_recurrence_across_chunks_and_decays():
+    """Four chunks of 8; channels that forget almost everything in a step
+    (alpha = e^-12) beside channels that forget almost nothing."""
+    b, h, t, dk = 2, 2, 32, 16
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    q, k, v = (jax.random.normal(key, (b, t, h, dk)) for key in keys[:3])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -jnp.exp(jax.random.uniform(keys[3], (b, t, h, dk), minval=-9.0, maxval=0.5))
+    g = g.at[..., :4].set(-12.0).at[..., 4:8].set(-1e-4)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, h)))
+    ref = reference.kda_recurrence(q, k, v, jnp.exp(g), beta)
+    to_heads = lambda a: jnp.moveaxis(a, 2, 1)  # noqa: E731
+    made = backbone.kda_chunked(*(to_heads(a) for a in (q, k, v, g, beta)),
+                                chunk=8, cd=jnp.float32)
+    np.testing.assert_allclose(jnp.moveaxis(made, 1, 2), ref, atol=2e-6)
+    assert float(jnp.abs(ref).max()) > 0.05
+
+
+def test_gradient_of_every_kda_parameter_matches_the_references(x):
+    module, shape = module_of(num_layers=1), shape_of(num_layers=1)
+    params, ref_params = start(module, shape)
+    made = jax.grad(lambda p: jnp.mean(module.apply({"params": p}, x) ** 2))(params)
+    ref = jax.grad(lambda p: jnp.mean(reference.forward(p, x, shape) ** 2))(ref_params)
+    kda = [name for name in params if name.startswith("kda_")]
+    assert len(kda) == 15
+    for name in kda:
+        scale = float(jnp.abs(ref[name]).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(made[name], ref[name], atol=2e-4 * scale,
+                                   err_msg=name)
+
+
+LAYERED = dict(num_layers=6, full_attn_every=3, first_k_dense_replace=2)
+
+
+def test_every_gradient_of_a_layered_model_matches_the_references(x):
+    """Six layers, two of them dense and two MLA: the leading dense layers
+    traced one by one, the four expert layers as one scan whose body takes
+    its layer's mixer out of the stacks and chooses it by ``lax.cond``, the
+    mixers' backward written out.  Every parameter's gradient, layer by
+    layer of every stack, against autodiff through the plain reference."""
+    module, shape = module_of(**LAYERED), shape_of(**LAYERED)
+    cfg = module.cfg
+    assert [cfg.mixer(l) for l in range(1, 7)] == ["kda", "kda", "mla", "kda", "kda", "mla"]
+    assert cfg.layers_of("dense") == (1, 2) and cfg.moe_layers == (3, 4, 5, 6)
+    params, ref_params = start(module, shape)
+    assert {name: value.shape for name, value in params.items()} == {
+        name: value.shape for name, value in ref_params.items()}
+    assert params["kda_wq"].shape[0] == 4 and params["mla_wq"].shape[0] == 2
+    assert params["dense_wg"].shape[0] == 2 and params["moe_wg"].shape[:2] == (4, 2)
+    np.testing.assert_allclose(
+        module.apply({"params": params}, x), reference.forward(ref_params, x, shape),
+        atol=2e-5)
+    made = jax.jit(jax.grad(lambda p: jnp.mean(module.apply({"params": p}, x) ** 2)))(params)
+    ref = jax.grad(lambda p: jnp.mean(reference.forward(p, x, shape) ** 2))(ref_params)
+    for name in params:
+        for slot in range(params[name].shape[0] if params[name].ndim > 1 else 1):
+            m, r = (made[name][slot], ref[name][slot]) if params[name].ndim > 1 else (
+                made[name], ref[name])
+            scale = float(jnp.abs(ref[name]).max())
+            assert scale > 0, name
+            np.testing.assert_allclose(m, r, atol=5e-4 * scale, err_msg=f"{name}[{slot}]")
+
+
+# -- 3. the shares add up ------------------------------------------------------
+
+def layer_params(key, cfg):
+    """One expert layer's parameters: a slice of the stack's shapes."""
+    specs = [(n, s[1:], i) for n, s, i in backbone.param_specs(cfg) if n.startswith("moe_")]
+    keys = jax.random.split(key, len(specs))
+    return {n: backbone._initializer(i)(k, s) for (n, s, i), k in zip(specs, keys)}
+
+
+def test_the_four_shares_of_two_experts_add_up_to_the_uncut_layer():
+    whole = module_of(experts_held=8).cfg
+    p = layer_params(jax.random.PRNGKey(5), whole)
+    xs = jax.random.normal(jax.random.PRNGKey(6), (40, whole.hidden_size))
+    shared = backbone.swiglu(xs, p["moe_shared_wg"], p["moe_shared_wu"],
+                             p["moe_shared_wd"], jnp.float32)
+    total, tokens = shared, []
+    for first in (0, 2, 4, 6):
+        cfg = module_of(experts_held=2, experts_held_from=first).cfg
+        mine = {**p, **{n: p[n][first:first + 2] for n in ("moe_wg", "moe_wu", "moe_wd")}}
+        y, counted = backbone.expert_layer(cfg, mine, xs)
+        total = total + (y - shared)       # the shared expert counted once
+        tokens.append(counted)
+    ref = reference._experts(dict(shape_of(experts_held=8)), p, xs, None)
+    np.testing.assert_allclose(total, ref, atol=1e-5)
+    # every selected pair fell on exactly one share
+    assert int(jnp.sum(jnp.stack(tokens))) == 40 * whole.num_experts_per_token
+
+
+# -- 4. nothing is dropped -------------------------------------------------------
+
+def test_every_position_on_the_same_two_experts_drops_nothing():
+    cfg = module_of().cfg
+    p = layer_params(jax.random.PRNGKey(7), cfg)
+    router = jnp.full_like(p["moe_router"], -10.0).at[:, :2].set(10.0)
+    p = {**p, "moe_router": router}
+    xs = jnp.abs(jax.random.normal(jax.random.PRNGKey(8), (64, cfg.hidden_size)))
+    y, tokens = backbone.expert_layer(cfg, p, xs)
+    assert tokens.tolist() == [64, 64]      # all 128 pairs, no capacity
+    ref = reference._experts(dict(shape_of()), p, xs, None)
+    np.testing.assert_allclose(y, ref, atol=1e-5)
+    # and none on the held experts: the shared expert alone
+    y, tokens = backbone.expert_layer(
+        module_of(experts_held_from=4).cfg, p, xs)
+    assert tokens.tolist() == [0, 0]
+    np.testing.assert_allclose(y, backbone.swiglu(
+        xs, p["moe_shared_wg"], p["moe_shared_wu"], p["moe_shared_wd"], jnp.float32),
+        atol=1e-6)
+
+
+def test_the_forward_pass_counts_what_it_routed(x):
+    module = module_of()
+    params, _ = start(module, shape_of())
+    y, counts = module.apply({"params": params}, x, counts=True)
+    np.testing.assert_allclose(y, module.apply({"params": params}, x))
+    assert counts["tokens"].shape == (1, 2)     # one expert layer, two held
+    assert int(counts["selected"]) == 3 * 32 * 2
+    assert int(counts["held"]) == int(counts["tokens"].sum()) <= int(counts["selected"])
+
+
+# -- 6. sequences ----------------------------------------------------------------
+
+@pytest.mark.parametrize("n_rows", [2, 20, 33, 34, 49, 50, 100])
+def test_every_row_from_offset_is_forecast_once_and_padding_weighs_nothing(n_rows):
+    context, stride = 32, 16
+    rows = jnp.arange(n_rows, dtype=jnp.float32)[:, None] * jnp.ones((1, 2))
+    inputs, targets, weights = make_sequences(rows, rows + 0.5, context, stride)
+    s = num_sequences(n_rows, context, stride)
+    assert inputs.shape == (s, context, 2) and weights.shape == (s, context)
+    real = weights == 1
+    assert set(np.unique(weights)) <= {0.0, 1.0}
+    # a real position reads row r and is asked for row r + 1; padding is zeros
+    np.testing.assert_array_equal(targets[real], inputs[real] + 1.5)
+    assert float(jnp.abs(inputs[~real]).sum()) == 0 and float(jnp.abs(targets[~real]).sum()) == 0
+    # the last row is nobody's input, every other row is somebody's
+    assert set(np.asarray(inputs[real][:, 0]).tolist()) == set(range(n_rows - 1))
+    # an identity "model": the rows come back as rows 0 .. n_rows - 2, once each
+    back = sequences_to_rows(inputs, n_rows, context, stride)
+    np.testing.assert_array_equal(back[:, 0], np.arange(n_rows - 1))
+
+
+def test_lstm_forecast_windows_are_what_they_were():
+    X = jnp.arange(40, dtype=jnp.float32).reshape(20, 2)
+    est = LSTMForecast(kind="lstm_symmetric", lookback_window=4)
+    np.testing.assert_array_equal(est._make_inputs(X), make_windows(X[:-1], 4))
+    np.testing.assert_array_equal(est._make_targets(X, None), X[4:])
+    assert est.offset == 4 and est._sample_weights(X) is None
+    assert est._rows_from_outputs(X, 20) is X
+
+
+# -- 5. and 7.: a project through build_project -----------------------------------
+
+def tiny_config():
+    with open(os.path.join(ROOT, "benchmark", "configs", "kimi-linear-plant.json")) as fh:
+        config = json.load(fh)
+    config["model"].update(context=32, stride=16, batch_size=4, kda_chunk=8, **TINY)
+    config["dataset"].update(
+        n_tags=F, train_end_date="2017-01-02T12:00:00+00:00", rows=217)
+    return config
+
+
+@pytest.fixture(scope="module")
+def built(tmp_path_factory):
+    """Two machines through ``build_project`` with NO ``max_bucket_size``:
+    the planner reads the parameter count and puts both in one chunk."""
+    from gordo_tpu.builder.fleet_build import build_project
+    from gordo_tpu.workflow.config import NormalizedConfig
+
+    config = tiny_config()
+    out = str(tmp_path_factory.mktemp("backbone-project"))
+    doc = kind.project_doc(config, SEED, 2)
+    machines = NormalizedConfig(doc, "backbone-test").machines
+    before = telemetry.REGISTRY.snapshot()["metrics"]
+    result = build_project(machines, out, artifact_format="v2")
+    after = telemetry.REGISTRY.snapshot()["metrics"]
+    return config, out, result, before, after
+
+
+def counter(snapshot, name):
+    return sum((snapshot.get(name) or {"series": {}})["series"].values())
+
+
+def test_two_machines_build_in_one_chunk_and_match_the_reference(built):
+    config, out, result, _, _ = built
+    summary = result.summary()
+    assert not summary["failed"] and summary["single_built"] == 0
+    assert summary["demoted"]["machines"] == 0
+    assert len(result.timeline) == 1          # one chunk of two machines
+    for i, name in enumerate(kind.machine_names(SEED, 2)):
+        made = kind.produced(out, name)
+        ref = kind.reference_of(config, kind.reference_rows(config, name),
+                                kind.model_seed(SEED), folds=i == 0)
+        numbers = kind.compare(made, ref)
+        assert numbers["loss_first_gap"] < 1e-5 and numbers["loss_last_gap"] < 1e-5
+        assert numbers["update_norm_gap"] < UPDATE_GAP and numbers["nonfinite"] == 0
+        if i == 0:
+            assert numbers["threshold_gap"] < 1e-4
+
+
+def test_a_half_billion_parameter_model_is_a_chunk_of_one():
+    from gordo_tpu import serializer
+    from gordo_tpu.builder.fleet_build import _parameter_count, default_bucket_size
+    from gordo_tpu.parallel.anomaly import analyze_definition
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "kimi-linear-plant.json")) as fh:
+        config = json.load(fh)
+    doc = kind.project_doc(config, SEED, 1)
+    spec = analyze_definition(serializer.from_definition(doc["globals"]["model"]))
+    assert _parameter_count(spec, (50, 50)) == config["parameters"] == 508292018
+    assert default_bucket_size(spec, (50, 50)) == 1
+    tiny = kind.project_doc(tiny_config(), SEED, 1)
+    spec = analyze_definition(serializer.from_definition(tiny["globals"]["model"]))
+    assert default_bucket_size(spec, (F, F)) == 512
+
+
+def test_the_routing_counters_and_the_artifacts_metadata(built):
+    from gordo_tpu import artifacts
+
+    _, out, _, before, after = built
+    selected = counter(after, "gordo_moe_selected_pairs_total") - counter(
+        before, "gordo_moe_selected_pairs_total")
+    held = counter(after, "gordo_moe_held_pairs_total") - counter(
+        before, "gordo_moe_held_pairs_total")
+    tokens = counter(after, "gordo_moe_tokens_total") - counter(
+        before, "gordo_moe_tokens_total")
+    # no pair can be dropped: every held pair was computed by a held expert
+    assert selected > 0 and 0 < held <= selected and tokens == held
+    labels = [json.loads(k) for k in after["gordo_moe_tokens_total"]["series"]]
+    assert sorted(labels) == [["2", "0"], ["2", "1"]]   # layer 2, experts 0 and 1
+    _, refs = artifacts.discover(out)
+    moe = refs[0].load_metadata()["model"]["cross_validation"]["moe"]
+    assert moe["held_pairs"] == int(np.sum(moe["tokens_per_held_expert"]))
+    assert moe["selected_pairs"] >= moe["held_pairs"]
+
+
+def test_the_artifact_round_trips_and_scores(built):
+    from gordo_tpu import artifacts
+
+    config, out, _, _, _ = built
+    _, refs = artifacts.discover(out)
+    by_name = {ref.name: ref for ref in refs}
+    name = kind.machine_names(SEED, 2)[1]
+    detector = by_name[name].load_model()
+    estimator = detector.base_estimator.steps[-1][1]
+    assert isinstance(estimator, SequenceForecast) and estimator.offset == 1
+    rows = kind.reference_rows(config, name)
+    frame = detector.anomaly(rows, rows)
+    assert len(frame) == len(rows) - 1
+    assert np.isfinite(frame[("total-anomaly-score", "")].to_numpy()).all()
+    # the estimator's own predict is the reference's forecast
+    scaled = reference.minmax(rows, rows)
+    shape = reference.shape_of(config["model"], F, F)
+    ref = reference.predict(
+        jax.tree.map(jnp.asarray, estimator.params_), rows, rows, config["model"], shape)
+    np.testing.assert_allclose(estimator.predict(scaled), ref, atol=1e-4)
+
+
+def test_the_stacked_the_streaming_and_the_backfill_planes_refuse_it_by_name(built):
+    from gordo_tpu import artifacts
+    from gordo_tpu.serve.fleet_scorer import FleetScorer
+    from gordo_tpu.serve.scorer import (
+        CompiledScorer, SequenceModelUnsupported, refuse_sequence_model,
+    )
+    from gordo_tpu.serve.stream import MachineStream
+
+    _, out, _, _, _ = built
+    _, refs = artifacts.discover(out)
+    models = {ref.name: ref.load_model() for ref in refs}
+    name = sorted(models)[0]
+    with pytest.raises(SequenceModelUnsupported, match="FleetScorer.*SequenceForecast"):
+        FleetScorer.from_models(models)
+    scorer = CompiledScorer(models[name], machine=name)
+    assert not scorer.fused        # falls back to the detector's own anomaly()
+    with pytest.raises(SequenceModelUnsupported, match="MachineStream.*SequenceForecast"):
+        MachineStream(name, scorer)
+    with pytest.raises(SequenceModelUnsupported, match="backfill.*SequenceForecast"):
+        refuse_sequence_model(models[name], name, "the backfill runner")
+
+
+# -- satellites ---------------------------------------------------------------------
+
+def test_a_list_valued_estimator_argument_buckets():
+    from gordo_tpu import serializer
+    from gordo_tpu.parallel.anomaly import analyze_definition
+
+    def spec_of(dims):
+        return analyze_definition(serializer.from_definition({
+            "gordo_tpu.anomaly.diff.DiffBasedAnomalyDetector": {"base_estimator": {
+                "gordo_tpu.pipeline.Pipeline": {"steps": [
+                    "gordo_tpu.ops.scalers.MinMaxScaler",
+                    {"gordo_tpu.models.estimator.LSTMAutoEncoder": {
+                        "kind": "lstm_symmetric", "lookback_window": 3,
+                        "dims": dims, "epochs": 1}}]}}}}))
+
+    a, b = spec_of([256, 128, 64]), spec_of([256, 128, 64])
+    assert hash(a.signature) == hash(b.signature) and a.signature == b.signature
+    assert a.signature != spec_of([128, 64]).signature
+    buckets = {}
+    buckets.setdefault((a.signature, (5, 5)), []).append("m0")
+    buckets.setdefault((b.signature, (5, 5)), []).append("m1")
+    assert list(buckets.values()) == [["m0", "m1"]]
