@@ -16,12 +16,14 @@ and projects to the output features, matching the reference's 2D
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Sequence, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from gordo_tpu import telemetry
 from gordo_tpu.models.factories.feedforward import (
     _broadcast_funcs,
     resolve_activation,
@@ -88,6 +90,56 @@ class _LSTMCellParams(nn.Module):
         return cell
 
 
+_LSTM_BACKWARD = telemetry.counter(
+    "gordo_lstm_backward_total",
+    "Backward passes of an LSTM layer traced, by the rule that gives them: "
+    "written (the hand-written rule of _fused_lstm_layer)",
+    labels=("rule",),
+)
+
+
+def _forward(x, kernel_i, kernel_h, bias, cd, save: bool):
+    """One layer's input projection and its scan over time.  Returns ``hs``
+    time-major ``(T, B, H)`` float32 and, with ``save``, what the backward
+    needs of every time step, time-major too: the four gates BEFORE their
+    non-linearities, the one ``(T, B, 4H)`` array ``z`` in ``cd`` they are
+    computed as, and the cell state each step STARTED from, ``(T, B, H)``
+    float32 (so the backward reads ``c_{t-1}`` where it stands and
+    recomputes ``c_t``).  Not the gates after their non-linearities: a
+    sigmoid near 1 rounded to bfloat16 leaves ``1 - o`` a quarter off, and
+    the output gate of a trained last layer sits there.
+
+    Step math mirrors ``OptimizedLSTMCell`` exactly (same concat order,
+    same dtype promotion: gates in ``cd``, carries promoted to float32 by
+    the elementwise ops)."""
+    xp = x.astype(cd) @ kernel_i.astype(cd)         # (B, T, 4H), one GEMM
+    kernel_h = kernel_h.astype(cd)
+    bias = bias.astype(cd)
+    batch, features = x.shape[0], kernel_h.shape[0]
+    c0 = jnp.zeros((batch, features), jnp.float32)  # flax carries are f32
+    h0 = jnp.zeros((batch, features), jnp.float32)
+
+    def step(carry, xp_t):
+        c_in, h = carry
+        z = (h.astype(cd) @ kernel_h + bias) + xp_t  # dense_h + dense_i
+        i, f, g, o = jnp.split(z, 4, axis=-1)
+        i, f, o = nn.sigmoid(i), nn.sigmoid(f), nn.sigmoid(o)
+        g = nn.tanh(g)
+        c = f * c_in + i * g       # promotes to f32 against the f32 carry
+        h = o * jnp.tanh(c)
+        return (c, h), ((h, z, c_in) if save else h)
+
+    # plain scan, no unroll: XLA does not fuse across the recurrence.  By
+    # the count of the program compiled for a v5e (the tool is
+    # scripts/fleet_program_ops.py) a time step is 4-9 operations here and
+    # 8-11 in the written backward, where autodiff's were 7-12 and 11-14
+    # (ISSUE 30); unrolling was counted on autodiff's (ISSUE 27: unroll=3
+    # is 10-23 operations a time step for 13, unroll=12 twice the compile)
+    _, out = jax.lax.scan(step, (c0, h0), jnp.swapaxes(xp, 0, 1))
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _fused_lstm_layer(
     x: jnp.ndarray,
     kernel_i: jnp.ndarray,
@@ -103,37 +155,92 @@ def _fused_lstm_layer(
     Here all T input projections run as ONE ``(B·T, F) @ (F, 4H)`` GEMM
     before the scan (under the fleet vmap: a batched GEMM over machines —
     the MXU-shaped form), and each scan step only pays the unavoidable
-    recurrent ``(B, H) @ (H, 4H)``.
+    recurrent ``(B, H) @ (H, 4H)``.  Results match the flax cell.
 
-    Step math mirrors ``OptimizedLSTMCell`` exactly (same concat order,
-    same dtype promotion: gates in ``compute_dtype``, carries promoted to
-    float32 by the elementwise ops), so results match the flax cell.
+    Its backward is written out (:func:`_fused_lstm_layer_bwd`) on the same
+    principle: the reverse scan keeps only what is sequential, and every
+    weight gradient is one GEMM over all time steps after it.
+    """
+    hs = _forward(x, kernel_i, kernel_h, bias, compute_dtype, save=False)
+    return jnp.swapaxes(hs, 0, 1)                   # (B, T, H)
+
+
+def _fused_lstm_layer_fwd(x, kernel_i, kernel_h, bias, features, compute_dtype):
+    """The forward as above, operation for operation, that also keeps three
+    stacks for the backward: ``z``, the cell states and ``hs`` itself."""
+    hs, z, c_in = _forward(x, kernel_i, kernel_h, bias, compute_dtype, save=True)
+    return jnp.swapaxes(hs, 0, 1), (x, kernel_i, kernel_h, bias, (hs, z, c_in))
+
+
+def _fused_lstm_layer_bwd(features, compute_dtype, residuals, d_out):
+    """What autodiff through the scan would give, arranged for the device.
+
+    Autodiff stacks every gate's value and derivative, the cell state,
+    its tanh and the cast of ``h`` apart (zero-filled before the loop,
+    copied after it) and forms the cotangent of ``kernel_h`` and of
+    ``bias`` at every time step into an accumulator carried through the
+    reverse loop.  Here the reverse scan carries ``(dh, dc)`` alone,
+    recomputes a time step's gates and ``tanh(c_t)`` in float32 from the
+    saved ``z_t`` and ``c_{t-1}``, turns them into ``dz_t`` and pays the one
+    product that is truly sequential, ``dh_{t-1} = dz_t @ kernel_hᵀ``; the
+    weight, input and bias cotangents are three GEMMs and a sum over the
+    whole ``dz`` stack (under the fleet vmap batched over machines,
+    contracting ``T·B`` rows at once).  Matmul operands are ``cd`` as in
+    the forward; every accumulation is float32.
     """
     cd = compute_dtype
-    xp = x.astype(cd) @ kernel_i.astype(cd)         # (B, T, 4H), one GEMM
-    kernel_h = kernel_h.astype(cd)
-    bias = bias.astype(cd)
-    batch = x.shape[0]
-    c0 = jnp.zeros((batch, features), jnp.float32)  # flax carries are f32
-    h0 = jnp.zeros((batch, features), jnp.float32)
+    f32 = jnp.float32
+    x, kernel_i, kernel_h, bias, stacks = residuals
+    hs, z, c_in = stacks
+    # runs where a gradient through the layer is traced
+    _LSTM_BACKWARD.inc(1.0, "written")
+    telemetry.add_to_span(lstm_backward_traces=1, lstm_saved_stacks=len(stacks))
+    kh = kernel_h.astype(cd)
+    into_h = (((1,), (1,)), ((), ()))               # (B, 4H) · (H, 4H)ᵀ
 
-    def step(carry, xp_t):
-        c, h = carry
-        z = (h.astype(cd) @ kernel_h + bias) + xp_t  # dense_h + dense_i
-        i, f, g, o = jnp.split(z, 4, axis=-1)
+    def step(carry, saved):
+        dh, dc = carry
+        z_t, c_in_t, d_out_t = saved
+        i, f, g, o = jnp.split(z_t.astype(f32), 4, axis=-1)
         i, f, o = nn.sigmoid(i), nn.sigmoid(f), nn.sigmoid(o)
         g = nn.tanh(g)
-        c = f * c + i * g          # promotes to f32 against the f32 carry
-        h = o * jnp.tanh(c)
-        return (c, h), h
+        tanh_c = jnp.tanh(f * c_in_t + i * g)
+        dh = dh + d_out_t
+        dc = dc + dh * o * (1.0 - tanh_c * tanh_c)
+        dz = jnp.concatenate([
+            dc * g * i * (1.0 - i),
+            dc * c_in_t * f * (1.0 - f),
+            dc * i * (1.0 - g * g),
+            dh * tanh_c * o * (1.0 - o),
+        ], axis=-1).astype(cd)
+        dh = jax.lax.dot_general(dz, kh, into_h, preferred_element_type=f32)
+        return (dh, dc * f), dz
 
-    # plain scan, no unroll: XLA does not fuse across the recurrence.  By
-    # the count of the program compiled for a v5e (ISSUE 27; the tool is
-    # scripts/fleet_program_ops.py) unroll=3 is 10-23 operations a time
-    # step for 13, and unroll=12 an optimiser step of 2,843 operations for
-    # 3,400 at twice the compile (hourglass), 3,417 for 3,190 (symmetric)
-    _, hs = jax.lax.scan(step, (c0, h0), jnp.swapaxes(xp, 0, 1))
-    return jnp.swapaxes(hs, 0, 1)                   # (B, T, H)
+    zero = jnp.zeros(hs.shape[1:], f32)
+    _, dz = jax.lax.scan(
+        step, (zero, zero),
+        (z, c_in, jnp.swapaxes(d_out, 0, 1)), reverse=True)
+
+    over_steps = (((0, 1), (0, 1)), ((), ()))       # contract T and B at once
+    # h_{t-1} is hs shifted by a step and h_{-1} = 0: leave that term out
+    d_kernel_h = jax.lax.dot_general(
+        hs[:-1].astype(cd), dz[1:], over_steps, preferred_element_type=f32)
+    d_kernel_i = jax.lax.dot_general(
+        x.astype(cd), dz, (((1, 0), (0, 1)), ((), ())),
+        preferred_element_type=f32)
+    dx = jax.lax.dot_general(
+        dz, kernel_i.astype(cd), (((2,), (1,)), ((), ())),
+        preferred_element_type=f32)                 # (T, B, F)
+    d_bias = jnp.sum(dz, axis=(0, 1), dtype=f32)
+    return (
+        jnp.swapaxes(dx, 0, 1).astype(x.dtype),
+        d_kernel_i.astype(kernel_i.dtype),
+        d_kernel_h.astype(kernel_h.dtype),
+        d_bias.astype(bias.dtype),
+    )
+
+
+_fused_lstm_layer.defvjp(_fused_lstm_layer_fwd, _fused_lstm_layer_bwd)
 
 
 class LSTMAutoEncoderModule(nn.Module):

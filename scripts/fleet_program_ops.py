@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import dataclasses
 import json
 import os
 import re
@@ -82,7 +83,7 @@ def _opcode(line: str) -> Optional[str]:
 
 def parse_hlo(text: str) -> Dict[str, Dict[str, Any]]:
     """``{computation: {"ops": Counter, "whiles": [(body, trip|None)],
-    "entry": bool}}`` of an HLO module's text.  A trip count is the
+    "entry": bool, "names": [instruction]}}`` of an HLO module's text.  A trip count is the
     instruction's ``known_trip_count`` or, as the TPU compiler's text has
     none, the constant its condition holds the counter under (a scan
     counts up from 0)."""
@@ -94,7 +95,7 @@ def parse_hlo(text: str) -> Dict[str, Dict[str, Any]]:
             cur = comps[head.group(1)] = {
                 "ops": collections.Counter(), "whiles": [],
                 "entry": line.startswith("ENTRY"),
-                "constants": {}, "limit": None,
+                "constants": {}, "limit": None, "names": [],
             }
             continue
         if cur is None:
@@ -103,6 +104,9 @@ def parse_hlo(text: str) -> Dict[str, Dict[str, Any]]:
             cur = None
             continue
         opcode = _opcode(line)
+        if opcode is not None:
+            cur["names"].append(
+                line.partition(" = ")[0].strip().removeprefix("ROOT ").lstrip("%"))
         if opcode == "constant":
             const = _CONSTANT.search(line)
             if const:
@@ -151,11 +155,14 @@ def while_tree(comps: Dict[str, Dict[str, Any]]) -> List[Dict[str, Any]]:
     return rows
 
 
-def cell_program(manifest, cell, unit: str):
+def cell_program(manifest, cell, unit: str, epochs: Optional[int] = None,
+                 max_steps: Optional[int] = None):
     """``(jitted, shapes, steps)`` of the cell's whole ``fleet.exact`` or of
     its final fit alone (the longest of the program's four), vmapped over
     the chunk's machines; ``shapes`` are the arguments as
-    ``ShapeDtypeStruct``s, still without a device."""
+    ``ShapeDtypeStruct``s, still without a device.  ``epochs`` and
+    ``max_steps`` shorten the fit alone (the same step, fewer of them: what
+    ``scripts/fit_step_chip.py`` traces)."""
     import jax
     import jax.numpy as jnp
 
@@ -177,7 +184,11 @@ def cell_program(manifest, cell, unit: str):
                      int(ds["rows"]), int(ds["n_tags"]))
     ctx = builder._group_context(rows, tags, tags)
     cfg = spec.train_cfg
+    if epochs is not None:
+        cfg = dataclasses.replace(cfg, epochs=epochs)
     windows = rows - ctx.offset
+    if max_steps is not None:
+        windows = min(windows, max_steps * cfg.batch_size)
     steps, bs, n_pad = batch_geometry(windows, cfg.batch_size)
     f32, shape = jnp.float32, jax.ShapeDtypeStruct
     if unit == "program":

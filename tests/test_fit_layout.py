@@ -313,7 +313,12 @@ def test_the_layout_is_counted_where_the_program_is_traced(builds, layout, leave
     directory = built["out"] / telemetry.SNAPSHOT_DIR
     (snapshot,) = telemetry.load_snapshot_dir(str(directory))
     assert "gordo_fit_layout_total" in json.dumps(snapshot)
+    assert "gordo_lstm_backward_total" in json.dumps(snapshot)
     rows = json.loads((directory / "timeline-000-of-001.json").read_text())["chunks"]
-    assert rows[0]["counts"]["enqueue"] == {"fit_traces": 4, "carry_leaves": carry}
+    # either layout differentiates through the layers' written backward
+    # (ISSUE 30): six layers in each of the four fits, three stacks kept by each
+    assert rows[0]["counts"]["enqueue"] == {
+        "fit_traces": 4, "carry_leaves": carry,
+        "lstm_backward_traces": 24, "lstm_saved_stacks": 72}
     assert "enqueue" not in rows[1]["counts"]
     assert rows[1]["counts"]["stage"]["leaves"] == 3
