@@ -295,102 +295,19 @@ def bench_build(mesh, out: dict) -> float:
     return rates[-1]
 
 
-def bench_build_pipeline(mesh, out: dict) -> None:
-    """ISSUE 4 acceptance: serial-vs-pipelined project builds.
-
-    Same machine set, same chunking; the kill-switch path
-    (``pipeline=False``) is the baseline.  Chunk sizes force multiple
-    chunks per project so the pipeline has stages to overlap.  Protocol:
-    one warmup run per mode (compiles land), then 4 PAIRED alternating
-    rounds (serial, pipelined, serial, ...) with per-mode BEST (min
-    time) standing — timing noise on this shared container is one-sided
-    contamination (a background burst can add 30% to a single run,
-    nothing can make one faster than the true floor), so min() estimates
-    the uncontaminated time; best-of pairing is the same discipline the
-    coalesced-vs-direct serving points use.  The stage-occupancy
-    telemetry emitted during the pipelined runs is attested into the
-    result doc.
-    """
-    from gordo_tpu import telemetry
-    from gordo_tpu.builder.fleet_build import build_project
-
-    def timed(machines, bucket, pipe, label) -> float:
-        out_dir = tempfile.mkdtemp(prefix=f"gordo-bench-pipe-{label}-")
-        t0 = time.perf_counter()
-        result = build_project(
-            machines, out_dir, mesh=mesh, max_bucket_size=bucket,
-            pipeline=pipe,
-        )
-        dt = time.perf_counter() - t0
-        shutil.rmtree(out_dir, ignore_errors=True)
-        if result.failed or len(result.artifacts) != len(machines):
-            raise RuntimeError(
-                f"build_pipeline {label}@{len(machines)}: "
-                f"{len(result.failed)} failed"
-            )
-        return dt
-
-    for n_machines, bucket in ((64, 16), (512, 64)):
-        machines = make_machines(n_machines, prefix=f"bench-pipe{n_machines}")
-        for pipe in (False, True):  # warmup: land the compiles
-            timed(machines, bucket, pipe, "warmup")
-        times = {"serial": [], "pipelined": []}
-        for rnd in range(4):
-            for label, pipe in (("serial", False), ("pipelined", True)):
-                dt = timed(machines, bucket, pipe, label)
-                times[label].append(dt)
-                log(f"build_pipeline {label}@{n_machines} round {rnd}: "
-                    f"{dt:.2f}s ({n_machines / dt * 3600.0:.0f} models/h)")
-        best = {label: min(ts) for label, ts in times.items()}
-        for label, t in best.items():
-            out[f"build_pipeline_{label}_models_per_hour_{n_machines}"] = (
-                round(n_machines / t * 3600.0, 1)
-            )
-        out[f"build_pipeline_speedup_{n_machines}"] = round(
-            best["serial"] / best["pipelined"], 4
-        )
-    # the pipelined runs must have emitted stage-occupancy telemetry; a
-    # scrape missing these names means the pipeline silently didn't run
-    scrape = telemetry.render()
-    wanted = (
-        "gordo_build_pipeline_stage_seconds",
-        "gordo_build_pipeline_stall_seconds",
-        "gordo_build_pipeline_writer_queue_depth",
-        "gordo_build_pipeline_chunks_total",
-    )
-    out["build_pipeline_telemetry_present"] = all(
-        name in scrape for name in wanted
-    )
-
-
 def bench_build_throughput(mesh, out: dict) -> None:
     """r23 acceptance: the dispatch/collect split of the build plane.
 
-    Same paired-alternating-best-of protocol as ``bench_build_pipeline``
-    (one warmup run per mode lands the compiles, then 4 alternating
-    serial/async rounds, per-mode BEST standing — min() rejects one-sided
-    timeshare contamination).  Two additions:
-
-    - per-stage attribution from the pipeline stage histogram deltas
-      around the best async round — dispatch (host-side launch), device
-      (dispatch→collect wall), fetch (blocking D2H), assemble
-      (per-machine detector unpacking), write, load — plus the new
-      ``gordo_build_device_idle_seconds`` occupancy counter, so the
-      remaining between-chunk gaps are measurable instead of inferred;
-    - an in-bench byte-parity attestation: one serial and one async
-      build of the same machines must produce identical artifacts
-      (params + metadata modulo wall-clock fields) and identical
-      registry keys, the same contract tests/test_dispatch_collect.py
-      pins.
-
-    1-core honesty: on this timeshared single-core container the
-    dispatch-behind-collect overlap cannot show as wall-clock win (host
-    assembly and "device" compute share the one core, so overlapped work
-    serializes anyway) — the CPU-measurable win here is the vectorized
-    collect side (pickle-clone assembly, partial D2H, ``tolist`` metadata)
-    and the speedup number reads as its lower bound; the overlap itself
-    can only show on an attached chip, where device compute is genuinely
-    asynchronous to the host (not measured).
+    One warmup run lands the compiles, then 4 rounds with the BEST
+    standing — timing noise on a shared container is one-sided
+    contamination (a background burst can add 30% to a single run,
+    nothing can make one faster than the true floor), so min() estimates
+    the uncontaminated time.  Per-stage attribution comes from the
+    pipeline stage histogram deltas around the best round — dispatch
+    (host-side launch), device (dispatch→collect wall), fetch (blocking
+    D2H), assemble (per-machine detector unpacking), write, load — plus
+    the ``gordo_build_device_idle_seconds`` occupancy counter, so the
+    remaining between-chunk gaps are measurable instead of inferred.
     """
     from gordo_tpu import telemetry
     from gordo_tpu.builder.fleet_build import build_project
@@ -404,21 +321,16 @@ def bench_build_throughput(mesh, out: dict) -> None:
             sums[json.loads(key)[0]] = float(v["sum"])
         return sums
 
-    def timed(machines, bucket, pipe, label, out_dir=None, reg=None):
-        keep = out_dir is not None
-        out_dir = out_dir or tempfile.mkdtemp(
-            prefix=f"gordo-bench-bt-{label}-"
-        )
+    def timed(machines, bucket, label):
+        out_dir = tempfile.mkdtemp(prefix=f"gordo-bench-bt-{label}-")
         before = stage_sums()
         t0 = time.perf_counter()
         result = build_project(
             machines, out_dir, mesh=mesh, max_bucket_size=bucket,
-            pipeline=pipe, model_register_dir=reg,
         )
         dt = time.perf_counter() - t0
         after = stage_sums()
-        if not keep:
-            shutil.rmtree(out_dir, ignore_errors=True)
+        shutil.rmtree(out_dir, ignore_errors=True)
         if result.failed or len(result.artifacts) != len(machines):
             raise RuntimeError(
                 f"build_throughput {label}@{len(machines)}: "
@@ -432,300 +344,22 @@ def bench_build_throughput(mesh, out: dict) -> None:
 
     n_machines, bucket = 512, 64
     machines = make_machines(n_machines, prefix=f"bench-bt{n_machines}")
-    for pipe in (False, True):  # warmup: land the compiles
-        timed(machines, bucket, pipe, "warmup")
-    times = {"serial": [], "async": []}
-    stage_attr = {"serial": None, "async": None}
-    idle = {"serial": None, "async": None}
+    timed(machines, bucket, "warmup")  # land the compiles
+    times = []
+    stage_attr = idle = None
     for rnd in range(4):
-        for label, pipe in (("serial", False), ("async", True)):
-            dt, stages, idle_s = timed(machines, bucket, pipe, label)
-            if not times[label] or dt < min(times[label]):
-                stage_attr[label] = stages  # attribution of the BEST round
-                idle[label] = round(idle_s, 4)
-            times[label].append(dt)
-            log(f"build_throughput {label}@{n_machines} round {rnd}: "
-                f"{dt:.2f}s ({n_machines / dt * 3600.0:.0f} models/h)")
-    best = {label: min(ts) for label, ts in times.items()}
-    for label, t in best.items():
-        out[f"build_throughput_{label}_models_per_hour_{n_machines}"] = (
-            round(n_machines / t * 3600.0, 1)
-        )
-    out[f"build_throughput_speedup_{n_machines}"] = round(
-        best["serial"] / best["async"], 4
+        dt, stages, idle_s = timed(machines, bucket, "async")
+        if not times or dt < min(times):
+            stage_attr = stages  # attribution of the BEST round
+            idle = round(idle_s, 4)
+        times.append(dt)
+        log(f"build_throughput async@{n_machines} round {rnd}: "
+            f"{dt:.2f}s ({n_machines / dt * 3600.0:.0f} models/h)")
+    out[f"build_throughput_async_models_per_hour_{n_machines}"] = (
+        round(n_machines / min(times) * 3600.0, 1)
     )
-    for label in ("serial", "async"):
-        out[f"build_throughput_stage_seconds_{label}"] = stage_attr[label]
-        out[f"build_throughput_device_idle_seconds_{label}"] = idle[label]
-    out["build_throughput_note"] = (
-        "1-core timeshare: overlap cannot move wall-clock here (host and "
-        "'device' share the core); speedup is the vectorized-collect "
-        "lower bound, dispatch overlap banked for TPU"
-    )
-
-    # -- in-bench byte-parity attestation (async vs serial, v2 packs) ------
-    import pickle
-
-    from gordo_tpu import artifacts as artifacts_mod
-    from gordo_tpu.utils import disk_registry
-
-    def scrub(obj, seen=None):
-        # mirror tests/test_build_pipeline.py::_scrub_timings: zero
-        # wall-clock fields through the pickled graph
-        if seen is None:
-            seen = set()
-        if id(obj) in seen:
-            return
-        seen.add(id(obj))
-        if isinstance(obj, dict):
-            for key, zero in (("fleet_seconds", 0.0), ("bucket_size", 0)):
-                if key in obj:
-                    obj[key] = zero
-            for v in obj.values():
-                scrub(v, seen)
-            return
-        if isinstance(obj, (list, tuple)):
-            for v in obj:
-                scrub(v, seen)
-            return
-        d = getattr(obj, "__dict__", None)
-        if d is None:
-            return
-        if "fit_seconds_" in d:
-            d["fit_seconds_"] = 0.0
-        for v in d.values():
-            scrub(v, seen)
-
-    parity_machines = make_machines(32, prefix="bench-btp")
-    dirs = {}
-    for label, pipe in (("serial", False), ("async", True)):
-        d = tempfile.mkdtemp(prefix=f"gordo-bench-btpar-{label}-")
-        r = tempfile.mkdtemp(prefix=f"gordo-bench-btreg-{label}-")
-        timed(parity_machines, 8, pipe, f"parity-{label}", out_dir=d, reg=r)
-        dirs[label] = (d, r)
-    try:
-        sa = artifacts_mod.open_store(dirs["serial"][0])
-        sb = artifacts_mod.open_store(dirs["async"][0])
-        parity_ok = sorted(sa.names()) == sorted(sb.names())
-        for m in parity_machines:
-            ma, mb = sa.load_model(m.name), sb.load_model(m.name)
-            scrub(ma)
-            scrub(mb)
-            parity_ok = parity_ok and (
-                pickle.dumps(ma) == pickle.dumps(mb)
-            )
-        parity_ok = parity_ok and sorted(
-            disk_registry.list_keys(dirs["serial"][1])
-        ) == sorted(disk_registry.list_keys(dirs["async"][1]))
-    finally:
-        for d, r in dirs.values():
-            shutil.rmtree(d, ignore_errors=True)
-            shutil.rmtree(r, ignore_errors=True)
-    out["build_throughput_parity_ok"] = bool(parity_ok)
-    log(f"build_throughput parity (async vs serial, v2): {parity_ok}")
-    if not parity_ok:
-        raise RuntimeError("async-vs-serial artifact parity FAILED")
-
-
-def bench_build_ingest(mesh, out: dict) -> None:
-    """r24 acceptance: the fleet-vectorized ingest plane vs the
-    per-machine pandas load path.
-
-    Same paired-alternating-best-of protocol as the other build stages:
-    one warmup run per mode lands the compiles and the OS page cache,
-    then 4 alternating per-machine/ingest rounds with the per-mode BEST
-    standing (min() rejects one-sided timeshare contamination).  The
-    GATED number is the load stage — the pipeline stage-seconds
-    histogram delta around each best round — because that is the work
-    the ingest plane replaces: 512 sequential resample/join/row-filter
-    pandas passes become one columnar numpy pass per dataset geometry,
-    writing straight into the preallocated stacked buffer.  Acceptance:
-    ingest load ≤ 0.5× the per-machine load.
-
-    ``loader_workers`` is recorded for both modes to attest the r23
-    regression fix: the async loader pool is now sized adaptively (2
-    threads when the chunk-granular ingest path runs, the wide
-    per-machine pool otherwise) instead of a fixed 8 that lost 1.9s to
-    thread-pool contention on this 1-core container.
-
-    In-bench byte-parity attestation mirrors build_throughput: one
-    per-machine and one ingest build of a 32-machine set — 8 of them
-    dataset-fingerprint twins so the fetch-dedup path is exercised, not
-    just the vectorized assembly — must produce identical artifacts
-    (models modulo zeroed wall-clock timings, metadata modulo volatile
-    timing fields) and identical registry keys.  The ingest run's dedup
-    counters land in ``build_ingest_dedup``.
-    """
-    import pickle
-
-    from gordo_tpu import telemetry
-    from gordo_tpu import artifacts as artifacts_mod
-    from gordo_tpu.builder.fleet_build import build_project
-    from gordo_tpu.utils import disk_registry
-
-    def stage_sums() -> dict:
-        metric = telemetry.REGISTRY.snapshot()["metrics"].get(
-            "gordo_build_pipeline_stage_seconds"
-        ) or {}
-        sums = {}
-        for key, v in metric.get("series", {}).items():
-            sums[json.loads(key)[0]] = float(v["sum"])
-        return sums
-
-    def timed(machines, bucket, ing, label, out_dir=None, reg=None):
-        keep = out_dir is not None
-        out_dir = out_dir or tempfile.mkdtemp(
-            prefix=f"gordo-bench-bi-{label}-"
-        )
-        before = stage_sums()
-        t0 = time.perf_counter()
-        result = build_project(
-            machines, out_dir, mesh=mesh, max_bucket_size=bucket,
-            pipeline=True, ingest=ing, model_register_dir=reg,
-        )
-        dt = time.perf_counter() - t0
-        after = stage_sums()
-        if not keep:
-            shutil.rmtree(out_dir, ignore_errors=True)
-        if result.failed or len(result.artifacts) != len(machines):
-            raise RuntimeError(
-                f"build_ingest {label}@{len(machines)}: "
-                f"{len(result.failed)} failed"
-            )
-        stages = {
-            k: round(after.get(k, 0.0) - before.get(k, 0.0), 4)
-            for k in sorted(set(after) | set(before))
-        }
-        return dt, stages, result
-
-    n_machines, bucket = N_MACHINES, 64
-    machines = make_machines(n_machines, prefix=f"bench-bi{n_machines}")
-    for ing in (False, True):  # warmup: land the compiles + page cache
-        timed(machines, bucket, ing, "warmup")
-    times = {"permachine": [], "ingest": []}
-    stage_attr = {"permachine": None, "ingest": None}
-    workers = {"permachine": None, "ingest": None}
-    dedup = None
-    for rnd in range(4):
-        for label, ing in (("permachine", False), ("ingest", True)):
-            dt, stages, result = timed(machines, bucket, ing, label)
-            if not times[label] or dt < min(times[label]):
-                stage_attr[label] = stages  # attribution of the BEST round
-                workers[label] = result.loader_workers
-                if ing:
-                    dedup = dict(result.ingest or {})
-            times[label].append(dt)
-            log(f"build_ingest {label}@{n_machines} round {rnd}: "
-                f"{dt:.2f}s load={stages.get('load', 0.0):.2f}s")
-    best = {label: min(ts) for label, ts in times.items()}
-    for label in ("permachine", "ingest"):
-        out[f"build_ingest_{label}_seconds_{n_machines}"] = round(
-            best[label], 4
-        )
-        out[f"build_ingest_stage_seconds_{label}"] = stage_attr[label]
-        out[f"build_ingest_loader_workers_{label}"] = workers[label]
-    load_pm = stage_attr["permachine"].get("load", 0.0)
-    load_in = stage_attr["ingest"].get("load", 0.0)
-    ratio = (load_in / load_pm) if load_pm else None
-    out["build_ingest_load_seconds_permachine"] = load_pm
-    out["build_ingest_load_seconds_ingest"] = load_in
-    out["build_ingest_load_ratio"] = round(ratio, 4) if ratio else ratio
-    out["build_ingest_load_gate_ok"] = bool(ratio is not None
-                                            and ratio <= 0.5)
-    out["build_ingest_wall_speedup"] = round(
-        best["permachine"] / best["ingest"], 4
-    )
-    log(f"build_ingest load: per-machine {load_pm:.2f}s, "
-        f"ingest {load_in:.2f}s, ratio {ratio:.3f} (gate ≤0.5)")
-
-    # -- in-bench byte-parity attestation (ingest vs per-machine) ----------
-    # make_machines tag names don't include the prefix, so two calls with
-    # different prefixes yield dataset-fingerprint TWINS: 8 of the 32
-    # parity machines dedup against the first 8, exercising the shared
-    # fetch path in the attested build, not just vectorized assembly.
-    volatile_meta = {
-        "model_creation_date", "data_query_duration_sec",
-        "cross_validation_duration_sec", "model_builder_duration_sec",
-        "fit_samples_per_second", "fit_seconds", "fleet_seconds",
-        "bucket_size",
-    }  # mirrors tests/test_build_pipeline.py::VOLATILE_META
-
-    def strip_meta(v):
-        if isinstance(v, dict):
-            return {k: strip_meta(x) for k, x in v.items()
-                    if k not in volatile_meta}
-        if isinstance(v, list):
-            return [strip_meta(x) for x in v]
-        return v
-
-    def scrub(obj, seen=None):
-        # mirror tests/test_build_pipeline.py::_scrub_timings
-        if seen is None:
-            seen = set()
-        if id(obj) in seen:
-            return
-        seen.add(id(obj))
-        if isinstance(obj, dict):
-            for key, zero in (("fleet_seconds", 0.0), ("bucket_size", 0)):
-                if key in obj:
-                    obj[key] = zero
-            for v in obj.values():
-                scrub(v, seen)
-            return
-        if isinstance(obj, (list, tuple)):
-            for v in obj:
-                scrub(v, seen)
-            return
-        d = getattr(obj, "__dict__", None)
-        if d is None:
-            return
-        if "fit_seconds_" in d:
-            d["fit_seconds_"] = 0.0
-        for v in d.values():
-            scrub(v, seen)
-
-    parity_machines = (
-        make_machines(24, prefix="bench-bi-par")
-        + make_machines(8, prefix="bench-bi-twin")
-    )
-    dirs = {}
-    for label, ing in (("permachine", False), ("ingest", True)):
-        d = tempfile.mkdtemp(prefix=f"gordo-bench-bipar-{label}-")
-        r = tempfile.mkdtemp(prefix=f"gordo-bench-bireg-{label}-")
-        # one 32-wide chunk: fetch dedup is chunk-granular, so the twins
-        # must share a chunk with their originals to register hits
-        _, _, result = timed(
-            parity_machines, 32, ing, f"parity-{label}", out_dir=d, reg=r
-        )
-        if ing:
-            out["build_ingest_dedup"] = dict(result.ingest or {})
-        dirs[label] = (d, r)
-    try:
-        sa = artifacts_mod.open_store(dirs["permachine"][0])
-        sb = artifacts_mod.open_store(dirs["ingest"][0])
-        parity_ok = sorted(sa.names()) == sorted(sb.names())
-        for m in parity_machines:
-            ma, mb = sa.load_model(m.name), sb.load_model(m.name)
-            scrub(ma)
-            scrub(mb)
-            parity_ok = parity_ok and (
-                pickle.dumps(ma) == pickle.dumps(mb)
-            )
-            parity_ok = parity_ok and (
-                strip_meta(sa.load_metadata(m.name))
-                == strip_meta(sb.load_metadata(m.name))
-            )
-        parity_ok = parity_ok and sorted(
-            disk_registry.list_keys(dirs["permachine"][1])
-        ) == sorted(disk_registry.list_keys(dirs["ingest"][1]))
-    finally:
-        for d, r in dirs.values():
-            shutil.rmtree(d, ignore_errors=True)
-            shutil.rmtree(r, ignore_errors=True)
-    out["build_ingest_parity_ok"] = bool(parity_ok)
-    log(f"build_ingest parity (ingest vs per-machine): {parity_ok}")
-    if not parity_ok:
-        raise RuntimeError("ingest-vs-per-machine artifact parity FAILED")
+    out["build_throughput_stage_seconds_async"] = stage_attr
+    out["build_throughput_device_idle_seconds_async"] = idle
 
 
 def bench_lstm_build(mesh, out: dict) -> None:
@@ -4010,8 +3644,7 @@ R18_BULK_REPLAY_SAMPLES_PER_SEC = 264367
 
 
 #: stage registry order == run order == metric priority
-STAGES = ("build", "build_pipeline", "build_throughput", "build_ingest",
-          "artifact_io", "hot_reload",
+STAGES = ("build", "build_throughput", "artifact_io", "hot_reload",
           "serving", "serving_precision", "serving_sharded",
           "serving_wire", "serving_openloop", "telemetry_overhead",
           "health_overhead", "cold_start", "multi_device", "refresh",
@@ -4112,9 +3745,7 @@ def main(argv: "list[str] | None" = None) -> int:
 
     stage_fns = {
         "build": build_stage,
-        "build_pipeline": lambda: bench_build_pipeline(mesh, out),
         "build_throughput": lambda: bench_build_throughput(mesh, out),
-        "build_ingest": lambda: bench_build_ingest(mesh, out),
         "artifact_io": lambda: bench_artifact_io(out),
         "hot_reload": lambda: bench_hot_reload(out),
         "serving": lambda: bench_serving(out),

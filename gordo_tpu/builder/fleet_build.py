@@ -54,7 +54,6 @@ from gordo_tpu.builder.build_model import (
     calculate_model_key,
     lookup_cached_artifact,
 )
-from gordo_tpu.dataset.base import GordoBaseDataset
 from gordo_tpu.ingest import plane as ingest_plane
 from gordo_tpu.parallel.anomaly import (
     FleetDiffBuilder,
@@ -102,7 +101,7 @@ _PIPE_WRITER_QUEUE_DEPTH = telemetry.gauge(
 _PIPE_CHUNKS_TOTAL = telemetry.counter(
     "gordo_build_pipeline_chunks_total",
     "Fleet chunks driven to completion, by execution path",
-    labels=("path",),  # pipelined | serial
+    labels=("path",),  # pipelined
 )
 
 # -- incremental refresh knobs (docs/configuration.md) ----------------------
@@ -191,17 +190,6 @@ def _resolve_warm_params(
             continue
         resolved[name] = (params, prev_loss)
     return resolved
-
-
-def _pipeline_enabled(pipeline: Optional[bool]) -> bool:
-    """Kill switch: ``GORDO_BUILD_PIPELINE=off`` (or ``0``/``false``)
-    forces the serial drive loop; an explicit ``pipeline=`` argument to
-    :func:`build_project` wins over the environment."""
-    if pipeline is not None:
-        return bool(pipeline)
-    return os.environ.get("GORDO_BUILD_PIPELINE", "on").strip().lower() not in (
-        "off", "0", "false",
-    )
 
 
 class _ArtifactWriter:
@@ -430,9 +418,6 @@ class ProjectBuildResult:
         #: (process_id, num_processes) when this was one shard of a
         #: multi-host build
         self.shard: Optional[Tuple[int, int]] = None
-        #: whether the pipelined drive loop ran (False: serial path via
-        #: the GORDO_BUILD_PIPELINE=off kill switch or pipeline=False)
-        self.pipelined: bool = False
         #: seconds between one fleet program's end and the next one's
         #: start, by the host's stamps (``timeline.DeviceOccupancy``; the
         #: first gap runs from build start).  0.0 with telemetry off: no
@@ -457,10 +442,10 @@ class ProjectBuildResult:
         #: resolved loader-pool thread count (adaptive when the caller
         #: passed data_workers=None — see build_project)
         self.loader_workers: int = 0
-        #: build-ingest plane accounting (None when GORDO_INGEST is off):
-        #: machines / fetches / dedup_hits / vectorized / fallback counts
-        #: accumulated across chunks by ingest.plane.load_chunk
-        self.ingest: Optional[Dict[str, Any]] = None
+        #: build-ingest plane accounting: machines / fetches / dedup_hits /
+        #: vectorized / fallback counts accumulated across chunks by
+        #: ingest.plane.load_chunk
+        self.ingest: Dict[str, Any] = {}
 
     def summary(self) -> Dict[str, Any]:
         from gordo_tpu import compile as compile_plane
@@ -480,14 +465,12 @@ class ProjectBuildResult:
             "aot_fallbacks": compile_plane.aot_fallbacks(),
             "build_seconds": self.seconds,
             "peak_loaded_machines": self.peak_loaded,
-            "pipelined": self.pipelined,
             "device_idle_seconds": self.device_idle_seconds,
             "artifact_format": self.artifact_format,
         }
         if self.loader_workers:
             out["loader_workers"] = self.loader_workers
-        if self.ingest is not None:
-            out["ingest"] = dict(self.ingest)
+        out["ingest"] = dict(self.ingest)
         if self.warm_started or self.warm_fallbacks:
             out["warm_started"] = len(self.warm_started)
             out["warm_fallbacks"] = dict(self.warm_fallbacks)
@@ -617,10 +600,8 @@ def build_project(
     auto_pad: bool = True,
     auto_pad_budget_seconds: Optional[float] = None,
     shard: Optional[Any] = None,
-    pipeline: Optional[bool] = None,
     artifact_format: Optional[str] = None,
     warm_start: bool = False,
-    ingest: Optional[bool] = None,
 ) -> ProjectBuildResult:
     """Build every machine; fleet-bucket the homogeneous ones.
 
@@ -655,17 +636,15 @@ def build_project(
     training on device and the one the loader pool is prefetching behind
     it.
 
-    ``pipeline`` (default: env-controlled, on): drive the chunks as a
-    three-stage pipeline — loader pool (prefetch) ∥ device (this thread)
-    ∥ background artifact-writer pool — so dataset loads and
-    ``serializer.dump`` both overlap device compute instead of sitting on
-    the critical path.  Artifacts are written to a scratch dir and
-    atomically renamed into place; completion records (registry, shard
-    state) follow the rename, and the writer queue drains before the
-    resumable exit-75 path transitions the shard state.  Artifact bytes
-    and registry entries are identical to the serial path's.
-    ``GORDO_BUILD_PIPELINE=off`` (kill switch) or ``pipeline=False``
-    preserves the serial drive loop; an explicit argument beats the env.
+    The chunks run as a three-stage pipeline — loader pool (prefetch) ∥
+    device (this thread) ∥ background artifact-writer pool — so dataset
+    loads and artifact writes both overlap device compute instead of
+    sitting on the critical path.  Artifacts are written to a scratch dir
+    and atomically renamed into place; completion records (registry,
+    shard state) follow the rename, and the writer queue drains before
+    the resumable exit-75 path transitions the shard state.  A chunk's
+    artifact bytes and registry entries do not depend on what was
+    dispatched before it was collected (tests/test_build_pipeline.py).
 
     ``max_bucket_size=None`` (the default) picks a per-signature chunk
     size: ``DEFAULT_MAX_BUCKET`` (512) for dense signatures,
@@ -702,22 +681,18 @@ def build_project(
     into cache keys exactly as an explicit ``pad_lengths`` would, so the
     decision is stable across re-runs of the same config set.
 
-    ``ingest`` (default: env-controlled via ``GORDO_INGEST``, on): load
-    each fleet chunk through the build-ingest plane
-    (:func:`gordo_tpu.ingest.plane.load_chunk`) — one fingerprint-deduped,
-    fleet-vectorized columnar assembly per chunk instead of one
-    ``dataset.get_data()`` pandas pass per machine, writing straight into
-    the stacked ``(m_pad, n, tags)`` buffer the dispatch path adopts.
-    Byte-identical artifacts either way (tests/test_ingest.py);
-    ``GORDO_INGEST=off`` or ``ingest=False`` restores the per-machine
-    loader pool.
+    Each fleet chunk loads through the build-ingest plane
+    (:func:`gordo_tpu.ingest.plane.load_chunk`): one fingerprint-deduped,
+    fleet-vectorized columnar assembly per chunk, written straight into
+    the stacked ``(m_pad, n, tags)`` buffer the dispatch path adopts;
+    datasets the columnar pass cannot express take its per-machine
+    ``get_data()`` fallback, with the same arrays and metadata
+    (tests/test_ingest.py).
 
-    ``data_workers`` (default None → adaptive): loader-pool threads.
-    BENCH_r23 measured the fixed 8-thread pool SLOWER than serial loading
-    on a low-core host (GIL contention on pure-pandas work), so None now
-    sizes the pool to the host — and to the ingest plane, whose unit of
-    work is a whole chunk, not a machine.  The resolved value lands in
-    ``result.loader_workers``.
+    ``data_workers`` (default None → 2): loader-pool threads.  The
+    plane's unit of work is a whole chunk, so the prefetch depth (the
+    current chunk and the next) is all the parallelism the drive can
+    use.  The resolved value lands in ``result.loader_workers``.
 
     ``shard``: a :class:`gordo_tpu.distributed.partition.ProcessShard` —
     build only this process's slice of ``machines`` (multi-host builds;
@@ -752,16 +727,11 @@ def build_project(
     result = ProjectBuildResult()
     artifact_fmt = artifacts.resolve_format(artifact_format)
     result.artifact_format = artifact_fmt
-    use_ingest = ingest_plane.resolve_enabled(ingest)
     if data_workers is None:
-        # adaptive pool sizing (see docstring): the ingest plane loads a
-        # whole chunk per task, so prefetch depth (2: current + next) is
-        # all the parallelism the pipeline can use; the per-machine path
-        # scales with cores but never past the old fixed 8
-        ncpu = os.cpu_count() or 2
-        data_workers = 2 if use_ingest else max(2, min(8, ncpu - 1))
+        # the ingest plane loads a whole chunk per task, so prefetch depth
+        # (2: current + next) is all the parallelism the drive can use
+        data_workers = 2
     result.loader_workers = int(data_workers)
-    result.ingest = {"enabled": use_ingest} if use_ingest else None
     tracker = _LoadTracker()
     timeline = BuildTimeline(t_start)
     warm_resolved: Dict[str, Tuple[Any, Optional[float]]] = {}
@@ -930,25 +900,6 @@ def build_project(
         for start in range(0, len(bucket), size):
             chunks.append((key, bucket[start : start + size]))
 
-    def _load(i: int, m: Machine):
-        t0 = time.time()  # query seconds are artifact metadata, not telemetry
-        with timeline.phase("load", i):
-            dataset = GordoBaseDataset.from_dict(dict(m.dataset))
-            X, y = dataset.get_data()
-            X = np.asarray(X, np.float32)
-            y = np.asarray(y, np.float32)
-            if align_lengths and len(X) >= align_lengths:  # validated >= 2
-                keep = (len(X) // align_lengths) * align_lengths
-                # newest rows win: industrial sensor history is trained
-                # most-recent-first relevant, so the truncation drops the
-                # head
-                X, y = X[len(X) - keep:], y[len(y) - keep:]
-        query_seconds = time.time() - t0
-        _DATA_LOAD_SECONDS.observe(query_seconds)
-        entry = (X, y, dataset.get_metadata(), query_seconds)
-        tracker.acquire()  # arrays are live from here until freed
-        return entry
-
     def _load_chunk_ingest(i: int, chunk: List[Machine]) -> Dict[str, Any]:
         """One loader-pool task per CHUNK: the build-ingest plane's
         fingerprint-deduped, fleet-vectorized assembly
@@ -968,48 +919,34 @@ def build_project(
         """Chunk ``i``'s load on the loader pool, each task in a copy of
         this context so its span keeps the build's trace id and parent."""
         chunk = chunks[i][1]
-        if use_ingest:
-            return pool.submit(
-                contextvars.copy_context().run, _load_chunk_ingest, i, chunk
-            )
-        return {
-            m.name: pool.submit(contextvars.copy_context().run, _load, i, m)
-            for m in chunk
-        }
+        return pool.submit(
+            contextvars.copy_context().run, _load_chunk_ingest, i, chunk
+        )
 
-    def _collect(chunk: List[Machine], futures) -> Dict[str, Tuple]:
+    def _collect(chunk: List[Machine], future) -> Dict[str, Tuple]:
         loaded: Dict[str, Tuple] = {}
-        if use_ingest:
-            try:
-                entries = futures.result()
-            except Exception as exc:  # plane crash: fail the whole chunk
-                logger.exception("Ingest load failed for %d machine(s)",
-                                 len(chunk))
-                for m in chunk:
-                    result.failed[m.name] = f"data: {exc}"
-                    _BUILD_MACHINES_TOTAL.inc(1.0, "failed")
-                return loaded
+        try:
+            entries = future.result()
+        except Exception as exc:  # plane crash: fail the whole chunk
+            logger.exception("Ingest load failed for %d machine(s)",
+                             len(chunk))
             for m in chunk:
-                entry = entries.get(m.name)
-                if entry is None or isinstance(entry, Exception):
-                    exc = entry if entry is not None else RuntimeError(
-                        "ingest plane produced no entry"
-                    )
-                    logger.error("Data load failed for %s: %s", m.name, exc)
-                    result.failed[m.name] = f"data: {exc}"
-                    _BUILD_MACHINES_TOTAL.inc(1.0, "failed")
-                    continue
-                _DATA_LOAD_SECONDS.observe(entry[3])
-                tracker.acquire()  # arrays live until freed, as in _load
-                loaded[m.name] = entry
-            return loaded
-        for m in chunk:
-            try:
-                loaded[m.name] = futures[m.name].result()
-            except Exception as exc:  # data failure must not sink the fleet
-                logger.exception("Data load failed for %s", m.name)
                 result.failed[m.name] = f"data: {exc}"
                 _BUILD_MACHINES_TOTAL.inc(1.0, "failed")
+            return loaded
+        for m in chunk:
+            entry = entries.get(m.name)
+            if entry is None or isinstance(entry, Exception):
+                exc = entry if entry is not None else RuntimeError(
+                    "ingest plane produced no entry"
+                )
+                logger.error("Data load failed for %s: %s", m.name, exc)
+                result.failed[m.name] = f"data: {exc}"
+                _BUILD_MACHINES_TOTAL.inc(1.0, "failed")
+                continue
+            _DATA_LOAD_SECONDS.observe(entry[3])
+            tracker.acquire()  # arrays are live from here until freed
+            loaded[m.name] = entry
         return loaded
 
     def _free(loaded: Dict[str, Tuple], names: Sequence[str]) -> None:
@@ -1240,8 +1177,7 @@ def build_project(
     def _finish_bucket(rec: _PendingChunk):
         """Collect one dispatched chunk: blocking D2H fetch + per-machine
         assembly.  An async failure from dispatch surfaces here and
-        demotes the chunk to singles, exactly like the serial path's
-        train-time failures.  Returns ``(ok_chunk, detectors,
+        demotes the chunk to singles.  Returns ``(ok_chunk, detectors,
         fleet_seconds)`` or None."""
         ok_chunk, loaded = rec.ok_chunk, rec.loaded
         detectors = rec.detectors
@@ -1265,11 +1201,9 @@ def build_project(
         _PIPE_STAGE_SECONDS.observe(fleet_seconds, "device")
         return ok_chunk, detectors, fleet_seconds
 
-    def _finish_chunk(rec: _PendingChunk, writer: Optional[_ArtifactWriter]):
+    def _finish_chunk(rec: _PendingChunk, writer: _ArtifactWriter):
         """Finish one chunk end-to-end: collect, manifest, and hand the
-        artifacts to the writer pool (pipelined) or write them inline
-        (serial, ``writer=None``)."""
-        key = rec.key
+        artifacts to the writer pool."""
         out = _finish_bucket(rec)
         if out is None:
             return
@@ -1278,88 +1212,26 @@ def build_project(
             _hand_off(rec, ok_chunk, detectors, fleet_seconds, writer)
 
     def _hand_off(rec: _PendingChunk, ok_chunk, detectors, fleet_seconds,
-                  writer: Optional[_ArtifactWriter]) -> None:
+                  writer: _ArtifactWriter) -> None:
         """What follows a chunk's collect on the drive thread: manifest
         row, fleet-health baselines, metadata, and the writes handed to
-        the writer pool (or done inline on the serial drive)."""
+        the writer pool."""
         key, loaded = rec.key, rec.loaded
         _record_manifest(key, ok_chunk)
-        _PIPE_CHUNKS_TOTAL.inc(1.0, "pipelined" if writer else "serial")
+        _PIPE_CHUNKS_TOTAL.inc(1.0, "pipelined")
+        payload = _chunk_payload(ok_chunk, detectors, fleet_seconds,
+                                 loaded, rec.pending)
         if artifact_fmt == "v2":
-            payload = _chunk_payload(ok_chunk, detectors, fleet_seconds,
-                                     loaded, rec.pending)
-            if writer is not None:
-                # v2: the chunk IS the write unit — one pack per chunk
-                # rides the writer queue as a single item
-                writer.submit([payload], rec.index)
-            else:
-                _write_chunk(*payload)
+            # v2: the chunk IS the write unit — one pack per chunk
+            # rides the writer queue as a single item
+            writer.submit([payload], rec.index)
             return
-        per_machine = fleet_seconds / len(ok_chunk)
-        if writer is None:
-            baselines = _chunk_baselines(ok_chunk, detectors, loaded,
-                                         rec.pending)
-            for m, det in zip(ok_chunk, detectors):
-                _dump_machine(
-                    m,
-                    det,
-                    loaded[m.name],
-                    per_machine,
-                    output_dir,
-                    model_register_dir,
-                    result,
-                    fleet=True,
-                    align_lengths=align_lengths,
-                    pad_lengths=pad_lengths,
-                    cache_key=machine_keys[m.name],
-                    baseline=baselines.get(m.name),
-                )
-                _done(m.name)
-                _free(loaded, [m.name])  # artifact on disk: arrays drop
-            return
-        # machines in a chunk share ONE model config, so their
-        # definition.yaml bytes are identical by construction —
-        # serialize once per chunk instead of per machine (the
-        # byte-parity test pins pipelined == serial per machine, so
-        # a config that DID diverge inside a chunk would be caught)
-        chunk_definition = serializer.render_definition(detectors[0])
-        baselines = _chunk_baselines(ok_chunk, detectors, loaded,
-                                     rec.pending)
-        batch = []
-        for m, det in zip(ok_chunk, detectors):
-            metadata = _machine_metadata(
-                m,
-                det,
-                loaded[m.name],
-                per_machine,
-                fleet=True,
-                align_lengths=align_lengths,
-                pad_lengths=pad_lengths,
-                cache_key=machine_keys[m.name],
-                baseline=baselines.get(m.name),
-            )
-            _free(loaded, [m.name])  # arrays drop at enqueue, not write
-            batch.append(
-                (m.name, det, metadata, per_machine, chunk_definition)
-            )
-        writer.submit(batch, rec.index)  # one handoff per chunk
-
-    def _drive_serial(pool) -> None:
-        """The pre-pipeline drive loop (GORDO_BUILD_PIPELINE=off): loads
-        still prefetch one chunk ahead, but dispatch and collect run back
-        to back (no overlap) and artifact dumps run inline on the
-        critical path after each chunk trains."""
-        next_futures = _submit(pool, 0) if chunks else None
-        for i, (key, chunk) in enumerate(chunks):
-            with timeline.phase("load_wait", i):
-                loaded = _collect(chunk, next_futures)
-            # prefetch the NEXT chunk now — it loads while this one trains
-            next_futures = (
-                _submit(pool, i + 1) if i + 1 < len(chunks) else None
-            )
-            rec = _dispatch_bucket(i, key, chunk, loaded)
-            if rec is not None:
-                _finish_chunk(rec, None)
+        names, dets, metadatas, per_machine, chunk_definition = payload
+        writer.submit(  # v1: an artifact a machine, one handoff per chunk
+            [(name, det, metadata, per_machine, chunk_definition)
+             for name, det, metadata in zip(names, dets, metadatas)],
+            rec.index,
+        )
 
     def _drive_pipeline(pool, writer: _ArtifactWriter) -> None:
         """The pipelined drive loop: loader pool (stage A, prefetching) ∥
@@ -1379,8 +1251,6 @@ def build_project(
         device→host calls (jax.device_get / np.asarray / to_host /
         block_until_ready) in its body; the D2H lives in
         ``_finish_bucket`` via ``PendingFleetBuild.collect``."""
-        if not chunks:
-            return
         futures = _submit(pool, 0)
         prev: Optional[_PendingChunk] = None
         for i, (key, chunk) in enumerate(chunks):
@@ -1397,9 +1267,10 @@ def build_project(
         if prev is not None:
             _finish_chunk(prev, writer)
 
-    use_pipeline = _pipeline_enabled(pipeline) and bool(chunks)
-    result.pipelined = use_pipeline
-    tmp_root = os.path.join(output_dir, ".gordo-tmp")
+    # one per build, not per output dir: the processes of a multi-host
+    # build share output_dir, and the first to finish removes its scratch
+    # while a peer's writer pool is still renaming out of its own
+    tmp_root = os.path.join(output_dir, f".gordo-tmp-{uuid.uuid4().hex[:8]}")
     writer: Optional[_ArtifactWriter] = None
 
     def _write_one(name: str, det, metadata: Dict[str, Any],
@@ -1427,9 +1298,11 @@ def build_project(
 
     def _chunk_payload(ok_chunk, detectors, fleet_seconds, loaded,
                        pending=None) -> Tuple:
-        """Assemble a v2 chunk's write payload (metadata closes over the
-        training arrays, so they free HERE — at enqueue — keeping the
-        2-chunk peak_loaded bound independent of writer backlog).
+        """Assemble a chunk's write payload, either format's (metadata
+        closes over the training arrays, so they free HERE — at enqueue —
+        keeping the 2-chunk peak_loaded bound independent of writer
+        backlog).  Machines in a chunk share ONE model config, so their
+        definition.yaml renders once per chunk, not per machine.
         Fleet-health baselines sketch FIRST, while the chunk's training
         arrays are still resident — one stacked scoring dispatch for the
         whole chunk (telemetry.fleet_health.training_baselines), fed the
@@ -1549,7 +1422,7 @@ def build_project(
     _write_chunk = _write_chunk_delta if warm_start else _write_chunk_pack
 
     with ThreadPoolExecutor(max_workers=data_workers) as pool:
-        if use_pipeline:
+        if chunks:
             writer = _ArtifactWriter(
                 _write_chunk if artifact_fmt == "v2" else _write_one,
                 timeline,
@@ -1559,8 +1432,6 @@ def build_project(
             except BaseException:
                 writer.drain()
                 raise
-        else:
-            _drive_serial(pool)
 
     # 4. Single-machine fallback (non-fleetable configs) — one at a time,
     #    each build loading and freeing its own data.
@@ -1779,60 +1650,26 @@ def _write_artifact(
     dest: str,
     model_register_dir: Optional[str],
     cache_key: Optional[str],
-    tmp_root: Optional[str] = None,
+    tmp_root: str,
     definition: Optional[str] = None,
 ) -> None:
     """Serialize one artifact to ``dest`` and register it.
 
-    ``tmp_root`` set (the pipelined path): the artifact dumps into a
-    scratch dir and renames into place — the rename is atomic, so a kill
-    mid-write leaves either no dir at ``dest`` or a complete artifact,
-    never a partial one.  The registry entry follows the rename.
-    ``tmp_root`` None (serial path): in-place dump, the historical
-    behavior.  ``definition``: pre-rendered definition.yaml text
-    (chunk-shared; see the drive loop).
+    The artifact dumps into a scratch dir under ``tmp_root`` and renames
+    into place — the rename is atomic, so a kill mid-write leaves either
+    no dir at ``dest`` or a complete artifact, never a partial one.  The
+    registry entry follows the rename.  ``definition``: pre-rendered
+    definition.yaml text (chunk-shared; see the drive loop).
     """
-    if tmp_root is None:
-        serializer.dump(detector, dest, metadata=metadata,
-                        definition=definition)
-    else:
-        tmp = os.path.join(
-            tmp_root, f"{os.path.basename(dest)}.{uuid.uuid4().hex[:8]}"
-        )
-        serializer.dump(detector, tmp, metadata=metadata,
-                        definition=definition)
-        if os.path.isdir(dest):  # rebuild over an existing artifact dir
-            shutil.rmtree(dest)
-        os.replace(tmp, dest)
-    _register(dest, model_register_dir, cache_key)
-
-
-def _dump_machine(
-    m: Machine,
-    detector,
-    loaded_entry: Tuple,
-    fit_seconds: float,
-    output_dir: str,
-    model_register_dir: Optional[str],
-    result: ProjectBuildResult,
-    fleet: bool,
-    align_lengths: Optional[int] = None,
-    pad_lengths: Optional[int] = None,
-    cache_key: Optional[str] = None,
-    baseline: Optional[Dict[str, Any]] = None,
-) -> None:
-    """Serial-path artifact dump: metadata + write + bookkeeping inline."""
-    metadata = _machine_metadata(
-        m, detector, loaded_entry, fit_seconds, fleet=fleet,
-        align_lengths=align_lengths, pad_lengths=pad_lengths,
-        cache_key=cache_key, baseline=baseline,
+    tmp = os.path.join(
+        tmp_root, f"{os.path.basename(dest)}.{uuid.uuid4().hex[:8]}"
     )
-    dest = os.path.join(output_dir, m.name)
-    _write_artifact(detector, metadata, dest, model_register_dir, cache_key)
-    result.artifacts[m.name] = dest
-    result.fleet_built.append(m.name)
-    _BUILD_MACHINES_TOTAL.inc(1.0, "fleet")
-    _BUILD_MACHINE_SECONDS.observe(fit_seconds, "fleet")
+    serializer.dump(detector, tmp, metadata=metadata,
+                    definition=definition)
+    if os.path.isdir(dest):  # rebuild over an existing artifact dir
+        shutil.rmtree(dest)
+    os.replace(tmp, dest)
+    _register(dest, model_register_dir, cache_key)
 
 
 def _register(

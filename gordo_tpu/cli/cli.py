@@ -144,18 +144,12 @@ def build(output_dir, name, model_config, data_config, metadata,
                    "single-device path, an integer N takes the first N "
                    "devices. Resolved by gordo_tpu.mesh.FleetMesh; env "
                    "equivalent GORDO_MESH_DEVICES.")
-@click.option("--data-workers", default=None, show_default="adaptive",
+@click.option("--data-workers", default=None, show_default="2",
               type=click.IntRange(min=1),
               help="Concurrent data-loader threads feeding the stream. "
-                   "Default: sized to the host and the ingest plane "
-                   "(BENCH_r23 measured a fixed 8-thread pool slower than "
-                   "serial loading on low-core hosts); the resolved count "
-                   "lands in the result summary as loader_workers.")
-@click.option("--ingest/--no-ingest", "ingest", default=None,
-              help="Fleet-vectorized chunk ingest with fingerprint-level "
-                   "fetch dedup (gordo_tpu/ingest/). Default: on, env "
-                   "GORDO_INGEST=off disables; artifacts are "
-                   "byte-identical either way.")
+                   "Default: 2, the ingest plane's prefetch depth (a task "
+                   "loads a whole chunk); the resolved count lands in the "
+                   "result summary as loader_workers.")
 @click.option("--align-lengths", default=None,
               type=click.IntRange(min=2),
               help="Truncate each machine's train rows down to a multiple "
@@ -203,7 +197,7 @@ def build(output_dir, name, model_config, data_config, metadata,
 @click.option("--replace-cache", is_flag=True)
 def build_project_cmd(machine_config, project_name, output_dir,
                       model_register_dir, max_bucket_size, data_parallel,
-                      mesh_devices, data_workers, ingest, align_lengths,
+                      mesh_devices, data_workers, align_lengths,
                       pad_lengths, machines_filter, multihost,
                       barrier_timeout, auto_pad, artifact_format,
                       replace_cache):
@@ -266,7 +260,6 @@ def build_project_cmd(machine_config, project_name, output_dir,
         pad_lengths=pad_lengths,
         auto_pad=auto_pad,
         artifact_format=artifact_format,
-        ingest=ingest,
     )
     click.echo(json.dumps(result.summary()))
     if result.failed:

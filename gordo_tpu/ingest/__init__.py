@@ -27,6 +27,5 @@ from gordo_tpu.ingest.fingerprint import (  # noqa: F401
 from gordo_tpu.ingest.plane import (  # noqa: F401
     load_chunk,
     owned_stack_base,
-    resolve_enabled,
     stack_live_slots,
 )

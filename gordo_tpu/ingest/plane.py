@@ -26,9 +26,7 @@ Anything the columnar pass cannot express — row filters, non-mean
 aggregation, targets != inputs, ragged per-tag indexes, subclassed
 assembly — takes :func:`_load_fallback`, the sanctioned per-machine
 ``dataset.get_data()`` path.  Both paths produce byte-identical arrays
-and metadata (pinned by tests/test_ingest.py and the ``bench --stage
-build_ingest`` in-bench attestation).  ``GORDO_INGEST=off`` is the kill
-switch.
+and metadata (pinned by tests/test_ingest.py).
 
 scripts/lint.py bans per-machine pandas verbs (``.resample(...)``,
 ``pd.concat``, ``pd.DataFrame``) in this module outside the sanctioned
@@ -40,7 +38,6 @@ from __future__ import annotations
 import contextlib
 import copy
 import logging
-import os
 import time
 import weakref
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -59,10 +56,6 @@ from gordo_tpu.dataset.datasets import (
 from gordo_tpu.ingest.fingerprint import dataset_fingerprint
 
 logger = logging.getLogger(__name__)
-
-#: kill switch: GORDO_INGEST=off routes every machine through the
-#: per-machine fallback (docs/configuration.md)
-ENV_INGEST = "GORDO_INGEST"
 
 # -- telemetry instruments (docs/observability.md) --------------------------
 _FETCH_TOTAL = telemetry.counter(
@@ -109,16 +102,6 @@ def _stage(name: str) -> Iterator[Dict[str, Any]]:
     with telemetry.span(SPAN_PREFIX + name) as sp:
         yield sp
     _STAGE_SECONDS.observe(sp.get("seconds", 0.0), name)
-
-
-def resolve_enabled(flag: Optional[bool] = None) -> bool:
-    """Ingest-plane gate: an explicit argument beats ``GORDO_INGEST``
-    (default on)."""
-    if flag is not None:
-        return bool(flag)
-    return os.environ.get(ENV_INGEST, "on").strip().lower() not in (
-        "off", "0", "false", "no",
-    )
 
 
 # -- stacked-buffer ownership ----------------------------------------------

@@ -355,11 +355,10 @@ def refresh_once(
         "generation": int(generation) if generation else None,
         "live_confirmed": confirmed is not None,
         "seconds": latency,
-    }
-    if getattr(result, "ingest", None) is not None:
         # the refresh rides the builder's ingest plane (warm_start chunks
         # load through it too) — surface the fetch-dedup accounting
-        summary["ingest"] = dict(result.ingest)
+        "ingest": dict(result.ingest),
+    }
     return summary
 
 

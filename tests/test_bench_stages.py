@@ -16,8 +16,7 @@ import bench
 
 def test_default_runs_every_stage_in_priority_order():
     assert bench.parse_stages([]) == [
-        "build", "build_pipeline", "build_throughput", "build_ingest",
-        "artifact_io", "hot_reload", "serving",
+        "build", "build_throughput", "artifact_io", "hot_reload", "serving",
         "serving_precision", "serving_sharded", "serving_wire",
         "serving_openloop", "telemetry_overhead", "health_overhead",
         "cold_start", "multi_device", "refresh", "backfill",
@@ -32,12 +31,6 @@ def test_backfill_stage_selectable():
 def test_build_throughput_stage_selectable():
     assert bench.parse_stages(["--stage", "build_throughput"]) == [
         "build_throughput"
-    ]
-
-
-def test_build_ingest_stage_selectable():
-    assert bench.parse_stages(["--stage", "build_ingest"]) == [
-        "build_ingest"
     ]
 
 
@@ -74,12 +67,6 @@ def test_scores_lifecycle_stage_selectable():
 def test_single_stage_selection():
     assert bench.parse_stages(["--stage", "serving_openloop"]) == [
         "serving_openloop"
-    ]
-
-
-def test_build_pipeline_stage_selectable():
-    assert bench.parse_stages(["--stage", "build_pipeline"]) == [
-        "build_pipeline"
     ]
 
 

@@ -54,7 +54,7 @@ def built(tmp_path_factory):
     try:
         result = build_project(
             _machines(2 * N_CHUNKS, prefix="tl"), str(tmp / "out"),
-            max_bucket_size=2, pipeline=True, artifact_format="v2",
+            max_bucket_size=2, artifact_format="v2",
         )
     finally:
         patch.undo()
@@ -171,7 +171,7 @@ def test_telemetry_off_starts_no_watcher_and_writes_the_same_packs(
     try:
         quiet = build_project(
             _machines(2 * N_CHUNKS, prefix="tl"), str(tmp_path / "out"),
-            max_bucket_size=2, pipeline=True, artifact_format="v2",
+            max_bucket_size=2, artifact_format="v2",
         )
     finally:
         telemetry.set_enabled(True)
@@ -216,7 +216,7 @@ def test_an_async_failure_leaves_no_watcher_and_demotes_the_chunk(
     monkeypatch.setenv("GORDO_SPAN_LOG", str(log))
     machines = _machines(4, prefix="tlf")
     result = build_project(
-        machines, str(tmp_path / "out"), max_bucket_size=2, pipeline=True,
+        machines, str(tmp_path / "out"), max_bucket_size=2,
         artifact_format="v2",
     )
     assert not result.failed

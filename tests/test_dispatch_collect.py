@@ -1,11 +1,11 @@
 """r23 dispatch/collect split: the async build plane must be
-byte-equivalent to the serial one.
+byte-equivalent to a serial one.
 
 Pins, in order of blast radius:
 
-- end-to-end: pipelined (dispatch k+1 before collect k) vs serial drives
-  of the same project produce byte-identical artifacts and registry
-  entries, across BOTH artifact layouts (v1 dirs, v2 packs), exact and
+- end-to-end: pipelined (dispatch k+1 before collect k) vs serial (one
+  ``build_project`` call per chunk) drives of the same project produce
+  byte-identical artifacts and registry entries, across BOTH artifact layouts (v1 dirs, v2 packs), exact and
   pad-up grouping, cold and warm-start builds;
 - builder-level: the collect side's LAZY/partial D2H fetch (device-side
   fold slicing, zero-copy view handout) returns exactly the values an
@@ -31,7 +31,12 @@ from gordo_tpu.utils import disk_registry
 from gordo_tpu.utils.trees import to_host
 from gordo_tpu.workflow.config import Machine
 
-from tests.test_build_pipeline import _machines, _scrub_timings, _strip_meta
+from tests.test_build_pipeline import (
+    _build_chunk_by_chunk,
+    _machines,
+    _scrub_timings,
+    _strip_meta,
+)
 
 pytestmark = pytest.mark.slow
 
@@ -112,7 +117,8 @@ def _assert_v2_parity(machines, a_out, b_out):
 class TestAsyncSerialParity:
     """The acceptance contract: for every layout and grouping mode, the
     overlapped drive (dispatch chunk k+1 before collecting chunk k) and
-    the serial drive produce the same bytes."""
+    a serial drive (``_build_chunk_by_chunk``: nothing is dispatched
+    before the previous chunk is collected) produce the same bytes."""
 
     @pytest.mark.parametrize(
         "fmt,ragged",
@@ -126,22 +132,21 @@ class TestAsyncSerialParity:
         else:
             machines = _machines(4, prefix=f"dc-{fmt}")
             kwargs = {}
-        dirs = {}
-        for label, pipe in (("serial", False), ("async", True)):
-            out = tmp_path / f"out-{label}"
-            reg = tmp_path / f"reg-{label}"
-            result = build_project(
-                machines, str(out), model_register_dir=str(reg),
-                max_bucket_size=2, pipeline=pipe, artifact_format=fmt,
-                **kwargs,
-            )
+        a_out, a_reg = tmp_path / "out-serial", tmp_path / "reg-serial"
+        b_out, b_reg = tmp_path / "out-async", tmp_path / "reg-async"
+        serial = _build_chunk_by_chunk(
+            machines, a_out, 2, model_register_dir=str(a_reg),
+            artifact_format=fmt, **kwargs,
+        )
+        overlapped = build_project(
+            machines, str(b_out), model_register_dir=str(b_reg),
+            max_bucket_size=2, artifact_format=fmt, **kwargs,
+        )
+        for result in serial + [overlapped]:
             assert not result.failed
-            assert sorted(result.fleet_built) == sorted(
-                m.name for m in machines
-            )
-            dirs[label] = (out, reg)
-        a_out, a_reg = dirs["serial"]
-        b_out, b_reg = dirs["async"]
+        assert sorted(overlapped.fleet_built) == sorted(
+            name for result in serial for name in result.fleet_built
+        ) == sorted(m.name for m in machines)
         if fmt == "v1":
             _assert_v1_parity(machines, a_out, b_out)
         else:
@@ -157,20 +162,25 @@ class TestAsyncSerialParity:
         relative to cold chunks must not matter."""
         machines = _machines(4, prefix="dcw")
         stores = {}
-        for label, pipe in (("serial", False), ("async", True)):
+        for label in ("serial", "async"):
             out = tmp_path / f"out-{label}"
             cold = build_project(
-                machines, str(out), max_bucket_size=2,
-                artifact_format="v2", pipeline=False,
+                machines, str(out), max_bucket_size=2, artifact_format="v2",
             )
             assert not cold.failed
-            warm = build_project(
-                machines, str(out), max_bucket_size=2,
-                artifact_format="v2", pipeline=pipe, warm_start=True,
-            )
-            assert not warm.failed
+            if label == "serial":
+                warms = _build_chunk_by_chunk(
+                    machines, out, 2, artifact_format="v2", warm_start=True,
+                )
+            else:
+                warms = [build_project(
+                    machines, str(out), max_bucket_size=2,
+                    artifact_format="v2", warm_start=True,
+                )]
+            assert not any(warm.failed for warm in warms)
             assert sorted(
-                warm.warm_started + list(warm.warm_fallbacks)
+                name for warm in warms
+                for name in warm.warm_started + list(warm.warm_fallbacks)
             ) == sorted(m.name for m in machines)
             stores[label] = out
         _assert_v2_parity(machines, stores["serial"], stores["async"])
@@ -180,7 +190,7 @@ class TestAsyncSerialParity:
         sane: bounded by wall clock, non-negative)."""
         result = build_project(
             _machines(4, prefix="idle"), str(tmp_path / "m"),
-            max_bucket_size=2, pipeline=True,
+            max_bucket_size=2,
         )
         assert not result.failed
         idle = result.summary()["device_idle_seconds"]

@@ -250,7 +250,7 @@ def build(out, log):
     before = layout_counts()
     try:
         result = build_project(
-            lstm_machines("fl"), str(out), max_bucket_size=2, pipeline=True,
+            lstm_machines("fl"), str(out), max_bucket_size=2,
             artifact_format="v2",
         )
     finally:

@@ -311,9 +311,13 @@ def test_pad_lengths_ragged_one_program_zero_rows_dropped(
     calls = []
     orig = FleetDiffBuilder._dispatch_group
 
-    def counting(self, X, y, lens=None, warm=None):
-        calls.append((X.shape, None if lens is None else tuple(lens)))
-        return orig(self, X, y, lens=lens, warm=warm)
+    def counting(self, stacked, warm=None):
+        def seeing():
+            X, y, lens = stacked()
+            calls.append((X.shape, None if lens is None else tuple(lens)))
+            return X, y, lens
+
+        return orig(self, seeing, warm=warm)
 
     monkeypatch.setattr(
         anomaly_mod.FleetDiffBuilder, "_dispatch_group", counting

@@ -165,11 +165,16 @@ class TestStreamingMemoryBound:
         """A provider returning different widths than the config promised
         must not poison the stacked bucket — the machine builds single."""
         from gordo_tpu.dataset import datasets as ds_mod
+        from gordo_tpu.ingest import plane
 
-        cfg = NormalizedConfig(
-            yaml.safe_load(_project_yaml(n_machines=3)), "mismatchproj"
-        )
-        machines = cfg.machines
+        doc = yaml.safe_load(_project_yaml(n_machines=3))
+        for i, m in enumerate(doc["machines"]):
+            # a fetch each: machines of one fingerprint share one load
+            m["dataset"]["tags"] = [f"tag-{i}-{c}" for c in "abc"]
+        machines = NormalizedConfig(doc, "mismatchproj").machines
+        # get_data() is the plane's per-machine path, for what its
+        # columnar pass cannot express: send every machine there
+        monkeypatch.setattr(plane, "_vectorizable", lambda dataset: False)
         orig = ds_mod.RandomDataset.get_data
         call_count = {"n": 0}
 
@@ -388,9 +393,13 @@ def test_pad_lengths_keeps_rows_and_collapses_programs(tmp_path, monkeypatch):
     # every group — padded or exact — launches through _dispatch_group
     orig = fb.FleetDiffBuilder._dispatch_group
 
-    def recording(self, X, y, lens=None, warm=None):
-        seen.append((X.shape[1], None if lens is None else list(lens)))
-        return orig(self, X, y, lens=lens, warm=warm)
+    def recording(self, stacked, warm=None):
+        def seeing():
+            X, y, lens = stacked()
+            seen.append((X.shape[1], None if lens is None else list(lens)))
+            return X, y, lens
+
+        return orig(self, seeing, warm=warm)
 
     monkeypatch.setattr(fb.FleetDiffBuilder, "_dispatch_group", recording)
 

