@@ -19,7 +19,9 @@ is the expert layer.
   write strength ``beta_t``; state ``S_t = (I - beta_t k_t k_t^T)
   Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T`` and ``o_t = S_t^T q_t / sqrt(d_k)``.
   Computed chunk-wise (:func:`kda_chunked`): within a chunk the delta rule
-  in matrix form (one triangular solve), between chunks the state through a
+  in matrix form, a unit lower triangular system solved by multiplying with
+  its inverse, which is built block by block from batched products
+  (:func:`_unit_lower_solve`); between chunks the state through a
   ``lax.scan``.
 - **MLA** without rotary positions: keys and values from a 512-wide latent,
   64 key channels shared by all heads, a causal softmax.
@@ -69,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gordo_tpu import telemetry
 from gordo_tpu.models.factories.feedforward import resolve_compute_dtype
 from gordo_tpu.registry import register_model_builder
 
@@ -255,6 +258,97 @@ def l2_normalize(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+_KDA_SOLVE = telemetry.counter(
+    "gordo_kda_solve_total",
+    "Solves of KDA's within-chunk triangular system traced, by the rule that "
+    "gives them: block_inverse (the inverse built block by block and "
+    "multiplied with, _unit_lower_solve)",
+    labels=("rule",),
+)
+_SOLVE_SCOPE = "backbone.kda.scan.solve"
+
+
+def _mm32(a, b):
+    """``a @ b`` in float32, operands unrounded."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=F32)
+
+
+def _lane_mm(a, b):
+    """``a @ b`` for stacks of small matrices ``(s, s, N)`` whose batch is
+    last: multiplied and summed in float32 on the vector unit."""
+    return jnp.sum(a[:, :, None, :] * b[None, :, :, :], axis=1)
+
+
+def _unit_lower_inverse(A):
+    """``(I + tril(A, -1))^-1`` for a stack ``A`` (n, n, N) of matrices, ``n``
+    a power of two.  ``[[L11, 0], [L21, L22]]^-1 = [[T11, 0], [-T22 L21 T11,
+    T22]]``: the two diagonal blocks are put side by side on the batch axis
+    and inverted together, so a level is two batched products however many
+    blocks it has, and ``log2 n`` levels build the inverse.  Only inverses of
+    diagonal blocks are multiplied with, as substitution does; the series
+    ``prod (I + (-A)^(2^j))`` cancels to nothing in float32 where
+    neighbouring keys are nearly parallel.
+
+    The batch is the LAST axis: the blocks of the lower levels are 1, 2, 4
+    wide, and a TPU pads the last two axes of an array to (8, 128), so with
+    the batch in front a level moves 64 times its data and the six levels
+    take 1.4 ms for 1,024 matrices of 64; with the batch in the lanes they
+    take 0.3 ms (PERF.md section 6, PR 33)."""
+    n, _, batch = A.shape
+    if n == 1:
+        return jnp.ones_like(A)
+    h = n // 2
+    T = _unit_lower_inverse(jnp.concatenate([A[:h, :h], A[h:, h:]], axis=-1))
+    T11, T22 = T[..., :batch], T[..., batch:]
+    T21 = -_lane_mm(_lane_mm(T22, A[h:, :h]), T11)
+    top = jnp.concatenate([T11, jnp.zeros_like(T11)], axis=1)
+    return jnp.concatenate([top, jnp.concatenate([T21, T22], axis=1)], axis=0)
+
+
+def _solve_fwd(A, rhs):
+    with jax.named_scope(_SOLVE_SCOPE):
+        n = A.shape[-1]
+        size = 1 << (n - 1).bit_length()
+        pad = [(0, 0)] * (A.ndim - 2) + [(0, size - n)] * 2
+        lanes = jnp.moveaxis(jnp.pad(A, pad).reshape((-1, size, size)), 0, -1)
+        T = jnp.moveaxis(_unit_lower_inverse(lanes)[:n, :n], -1, 0).reshape(A.shape)
+        # runs where the solve is traced
+        _KDA_SOLVE.inc(1.0, "block_inverse")
+        telemetry.add_to_span(
+            kda_solve_traces=1, kda_solve_levels=size.bit_length() - 1)
+        U = _mm32(T, rhs)
+    return U, (T, U)
+
+
+def _solve_bwd(res, dU):
+    T, U = res
+    with jax.named_scope(_SOLVE_SCOPE):
+        d_rhs = _mm32(jnp.swapaxes(T, -1, -2), dU)
+        dA = -_mm32(d_rhs, jnp.swapaxes(U, -1, -2))
+        n = dA.shape[-1]
+        dA = jnp.where(jnp.tri(n, k=-1, dtype=bool), dA, 0.0)
+    return dA, d_rhs
+
+
+@jax.custom_vjp
+def _unit_lower_solve(A, rhs):
+    """``U`` with ``(I + tril(A, -1)) U = rhs``: ``A`` (..., n, n) float32,
+    read below its diagonal only, ``rhs`` (..., n, m) float32.
+
+    Exact, in float32: the inverse ``T`` is built bottom-up
+    (:func:`_unit_lower_inverse`; an ``n`` that is no power of two is padded
+    with rows of the identity up to the next one) and ``U = T rhs`` is one
+    matmul at the highest precision.  Backward reads the same ``T`` and
+    ``U``: ``d_rhs = T^T dU``, ``dA = -tril(d_rhs U^T, -1)``, two matmuls
+    and no second solve.  Both rules run under the named scope
+    ``backbone.kda.scan.solve``."""
+    return _solve_fwd(A, rhs)[0]
+
+
+_unit_lower_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int, cd):
     """The gated delta rule over whole sequences, chunk by chunk.
 
@@ -267,10 +361,11 @@ def kda_chunked(q, k, v, g, beta, chunk: int, cd):
     entering it, ``S_t = Diag(e^{G_t}) S0 + sum_{s<=t} Diag(e^{G_t-G_s}) k_s
     u_s^T`` where the pseudo-values ``U`` solve ``(I + Diag(beta)
     tril(A, -1)) U = Diag(beta) (V - (K e^G) S0)``, ``A_ts = sum_c k_tc k_sc
-    e^{G_tc - G_sc}``.  ``A`` is formed as a product of two factors taken
-    against the chunk's middle row, so that neither exponent exceeds half a
-    chunk's decay; the exponents are clipped at +-80, which only a channel
-    that forgets by more than e^-80 within half a chunk can reach."""
+    e^{G_tc - G_sc}`` (:func:`_unit_lower_solve`).  ``A`` is formed as a
+    product of two factors taken against the chunk's middle row, so that
+    neither exponent exceeds half a chunk's decay; the exponents are clipped
+    at +-80, which only a channel that forgets by more than e^-80 within
+    half a chunk can reach."""
     b, h, t, dk = k.shape
     dv = v.shape[-1]
     nc = t // chunk
@@ -291,8 +386,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int, cd):
     Aqk = jnp.where(lower, pair(q * e_plus), 0.0)
     decay = jnp.exp(G)                                         # from chunk start
     rhs = jnp.concatenate([v, k * decay], axis=-1) * beta[..., None]
-    solved = jax.scipy.linalg.solve_triangular(
-        A + jnp.eye(chunk, dtype=F32), rhs, lower=True, unit_diagonal=True)
+    solved = _unit_lower_solve(A, rhs)
     U0, W = solved[..., :dv], solved[..., dv:]
     G_end = G[:, :, :, -1:]
     k_end = k * jnp.exp(G_end - G)                             # to chunk end

@@ -106,6 +106,73 @@ def test_chunked_kda_matches_the_recurrence_across_chunks_and_decays():
     assert float(jnp.abs(ref).max()) > 0.05
 
 
+def substitution(A, rhs):
+    """The reference of the within-chunk solve: ``solve_triangular``'s row by
+    row substitution, which the package no longer calls."""
+    n = A.shape[-1]
+    return jax.scipy.linalg.solve_triangular(
+        jnp.tril(A, -1) + jnp.eye(n, dtype=A.dtype), rhs, lower=True,
+        unit_diagonal=True)
+
+
+@pytest.mark.parametrize("n,beta", [
+    (1, None), (2, None), (8, None), (24, None), (64, None), (64, 0.5), (64, 0.99)])
+def test_the_block_inverse_solves_what_substitution_solves(n, beta):
+    """Value and both gradients, float32.  ``beta`` times strictly-lower ones
+    is what smooth sensor rows drive ``A`` towards: its powers reach 1e17
+    while the inverse's entries stay under 1, so a series for the inverse
+    cancels to nothing there and only a substitution-like method passes."""
+    keys = jax.random.split(jax.random.PRNGKey(n), 3)
+    if beta is None:
+        A = 0.5 * jax.random.normal(keys[0], (3, 2, n, n))    # upper part unread
+        width = 5
+    else:
+        A = beta * jnp.tril(jnp.ones((n, n)), -1)
+        width = 256
+    rhs = jax.random.normal(keys[1], A.shape[:-1] + (width,))
+    ct = jax.random.normal(keys[2], rhs.shape)
+    made, made_vjp = jax.vjp(backbone._unit_lower_solve, A, rhs)
+    ref, ref_vjp = jax.vjp(substitution, A, rhs)
+    assert made.dtype == jnp.float32
+    for name, a, b in zip(("U", "dA", "d_rhs"), (made,) + made_vjp(ct),
+                          (ref,) + ref_vjp(ct)):
+        scale = float(jnp.abs(b).max()) or 1.0     # n = 1: dA is all zeros
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * scale, name
+
+
+def leaf_equations(jaxpr, enclosing=""):
+    """``(primitive, name stack)`` of every equation that holds no jaxpr of
+    its own, the stacks of the equations around it in front."""
+    for eqn in jaxpr.eqns:
+        stack = enclosing + "/" + str(eqn.source_info.name_stack)
+        inner = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                 if hasattr(getattr(v, "jaxpr", v), "eqns")]
+        for sub in inner:
+            yield from leaf_equations(sub, stack)
+        if not inner:
+            yield eqn.primitive.name, stack
+
+
+def test_every_operation_of_the_solve_stays_in_the_scan_scope():
+    """Forward and gradient: ``seq.kda_s_per_step`` and
+    ``seq.kda_scan_roofline`` read the operations whose name holds
+    ``backbone.kda.scan``, so one that slipped out would flatter them."""
+    A, rhs = jnp.zeros((2, 24, 24)), jnp.zeros((2, 24, 3))
+
+    def both(A, rhs, ct):
+        out, vjp = jax.vjp(backbone._unit_lower_solve, A, rhs)
+        return (out,) + vjp(ct)
+
+    # 24 rows are padded to 32: five levels of two products each, then
+    # ``T rhs``; the gradient adds its two matmuls
+    for fn, args, matmuls in ((backbone._unit_lower_solve, (A, rhs), 1),
+                              (both, (A, rhs, rhs), 3)):
+        found = list(leaf_equations(jax.make_jaxpr(fn)(*args).jaxpr))
+        names = [name for name, _ in found]
+        assert names.count("reduce_sum") == 10 and names.count("dot_general") == matmuls
+        assert [(n, s) for n, s in found if "backbone.kda.scan.solve" not in s] == []
+
+
 def test_gradient_of_every_kda_parameter_matches_the_references(x):
     module, shape = module_of(num_layers=1), shape_of(num_layers=1)
     params, ref_params = start(module, shape)
@@ -324,6 +391,48 @@ def test_the_routing_counters_and_the_artifacts_metadata(built):
     moe = refs[0].load_metadata()["model"]["cross_validation"]["moe"]
     assert moe["held_pairs"] == int(np.sum(moe["tokens_per_held_expert"]))
     assert moe["selected_pairs"] >= moe["held_pairs"]
+
+
+def test_the_solve_is_counted_where_the_program_is_traced(built):
+    """``gordo_kda_solve_total{rule="block_inverse"}`` and the span's counts:
+    one KDA layer (layer 1; layer 2 is MLA), traced in the forward and in the
+    written backward's recomputation of four fits and in three forecasts."""
+    _, out, result, before, after = built
+    labels = [json.loads(k) for k in after["gordo_kda_solve_total"]["series"]]
+    assert labels == [["block_inverse"]]
+    traced = counter(after, "gordo_kda_solve_total") - counter(
+        before, "gordo_kda_solve_total")
+    assert traced > 0
+    counts = result.timeline[0]["counts"]["enqueue"]
+    assert counts["kda_solve_traces"] == traced
+    # chunks of 8: three levels a solve
+    assert counts["kda_solve_levels"] == 3 * traced
+    (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
+    assert "gordo_kda_solve_total" in json.dumps(snapshot)
+
+
+def test_the_lowered_sequence_fit_holds_no_triangular_solve():
+    from gordo_tpu import serializer
+    from gordo_tpu.parallel.anomaly import FleetDiffBuilder, analyze_definition
+
+    config = tiny_config()
+    doc = kind.project_doc(config, SEED, 1)
+    spec = analyze_definition(serializer.from_definition(doc["globals"]["model"]))
+    builder = FleetDiffBuilder(spec)
+    rows = int(config["dataset"]["rows"])
+    ctx = builder._group_context(rows, F, F)
+    program = builder._group_program(ctx, padded=False, warm=False)
+    data = jax.ShapeDtypeStruct((1, rows, F), jnp.float32)
+    lowered = program._jitted.lower(
+        data, data, jax.ShapeDtypeStruct((1,), jnp.uint32))
+    text = lowered.as_text()
+    assert "stablehlo.dot_general" in text
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    # the solve's operations, under the scan's scope in the forward pass and
+    # in the backward's (whose stack holds the rule's own scope in any case)
+    named = lowered.as_text(debug_info=True)
+    for passes in ("backbone.kda", "jvp(backbone.kda)", "transpose(jvp(backbone.kda))"):
+        assert passes + "/backbone.kda.scan/backbone.kda.scan.solve/dot_general" in named
 
 
 def test_the_artifact_round_trips_and_scores(built):

@@ -49,6 +49,12 @@ def counted(before):
     return {k: v - before[k] for k, v in layout_counts().items()}
 
 
+def kda_solves():
+    """``gordo_kda_solve_total``: only a ``kimi_linear`` module counts there."""
+    series = telemetry.REGISTRY.get("gordo_kda_solve_total")
+    return series.value("block_inverse") if series is not None else 0.0
+
+
 def public_only(module, cfg):
     """What replaces ``train.fit.packed_layout`` for the parent's fit:
     every module carries its public tree."""
@@ -247,7 +253,7 @@ def build(out, log):
     patch.setenv("GORDO_SPAN_LOG", str(log))
     # the fleet program is cached by module and config, not by layout
     compile_plane.REGISTRY.clear()
-    before = layout_counts()
+    before, solves_before = layout_counts(), kda_solves()
     try:
         result = build_project(
             lstm_machines("fl"), str(out), max_bucket_size=2,
@@ -260,6 +266,7 @@ def build(out, log):
     with open(log) as f:
         spans = [json.loads(line) for line in f]
     return {"result": result, "out": out, "counted": counted(before),
+            "kda_solves": kda_solves() - solves_before,
             "enqueues": [s for s in spans if s["span"] == "gordo.build.enqueue"]}
 
 
@@ -304,6 +311,7 @@ def test_the_layout_is_counted_where_the_program_is_traced(builds, layout, leave
     # one trace of fleet.exact: three folds and the final fit
     other = "public" if layout == "packed" else "packed"
     assert built["counted"] == {layout: 4, other: 0}
+    assert built["kda_solves"] == 0       # and no `kda_solve_traces` below
     carry = 4 * (3 * leaves + 1)  # parameters, Adam's mu and nu, its count
     traced = [s for s in built["enqueues"] if "carry_leaves" in s]
     assert [(s["chunk"], s["fit_traces"], s["carry_leaves"]) for s in traced] == [
