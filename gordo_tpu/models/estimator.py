@@ -251,8 +251,16 @@ class LSTMForecast(LSTMAutoEncoder):
 
 class SequenceForecast(BaseJaxEstimator):
     """Next-row forecast by a sequence backbone that reads the series once
-    (``kind: kimi_linear``, ``models/factories/backbone.py``): every
-    position of a ``context``-row sequence forecasts its next row.
+    (``models/factories/backbone.py``): every position of a ``context``-row
+    sequence forecasts its next row.  Two kinds: ``kimi_linear`` (delta-rule
+    linear attention with latent attention every fourth layer) and
+    ``glm_moe_lite`` (rotary latent attention in every layer, and a
+    multi-token-prediction module that is trained on the row after next
+    beside the main head: the loss is the next row's error plus
+    ``mtp_weight`` times the module's).  Prediction, held-out forecasts,
+    thresholds and scoring read the main head alone and do not run the
+    module; the artifact keeps its weights (the ``mtp_`` parameters) and
+    :meth:`get_metadata` says what they are.
 
     Training cuts the rows into sequences of ``context`` rows at ``stride``
     (``ops.windows.make_sequences``); the target of the position that reads
@@ -298,6 +306,22 @@ class SequenceForecast(BaseJaxEstimator):
         X = as_float2d(X)
         truth = (X if y is None else as_float2d(y))[self.offset:]
         return float(explained_variance_score(truth, self.predict(X)))
+
+    def get_metadata(self) -> Dict[str, Any]:
+        meta = super().get_metadata()
+        held = sorted(k for k in (self.params_ or {}) if k.startswith("mtp_"))
+        if held:
+            if self.module_ is None:
+                self._rebuild_module()
+            meta["multi_token_prediction"] = {
+                "modules": int(self.module_.cfg.mtp_depth),
+                "loss_weight": float(self.module_.cfg.mtp_weight),
+                "parameters": held,
+                "trained_on": "the row after next, beside the main head's next row",
+                "used_by": "training alone: predict, thresholds and scores "
+                           "read the main head and do not run the module",
+            }
+        return meta
 
 
 # Parity aliases (reference class names).

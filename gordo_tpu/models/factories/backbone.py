@@ -1,16 +1,22 @@
-"""A sequence backbone from a current open model's block: ``kimi_linear``.
+"""Sequence backbones from current open models' blocks: ``kimi_linear`` and
+``glm_moe_lite``.
 
 No reference equivalent: upstream's factories are Keras feed-forward and
-LSTM stacks.  This one is the block of Kimi-Linear-48B-A3B (``model_type``
-``kimi_linear``, arXiv:2510.26692) as the encoder of a per-machine next-row
-forecaster: input ``(S, T, F)`` scaled sensor rows, output ``(S, T, F_out)``
-where position t forecasts row t + 1.  The token embedding and the language
-model head have no counterpart for real-valued rows, so ``h_0 = X W_in`` and
-``Y = RMSNorm(h_L) W_out + b``.
+LSTM stacks.  These are the blocks of Kimi-Linear-48B-A3B (``model_type``
+``kimi_linear``, arXiv:2510.26692) and of GLM-4.7-Flash (``model_type``
+``glm4_moe_lite``) as the encoder of a per-machine forecaster: input
+``(S, T, F)`` scaled sensor rows, output ``(S, T, F_out)`` where position t
+forecasts row t + 1.  The token embedding and the language model head have
+no counterpart for real-valued rows, so ``h_0 = X W_in`` and ``Y =
+RMSNorm(h_L) W_out + b``.  One module (:class:`SequenceBackbone`), one
+:class:`BackboneConfig` and one :func:`forward` serve both kinds; a kind is
+a preset of the configuration's keywords and nothing selects a path.
 
 Every block is pre-norm residual: ``h += Mixer(RMSNorm(h))``, ``h +=
-FFN(RMSNorm(h))``.  Layers are numbered from 1 as the source does: every
-fourth is MLA, the others KDA; layer 1's feed-forward is dense, the others'
+FFN(RMSNorm(h))``.  Layers are numbered from 1 here, for both kinds (GLM's
+source numbers its own from 0).  ``kimi_linear``: every fourth layer's
+mixer is MLA, the others' KDA.  ``glm_moe_lite``: every layer's is MLA.  The
+leading ``first_k_dense_replace`` layers' feed-forward is dense, the others'
 is the expert layer.
 
 - **KDA** (Kimi Delta Attention): ``q, k, v`` each through a depthwise causal
@@ -23,36 +29,53 @@ is the expert layer.
   its inverse, which is built block by block from batched products
   (:func:`_unit_lower_solve`); between chunks the state through a
   ``lax.scan``.
-- **MLA** without rotary positions: keys and values from a 512-wide latent,
-  64 key channels shared by all heads, a causal softmax.
+- **MLA** (:func:`mla_mixer`): keys and values from a normalised latent
+  (``kv_lora_rank``), ``qk_rope_head_dim`` key channels shared by all heads,
+  a causal softmax over ``(q_n k_n + q_r k_r) / sqrt(d_nope + d_rope)``.
+  ``kimi_linear``: queries from one matrix, the shared channels carried
+  without rotation (NoPE).  ``glm_moe_lite``: queries through a low-rank
+  pair with a norm between (``q_lora_rank``), rotary positions on ``q_r``
+  and the shared ``k_r`` (``rope_theta``; the position counted inside the
+  sequence, pairs half-split), values wider than the keys' own channels.
 - **Expert layer** (:func:`expert_layer`): a sigmoid router over ALL the
-  model's experts, the 8 largest kept and renormalised; the module is told
-  which contiguous range of experts it holds and computes the sum over the
-  selected experts it holds (the absent experts' terms are left out, as one
-  chip of an expert-parallel deployment would before the exchange), plus the
-  shared expert.  Positions are sorted by expert and multiplied through
-  ``lax.ragged_dot``: no capacity, so no pair is ever dropped.  The
-  selection bias of the source is a buffer held at 0 and is not stored.
+  model's experts, the ``num_experts_per_token`` largest kept and
+  renormalised; the module is told which contiguous range of experts it
+  holds and computes the sum over the selected experts it holds (the absent
+  experts' terms are left out, as one chip of an expert-parallel deployment
+  would before the exchange), plus the shared expert.  Positions are sorted
+  by expert and multiplied through ``lax.ragged_dot``: no capacity, so no
+  pair is ever dropped.  The selection bias of the source is a buffer held
+  at 0 and is not stored.
+- **MTP module** (:func:`_mtp`, ``glm_moe_lite``; parameters ``mtp_*``,
+  named scope ``backbone.mtp``): ``h'_i = W_eh [RMSNorm(h_{L,i});
+  RMSNorm(h_{0,i+1})]``, one whole block of the expert-layer form with its
+  own weights, then the main head's ``W_out, b`` behind the module's own
+  norm: position i forecasts the row AFTER next from rows up to the next.
+  Run in a training pass alone (``forward(..., mtp=True)``), where
+  ``train.fit.make_loss_fn`` adds its term at weight ``mtp_weight``;
+  prediction never runs it.
 
 Parameters are float32; matmul operands are cast to ``compute_dtype``
-(bfloat16 on a TPU under ``auto``) and accumulated in float32; norms,
-softmax, router scores, the KDA state and its decays, the loss and the
-optimiser are float32.
+(bfloat16 on a TPU under ``auto``) and accumulated in float32; norms, the
+rotation, softmax, router scores, the KDA state and its decays, the loss and
+the optimiser are float32.
 
 Parameters of one kind of part are stacked over the layers that have it
 (``kda_wq`` is ``(KDA layers, D, H dk)``, ``moe_wg`` ``(expert layers, held,
-D, W)``, the two norms ``(layers, D)``).  The leading dense layers are
-traced one by one and the expert layers run as ONE ``lax.scan`` over their
-stacked parameters, whose body chooses its layer's mixer by ``lax.cond``:
-the program holds the expert layer, MLA and the scan's KDA once however many
-layers there are, which is what keeps a model of this size compilable in a
-build's set-up and its executable in a compile cache.  (A ``lax.cond``
-between the dense and the expert feed-forward would put layer 1 into the
-scan too; the TPU compiler's conditional code motion crashes on its
-gradient at the published widths, PERF.md section 6.)  Each feed-forward is
-under ``jax.checkpoint`` and each mixer's backward is written out
-(:func:`_mixer`), so backward keeps the residual stream alone and recomputes
-each part once; a mixer reads ``mixer_group`` sequences at a time.
+D, W)``, the two norms ``(layers, D)``, the module's ``(1, ...)``).  The
+leading dense layers are traced one by one and the expert layers run as ONE
+``lax.scan`` over their stacked parameters, whose body chooses its layer's
+mixer by ``lax.cond`` where the layers' mixers are of two kinds and traces
+the one kind where they are not: the program holds the expert layer, MLA and
+the scan's KDA once however many layers there are, which is what keeps a
+model of this size compilable in a build's set-up and its executable in a
+compile cache.  (A ``lax.cond`` between the dense and the expert
+feed-forward would put layer 1 into the scan too; the TPU compiler's
+conditional code motion crashes on its gradient at the published widths,
+PERF.md section 6.)  Each feed-forward is under ``jax.checkpoint`` and each
+mixer's backward is written out (:func:`_mixer`), so backward keeps the
+residual stream alone and recomputes each part once; a mixer reads
+``mixer_group`` sequences at a time.
 
 The module has no packed layout (``train.fit.packed_layout`` is False for
 it: it has no ``pack``), and it asks the fleet program to run machines one
@@ -96,10 +119,12 @@ class BackboneConfig:
     kda_chunk: int = 64
     mixer_group: int = 2              # sequences a mixer reads at a time
     full_attn_every: int = 4          # layers 4, 8, ... are MLA
+    q_lora_rank: int = 0              # 0: queries from one matrix
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
-    qk_rope_head_dim: int = 64        # carried without rotation (NoPE)
+    qk_rope_head_dim: int = 64        # rotated only where rope_theta says so
     v_head_dim: int = 128
+    rope_theta: float = 0.0           # 0: carried without rotation (NoPE)
     intermediate_size: int = 9216
     first_k_dense_replace: int = 1
     moe_intermediate_size: int = 1024
@@ -110,6 +135,8 @@ class BackboneConfig:
     experts_held_from: int = 0
     experts_held: int = 8
     rms_norm_eps: float = 1e-5
+    mtp_depth: int = 0                # multi-token-prediction modules (0 or 1)
+    mtp_weight: float = 0.0           # lambda of the second loss term
     compute_dtype: Any = jnp.float32
 
     def mixer(self, layer: int) -> str:
@@ -128,6 +155,13 @@ class BackboneConfig:
         return tuple(l for l in range(1, self.num_layers + 1)
                      if kind in (self.mixer(l), self.ffn(l)))
 
+    @property
+    def moe_labels(self) -> Tuple[str, ...]:
+        """What the rows of a training pass's ``tokens`` count are called:
+        the expert layers by their numbers here (from 1), then the MTP
+        module's expert layer as a layer of its own."""
+        return tuple(str(l) for l in self.moe_layers) + ("mtp",) * self.mtp_depth
+
 
 # ---------------------------------------------------------------------------
 # parameters: one flat dict, created in this order
@@ -141,16 +175,24 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
     """``(name, shape, init)`` of every parameter, in creation order; the
     leading axis of a ``kda_`` / ``mla_`` / ``dense_`` / ``moe_`` parameter
     runs over the layers of that kind (:meth:`BackboneConfig.layers_of`), a
-    kind no layer has is left out.  ``init``: ``fan_in`` (normal, std
+    kind no layer has is left out; the ``mtp_`` parameters (the module's two
+    input norms and ``W_eh``, one whole MLA + expert block, its output norm)
+    are stacked over the MTP modules.  ``init``: ``fan_in`` (normal, std
     ``shape[-2] ** -0.5``; a convolution's fan-in is its width), ``ones``,
     ``zeros``, ``a_log`` (log of uniform(1, 16)), ``dt_bias`` (inverse
     softplus of a step drawn log-uniformly from [1e-3, 1e-1])."""
     d, h = cfg.hidden_size, cfg.num_heads
     dk, r = cfg.kda_head_dim, cfg.kda_gate_rank
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    qk, qr = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.q_lora_rank
     conv = cfg.short_conv_kernel_size
     w, e = cfg.moe_intermediate_size, cfg.experts_held
     ws = w * cfg.num_shared_experts
+    # the queries: one matrix, or a low-rank pair with a norm between
+    queries = [("mla_wq", (d, h * qk), "fan_in")] if not qr else [
+        ("mla_wq_a", (d, qr), "fan_in"),
+        ("mla_q_norm", (qr,), "ones"),
+        ("mla_wq_b", (qr, h * qk), "fan_in"),
+    ]
     by_kind = {
         "kda": [
             ("kda_wq", (d, h * dk), "fan_in"),
@@ -169,8 +211,7 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
             ("kda_out_norm", (dk,), "ones"),
             ("kda_wo", (h * dk, d), "fan_in"),
         ],
-        "mla": [
-            ("mla_wq", (d, h * qk), "fan_in"),
+        "mla": queries + [
             ("mla_wkv_a", (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), "fan_in"),
             ("mla_kv_norm", (cfg.kv_lora_rank,), "ones"),
             ("mla_wkv_b", (cfg.kv_lora_rank,
@@ -206,6 +247,17 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
         ("out_proj", (d, cfg.n_features_out), "fan_in"),
         ("out_bias", (cfg.n_features_out,), "zeros"),
     ]
+    if cfg.mtp_depth:
+        # created last: the layers' draws are those of a model without it
+        module = [
+            ("norm_h", (d,), "ones"),
+            ("norm_e", (d,), "ones"),
+            ("weh", (2 * d, d), "fan_in"),
+            ("mixer_norm", (d,), "ones"),
+            ("ffn_norm", (d,), "ones"),
+        ] + by_kind["mla"] + by_kind["moe"] + [("out_norm", (d,), "ones")]
+        specs += [("mtp_" + name, (cfg.mtp_depth,) + shape, init)
+                  for name, shape, init in module]
     return specs
 
 
@@ -435,26 +487,60 @@ def kda_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
     return _mm(o, p["kda_wo"], cd)
 
 
+def rotary(t: int, width: int, theta: float):
+    """``(cos, sin)`` each (t, width / 2) of the rotary angles: position
+    ``i`` (counted inside the sequence) turns pair ``j`` by ``i *
+    theta^(-2j / width)``.  Float32."""
+    inverse = theta ** (-jnp.arange(0, width, 2, dtype=F32) / width)
+    angle = jnp.arange(t, dtype=F32)[:, None] * inverse[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def rotate(x, cos, sin):
+    """Rotary positions on the last axis of ``x``: channel ``j`` of the
+    first half is paired with channel ``j`` of the second (half-split; the
+    adjacent-pairs convention is a fixed permutation of the channels away,
+    which random weights cannot tell apart).  ``cos``, ``sin`` broadcast
+    against ``x``'s halves."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(F32), x[..., half:].astype(F32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
 def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    """Latent attention.  Queries from one matrix, or (``q_lora_rank``) from
+    a low-rank pair with a norm between; keys and values from a normalised
+    latent, the decoupled key channels shared by all heads and, like the
+    queries', rotated where ``rope_theta`` is set; a causal softmax."""
     cd, h = cfg.compute_dtype, cfg.num_heads
     dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                      cfg.v_head_dim, cfg.kv_lora_rank)
     b, t, _ = x.shape
-    q = _mm(x, p["mla_wq"], cd).reshape(b, t, h, dn + dr)
+    if cfg.q_lora_rank:
+        c_q = rms_norm(_mm(x, p["mla_wq_a"], cd), p["mla_q_norm"], cfg.rms_norm_eps)
+        q = _mm(c_q, p["mla_wq_b"], cd).reshape(b, t, h, dn + dr)
+    else:
+        q = _mm(x, p["mla_wq"], cd).reshape(b, t, h, dn + dr)
     kv_a = _mm(x, p["mla_wkv_a"], cd)
     c = rms_norm(kv_a[..., :r], p["mla_kv_norm"], cfg.rms_norm_eps)
     k_r = kv_a[..., r:]                                   # shared by all heads
     kv = _mm(c, p["mla_wkv_b"], cd).reshape(b, t, h, dn + dv)
     k_n, v = kv[..., :dn], kv[..., dn:]
-    scores = jnp.einsum("bthc,bshc->bhts", q[..., :dn].astype(cd), k_n.astype(cd),
-                        preferred_element_type=F32)
-    scores += jnp.einsum("bthc,bsc->bhts", q[..., dn:].astype(cd), k_r.astype(cd),
-                         preferred_element_type=F32)
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v.astype(cd),
-                   preferred_element_type=F32)
+    with jax.named_scope("backbone.mla.attn"):
+        scores = jnp.einsum("bthc,bshc->bhts", q[..., :dn].astype(cd), k_n.astype(cd),
+                            preferred_element_type=F32)
+        q_r = q[..., dn:]
+        if cfg.rope_theta:
+            cos, sin = rotary(t, dr, cfg.rope_theta)
+            q_r = rotate(q_r, cos[:, None, :], sin[:, None, :])
+            k_r = rotate(k_r, cos, sin)
+        scores += jnp.einsum("bthc,bsc->bhts", q_r.astype(cd), k_r.astype(cd),
+                             preferred_element_type=F32)
+        causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+        scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v.astype(cd),
+                       preferred_element_type=F32)
     return _mm(o.reshape(b, t, h * dv), p["mla_wo"], cd)
 
 
@@ -636,21 +722,56 @@ def _expert_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
     return y.reshape(b, t, d), tokens
 
 
-def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False):
+def _head(cfg: BackboneConfig, params: Dict[str, Any], norm, h):
+    """``RMSNorm(h) W_out + b``: the forecast of every position."""
+    return _mm(rms_norm(h, norm, cfg.rms_norm_eps),
+               params["out_proj"], cfg.compute_dtype) + params["out_bias"]
+
+
+def _mtp(cfg: BackboneConfig, params: Dict[str, Any], h0, h):
+    """The multi-token-prediction module on the embedded rows ``h0`` and the
+    last layer's stream ``h`` (both (B, T, D)): ``(forecast of the row after
+    next (B, T, F_out), tokens (held,))``.
+
+    ``h'_i = W_eh [RMSNorm(h_i); RMSNorm(h0_{i+1})]``, one whole block of the
+    expert-layer form with the module's own weights, then the main head's
+    projection behind the module's own norm.  ``h0_{i+1}`` is the
+    sequence's own next position: the last position has none, reads zeros
+    and weighs nothing in the loss."""
+    own = {k[len("mtp_"):]: v for k, v in params.items() if k.startswith("mtp_")}
+    eps, cd = cfg.rms_norm_eps, cfg.compute_dtype
+    ahead = jnp.concatenate([h0[:, 1:], jnp.zeros_like(h0[:, :1])], axis=1)
+    both = jnp.concatenate([rms_norm(h, own["norm_h"][0], eps),
+                            rms_norm(ahead, own["norm_e"][0], eps)], axis=-1)
+    h = _mm(both, own["weh"][0], cd)
+    first = {"is_mla": jnp.asarray(True), "kda": jnp.asarray(0, jnp.int32),
+             "mla": jnp.asarray(0, jnp.int32)}
+    mla = {k: v for k, v in own.items() if k.startswith("mla_")}
+    h = h + _mixer(cfg, {"mla": mla}, own["mixer_norm"][0], first, h)
+    y, tokens = jax.checkpoint(functools.partial(_expert_ffn, cfg))(
+        {k: v[0] for k, v in own.items() if k.startswith("moe_")},
+        own["ffn_norm"][0], h)
+    return _head(cfg, params, own["out_norm"][0], h + y), tokens
+
+
+def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False,
+            mtp: bool = False):
     """``x`` (S, T, F) or (T, F) → the forecast of every position's next row.
-    With ``counts``, also ``{"tokens": (moe layers, held), "selected":
-    pairs routed, "held": pairs that fell on held experts}``.
+    With ``mtp`` (a training pass of a model that has the module), the pair
+    ``(next row, row after next)``.  With ``counts``, also ``{"tokens":
+    (expert layers [+ the MTP's], held), "selected": pairs routed, "held":
+    pairs that fell on held experts}``.
 
     The leading dense layers are traced one by one; the expert layers are
     ONE ``lax.scan`` over their stacked parameters, whose body chooses its
-    layer's mixer by ``lax.cond``.  Every feed-forward is under
-    ``jax.checkpoint`` and every mixer has its backward written out
-    (:func:`_mixer`): backward keeps the residual stream alone and
-    recomputes each part once."""
+    layer's mixer by ``lax.cond`` (none where every layer's is of one kind).
+    Every feed-forward is under ``jax.checkpoint`` and every mixer has its
+    backward written out (:func:`_mixer`): backward keeps the residual
+    stream alone and recomputes each part once."""
     squeeze = x.ndim == 2
     if squeeze:
         x = x[None]
-    h = _mm(x, params["in_proj"], cfg.compute_dtype)
+    h = h0 = _mm(x, params["in_proj"], cfg.compute_dtype)
     stack = lambda kind: {  # noqa: E731
         k: v for k, v in params.items() if k.startswith(kind + "_")}
     mixers = {kind: stack(kind) for kind in ("kda", "mla") if cfg.layers_of(kind)}
@@ -686,9 +807,15 @@ def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False
             "which": which(rest),
             "moe": stack("moe"),
         })
-    y = _mm(rms_norm(h, params["out_norm"], cfg.rms_norm_eps),
-            params["out_proj"], cfg.compute_dtype) + params["out_bias"]
+    y = _head(cfg, params, params["out_norm"], h)
     y = y[0] if squeeze else y
+    if mtp:
+        if not cfg.mtp_depth:
+            raise ValueError("this model has no multi-token-prediction module")
+        with jax.named_scope("backbone.mtp"):
+            ahead, mtp_tokens = _mtp(cfg, params, h0, h)
+        y = (y, ahead[0] if squeeze else ahead)
+        tokens = jnp.concatenate([tokens, mtp_tokens[None]])
     if not counts:
         return y
     positions = h.shape[0] * h.shape[1]
@@ -696,11 +823,11 @@ def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False
         "tokens": tokens,
         "held": jnp.sum(tokens),
         "selected": jnp.asarray(
-            positions * cfg.num_experts_per_token * len(cfg.moe_layers), jnp.int32),
+            positions * cfg.num_experts_per_token * tokens.shape[0], jnp.int32),
     }
 
 
-class KimiLinearBackbone(nn.Module):
+class SequenceBackbone(nn.Module):
     """The block as a flax module: parameters in one flat dict
     (:func:`param_specs`), the forward pass in :func:`forward`."""
 
@@ -710,8 +837,15 @@ class KimiLinearBackbone(nn.Module):
     #: (``lax.map``), not side by side under ``vmap``: one model fills the chip
     fleet_axis = "map"
 
+    @property
+    def mtp_weight(self) -> float:
+        """Lambda of the loss's second term (``train.fit.make_loss_fn``
+        ``second``): a training pass then asks for ``mtp=True``.  0 where
+        the model has no module or does not train it."""
+        return float(self.cfg.mtp_weight) if self.cfg.mtp_depth else 0.0
+
     @nn.compact
-    def __call__(self, x, counts: bool = False):
+    def __call__(self, x, counts: bool = False, mtp: bool = False):
         params = {
             name: self.param(name, _initializer(init), shape, F32)
             for name, shape, init in param_specs(self.cfg)
@@ -720,10 +854,30 @@ class KimiLinearBackbone(nn.Module):
             # the parameters' shapes do not depend on a forward pass: none is
             # traced where the model is only being initialised
             return jnp.zeros(x.shape[:-1] + (self.cfg.n_features_out,), F32)
-        return forward(self.cfg, params, x, counts)
+        return forward(self.cfg, params, x, counts, mtp)
 
     def param_count(self) -> int:
         return sum(math.prod(shape) for _, shape, _ in param_specs(self.cfg))
+
+
+def _backbone(kind: str, n_features, n_features_out, compute_dtype, preset, widths):
+    known = {f.name for f in dataclasses.fields(BackboneConfig)}
+    unknown = sorted(set(widths) - known)
+    if unknown:
+        raise TypeError(f"{kind} got unknown arguments {unknown}")
+    cfg = BackboneConfig(**{
+        **preset, **widths,
+        "n_features": int(n_features),
+        "n_features_out": int(n_features_out or n_features),
+        "compute_dtype": resolve_compute_dtype(compute_dtype),
+    })
+    if not 0 <= cfg.experts_held_from <= cfg.experts_held_from + cfg.experts_held <= cfg.num_experts:
+        raise ValueError("experts_held is not a range of the model's experts")
+    if cfg.mtp_depth not in (0, 1):
+        raise ValueError("one multi-token-prediction module at most")
+    if cfg.rope_theta and cfg.qk_rope_head_dim % 2:
+        raise ValueError("rotary positions pair the channels: qk_rope_head_dim is odd")
+    return SequenceBackbone(cfg)
 
 
 @register_model_builder(type="SequenceForecast")
@@ -742,16 +896,38 @@ def kimi_linear(
     ``experts_held_from`` of ``num_experts``.  ``context`` and ``stride`` are
     the estimator's."""
     del context, stride, seed
-    known = {f.name for f in dataclasses.fields(BackboneConfig)}
-    unknown = sorted(set(widths) - known)
-    if unknown:
-        raise TypeError(f"kimi_linear got unknown arguments {unknown}")
-    cfg = BackboneConfig(
-        n_features=int(n_features),
-        n_features_out=int(n_features_out or n_features),
-        compute_dtype=resolve_compute_dtype(compute_dtype),
-        **widths,
-    )
-    if not 0 <= cfg.experts_held_from <= cfg.experts_held_from + cfg.experts_held <= cfg.num_experts:
-        raise ValueError("experts_held is not a range of the model's experts")
-    return KimiLinearBackbone(cfg)
+    return _backbone("kimi_linear", n_features, n_features_out, compute_dtype, {}, widths)
+
+
+#: GLM-4.7-Flash's config.json as :class:`BackboneConfig` keywords
+GLM_MOE_LITE = dict(
+    hidden_size=2048, num_heads=20, full_attn_every=1, q_lora_rank=768,
+    kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    rope_theta=1e6, intermediate_size=10240, first_k_dense_replace=1,
+    moe_intermediate_size=1536, num_experts=64, num_experts_per_token=4,
+    num_shared_experts=1, routed_scaling_factor=1.8, experts_held=8,
+    mtp_depth=1, mtp_weight=0.3,
+)
+
+
+@register_model_builder(type="SequenceForecast")
+def glm_moe_lite(
+    n_features: int,
+    n_features_out: int = None,
+    compute_dtype: str = "auto",
+    context: int = None,
+    stride: int = None,
+    seed: int = 0,
+    **widths,
+) -> nn.Module:
+    """GLM-4.7-Flash's block at its published widths (``model_type``
+    ``glm4_moe_lite``): every layer's mixer is rotary latent attention with
+    low-rank normalised queries, layer 0's feed-forward is dense and the
+    others' the 64-way expert layer, and one multi-token-prediction module
+    is trained beside the main head (``mtp_weight``; 0 leaves it untrained).
+    ``num_layers`` layers from the source's layer 0 on, ``experts_held``
+    routed experts from ``experts_held_from``.  Every width is a keyword of
+    :class:`BackboneConfig`; tests pass a tiny preset."""
+    del context, stride, seed
+    return _backbone("glm_moe_lite", n_features, n_features_out, compute_dtype,
+                     GLM_MOE_LITE, widths)

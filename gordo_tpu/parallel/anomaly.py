@@ -73,7 +73,7 @@ from gordo_tpu.pipeline import Pipeline
 from gordo_tpu.registry import lookup_factory
 from gordo_tpu.train.cv import build_splitter
 from gordo_tpu.train.fit import TrainConfig, make_fit_fn
-from gordo_tpu.utils.trees import to_host
+from gordo_tpu.utils.trees import start_fetch, to_host
 
 logger = logging.getLogger(__name__)
 
@@ -91,6 +91,13 @@ _MOE_HELD_PAIRS = telemetry.counter(
 _MOE_SELECTED_PAIRS = telemetry.counter(
     "gordo_moe_selected_pairs_total",
     "(position, expert) pairs the routers selected, held here or not",
+)
+
+_MTP_POSITIONS = telemetry.counter(
+    "gordo_mtp_positions_total",
+    "Positions whose row after next a multi-token-prediction module was "
+    "trained on (weight above 0 in the loss's second term), in the optimiser "
+    "steps of the fleet programs' final fits",
 )
 
 #: scalers whose stats are computable by a static pure function (vmappable).
@@ -338,7 +345,7 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
 
     from gordo_tpu.train.fit import (
         _FIT_LAYOUT as fit_layout_counter,
-        batch_geometry, make_loss_fn, make_optimizer, pad_weights,
+        batch_geometry, make_loss_fn, make_optimizer, pad_weights, training_pass,
     )
 
     geometry = [batch_geometry(f[0].shape[1], cfg.batch_size) for f in fits]
@@ -372,12 +379,12 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
     totals = jnp.maximum(jnp.sum(ws, axis=(1, 2)), 1.0)
 
     tx = make_optimizer(cfg)
+    apply_fn, second = training_pass(module, counts=counted)
     if counted:
         grad_fn = jax.value_and_grad(make_loss_fn(
-            lambda variables, x: module.apply(variables, x, counts=True),
-            cfg.loss, aux=True), has_aux=True)
+            apply_fn, cfg.loss, aux=True, second=second), has_aux=True)
     else:
-        plain = jax.value_and_grad(make_loss_fn(module.apply, cfg.loss))
+        plain = jax.value_and_grad(make_loss_fn(apply_fn, cfg.loss, second=second))
 
         def grad_fn(*args):
             loss, grads = plain(*args)
@@ -441,9 +448,12 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
         pred_shape = jax.eval_shape(forecast, like, te_m[0])
         no_counts = jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype),
-            jax.eval_shape(lambda p: module.apply(
-                {"params": p}, xs_m[0, :bs_cap], counts=True)[1], like),
+            jax.eval_shape(lambda p: apply_fn(
+                {"params": p}, xs_m[0, :bs_cap])[1], like),
         ) if counted else ()
+        if counted and second:
+            # the two loss terms' sums, carried beside the routed counts
+            no_counts = {**no_counts, "loss_terms": jnp.zeros((4,), jnp.float32)}
         (final, _, routed), (history, preds) = jax.lax.scan(
             one_fit, (blank_params, tx.init(blank_params), no_counts),
             (xs_m, ys_m, ws, schedule(fit_key), live, totals, te_m, is_fold))
@@ -1203,6 +1213,10 @@ class FleetDiffBuilder:
         dispatch surfaces here."""
         out = g.out
         with self._span("fetch"):
+            # what is fetched whole starts for the host now: the sliced reads
+            # below are device operations, which queue behind the NEXT chunk's
+            # program, and these bytes cross while it runs
+            start_fetch([v for k, v in out.items() if k != "scaler_stats"])
             host = {
                 # fold axis: slot -1 is the final full-data fit — the only
                 # slot _assemble reads, so slice on device and fetch (K+1)x
@@ -1240,12 +1254,14 @@ class FleetDiffBuilder:
         """One group's routing counts (``(M, layers, held)`` tokens, ``(M,)``
         pairs) onto the process's counters."""
         tokens = moe["tokens"][:m].sum(axis=0)
-        for row, layer in zip(tokens, cfg.moe_layers):
+        for row, layer in zip(tokens, cfg.moe_labels):
             for e, count in enumerate(row):
                 _MOE_TOKENS.inc(
-                    float(count), str(layer), str(cfg.experts_held_from + e))
+                    float(count), layer, str(cfg.experts_held_from + e))
         _MOE_HELD_PAIRS.inc(float(moe["held"][:m].sum()))
         _MOE_SELECTED_PAIRS.inc(float(moe["selected"][:m].sum()))
+        if "loss_terms" in moe:
+            _MTP_POSITIONS.inc(float(moe["loss_terms"][:m, 3].sum()))
 
     # -- unpacking into per-machine detector objects ------------------------
     def _assemble(
@@ -1338,6 +1354,14 @@ class FleetDiffBuilder:
                     "held_pairs": int(moe["held"][i]),
                     "selected_pairs": int(moe["selected"][i]),
                 }
+                if "loss_terms" in moe:
+                    first, w1, then, w2 = (float(v) for v in moe["loss_terms"][i])
+                    det.cv_metadata_["loss_terms"] = {
+                        "counted_on": "the final fit's optimiser steps",
+                        "next_row": first / max(w1, 1.0),
+                        "row_after_next": then / max(w2, 1.0),
+                        "row_after_next_positions": int(w2),
+                    }
             detectors.append(det)
         return detectors
 
@@ -1477,6 +1501,9 @@ def _exact_fleet_program(
                 experts_held=module.cfg.experts_held,
                 sequences=inputs_full.shape[1],
                 context=lookback,
+                **({"mtp_depth": module.cfg.mtp_depth,
+                    "mtp_weight": module.cfg.mtp_weight}
+                   if getattr(module.cfg, "mtp_depth", 0) else {}),
             )
         if sequence:
             params0 = None  # drawn where each fit begins, see _sequence_fits

@@ -133,25 +133,65 @@ def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
     return OPTIMIZERS[name](lr, **kwargs)
 
 
-def make_loss_fn(apply_fn: Callable, loss: str, aux: bool = False) -> Callable:
+def make_loss_fn(apply_fn: Callable, loss: str, aux: bool = False,
+                 second: float = 0.0) -> Callable:
     """Weighted scalar loss of (params, x, y, w); w masks padded rows.
     ``w`` weighs samples ``(bs,)`` or, for a model that forecasts every
     position of a sequence, positions ``(bs, T)``: the error is averaged
     over the axes ``w`` does not have.  With ``aux``, ``apply_fn`` returns
     ``(prediction, aux)`` and so does the loss: ``(loss, aux)``, for
-    ``jax.value_and_grad(..., has_aux=True)``."""
+    ``jax.value_and_grad(..., has_aux=True)``.
+
+    With ``second`` (lambda > 0) the prediction is a pair of forecasts from
+    one pass over the minibatch, position ``i``'s target and its successor's
+    (a multi-token-prediction module's): the loss is ``L1 + second * L2``,
+    where ``L2`` holds the second forecast of position ``i`` against the
+    target of position ``i + 1`` with that position's weight (the last
+    position of a sequence has no successor and weighs 0) and is a weighted
+    mean over its own weights' sum.  The aux, a dict then, gains
+    ``"loss_terms"``: ``[L1 * sum(w), sum(w), L2 * sum(w2), sum(w2)]``, the
+    two terms' sums apart."""
     if loss not in LOSSES:
         raise ValueError(f"Unknown loss {loss!r}; available: {sorted(LOSSES)}")
     elem = LOSSES[loss]
 
+    def mean(pred, y, w):
+        per_row = jnp.mean(elem(pred, y), axis=tuple(range(w.ndim, pred.ndim)))
+        return jnp.sum(per_row * w) / jnp.maximum(jnp.sum(w), 1.0)
+
     def loss_fn(params, x, y, w):
         pred = apply_fn({"params": params}, x)
         pred, extra = pred if aux else (pred, None)
-        per_row = jnp.mean(elem(pred, y), axis=tuple(range(w.ndim, pred.ndim)))
-        value = jnp.sum(per_row * w) / jnp.maximum(jnp.sum(w), 1.0)
-        return (value, extra) if aux else value
+        if not second:
+            value = mean(pred, y, w)
+            return (value, extra) if aux else value
+        if w.ndim != 2:
+            raise ValueError("a second horizon needs a weight for every position")
+        pred, ahead = pred
+        # position i's successor: its target and its weight, zeros at the end
+        y2, w2 = (jnp.concatenate([z[:, 1:], jnp.zeros_like(z[:, :1])], axis=1)
+                  for z in (y, w))
+        first, then = mean(pred, y, w), mean(ahead, y2, w2)
+        value = first + second * then
+        if not aux:
+            return value
+        terms = jnp.stack([first * jnp.sum(w), jnp.sum(w), then * jnp.sum(w2), jnp.sum(w2)])
+        return value, {**(extra or {}), "loss_terms": terms}
 
     return loss_fn
+
+
+def training_pass(module, counts: bool = False):
+    """``(apply_fn, second)`` for :func:`make_loss_fn`: the module's apply
+    as a training step calls it.  A module that trains a second horizon
+    beside its main head says so by ``mtp_weight`` and is asked for both
+    forecasts; ``counts`` asks a module that counts what it routed for the
+    counts as the loss's aux."""
+    second = float(getattr(module, "mtp_weight", 0.0) or 0.0)
+    asked = {**({"counts": True} if counts else {}), **({"mtp": True} if second else {})}
+    if not asked:
+        return module.apply, 0.0
+    return (lambda variables, x: module.apply(variables, x, **asked)), second
 
 
 def init_params(module, rng: jax.Array, sample_x: jnp.ndarray):
@@ -280,9 +320,10 @@ def make_fit_fn(module, cfg: TrainConfig, steps: int, bs: int) -> Callable:
     if packed:
         def apply_fn(variables, x):
             return module.apply_packed(variables["params"], x)
+        second = 0.0
     else:
-        apply_fn = module.apply
-    loss_fn = make_loss_fn(apply_fn, cfg.loss)
+        apply_fn, second = training_pass(module)
+    loss_fn = make_loss_fn(apply_fn, cfg.loss, second=second)
     epoch = make_epoch_fn(loss_fn, tx, steps, bs, cfg.shuffle)
 
     def fit_fn(params, X, y, w, rng):

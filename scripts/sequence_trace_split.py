@@ -1,15 +1,17 @@
 """Where a sequence cell's program spends its device seconds, by named scope
 and by the jax primitive an operation was traced from.
 
-    python scripts/sequence_trace_split.py --label parent [--seed 7]
+    python scripts/sequence_trace_split.py --label parent [--seed 7] [--workload <cell>]
 
-Chip only.  Runs ONE traced benchmark run of ``kimi-linear.build-series``
-(``python3 -m benchmark.run --trace 1``, unchanged) and, before the run's
+Chip only.  Runs ONE traced benchmark run of a sequence cell
+(``kimi-linear.build-series`` unless ``--workload`` names another;
+``python3 -m benchmark.run --trace 1``, unchanged) and, before the run's
 scratch directory is removed, reads the ``.xplane.pb`` the way
 ``benchmark/readers/trace_scope_seconds.py`` does: the operations of whole
 executions of the fleet program, control flow's own events left out.  Each
 operation is booked under the innermost ``backbone.*`` scope in its
-``tf_op`` (``unscoped`` where it has none) and under that path's last
+``tf_op`` (``unscoped`` where it has none; a scope inside the
+multi-token-prediction module's as ``backbone.mtp/<scope>``) and under that path's last
 component, the primitive (``triangular_solve``, ``dot_general``, ...).
 Prints the split and writes it to ``chiprun_out/trace_split/<label>.json``.
 The benchmark's own result line stays the last line of the output.
@@ -19,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import importlib
 import json
 import os
 import re
@@ -70,6 +73,8 @@ def split(path: str, program_seconds=None):
             else:
                 scopes = SCOPE.findall(text)
                 scope = max(scopes, key=len) if scopes else "unscoped"
+                if "backbone.mtp" in scopes and scope != "backbone.mtp":
+                    scope = "backbone.mtp/" + scope
                 keys[metadata_id] = (scope, text.rsplit("/", 1)[-1].rstrip(":") or head, head)
         return keys[metadata_id]
 
@@ -104,11 +109,15 @@ def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--label", required=True)
     parser.add_argument("--seed", type=int, default=2147483659)
+    parser.add_argument("--workload", default=WORKLOAD)
     args = parser.parse_args()
 
-    from benchmark import run, trace as trace_mod
-    from benchmark.kinds import sequence_build as kind
+    from benchmark import manifest as manifest_mod, run, trace as trace_mod
     from benchmark.readers import trace_scope_seconds as reader
+
+    manifest = manifest_mod.Manifest()
+    traffic = manifest.traffic(manifest.cell(args.workload)["traffic"])
+    kind = importlib.import_module(f"benchmark.kinds.{traffic['kind']}")
 
     cleanup = kind.cleanup
 
@@ -130,7 +139,7 @@ def main() -> int:
         cleanup(record)
 
     kind.cleanup = split_then_cleanup
-    return run.main(["--workload", WORKLOAD, "--seed", str(args.seed),
+    return run.main(["--workload", args.workload, "--seed", str(args.seed),
                      "--seconds", "51", "--trace", "1"])
 
 

@@ -20,6 +20,7 @@ Slow lane (CI test-full job), alongside tests/test_build_pipeline.py.
 
 import pickle
 
+import jax
 import numpy as np
 import pytest
 
@@ -243,6 +244,48 @@ class TestLazyFetchParity:
                 assert stats["folds"] == [float(v) for v in folds]
                 assert stats["mean"] == float(folds.mean())
                 assert stats["std"] == float(folds.std())
+
+    def test_whole_leaves_start_for_the_host_before_the_sliced_reads(
+        self, monkeypatch
+    ):
+        """The sliced reads are device operations, which on the chip queue
+        behind the next chunk's program: what is fetched whole has to be on
+        its way before the first of them blocks."""
+        from gordo_tpu.parallel import anomaly as anomaly_mod
+        from gordo_tpu.utils import trees
+
+        rng = np.random.default_rng(13)
+        X = rng.standard_normal((2, 250, 3)).astype(np.float32)
+        spec = analyze_definition(from_definition(DETECTOR_DEF))
+        builder = FleetDiffBuilder(spec)
+        g = builder._dispatch_group(lambda: (X, X, None))
+        whole = {k: v for k, v in g.out.items() if k != "scaler_stats"}
+        order = []
+
+        def started(tree):
+            order.append(("start", len(jax.tree.leaves(tree))))
+            trees.start_fetch(tree)
+
+        real_asarray = np.asarray
+
+        def read(x, *args, **kwargs):
+            if isinstance(x, jax.Array):
+                order.append(("read",))
+            return real_asarray(x, *args, **kwargs)
+
+        monkeypatch.setattr(anomaly_mod, "start_fetch", started)
+        monkeypatch.setattr(anomaly_mod.np, "asarray", read)
+        builder._collect_group(g)
+        assert order[0] == ("start", len(jax.tree.leaves(whole)))
+        assert ("read",) in order[1:]
+
+    def test_start_fetch_takes_any_tree_and_changes_no_value(self):
+        from gordo_tpu.utils.trees import start_fetch
+
+        tree = {"a": jax.numpy.arange(6.0).reshape(2, 3), "b": [np.ones(2), 3, None]}
+        assert start_fetch(tree) is None
+        np.testing.assert_array_equal(
+            to_host(tree)["a"], np.arange(6.0).reshape(2, 3))
 
     def test_collect_frees_device_tree_and_is_idempotent(self):
         rng = np.random.default_rng(12)
