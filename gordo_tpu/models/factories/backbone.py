@@ -31,7 +31,10 @@ is the expert layer.
   ``lax.scan``.
 - **MLA** (:func:`mla_mixer`): keys and values from a normalised latent
   (``kv_lora_rank``), ``qk_rope_head_dim`` key channels shared by all heads,
-  a causal softmax over ``(q_n k_n + q_r k_r) / sqrt(d_nope + d_rope)``.
+  a causal softmax over ``(q_n k_n + q_r k_r) / sqrt(d_nope + d_rope)``,
+  computed in query blocks of ``MLA_BLOCK`` rows, each against the prefix of
+  keys it may see (:func:`_causal_core`; one block, the whole masked
+  square, where the sequence is no longer than a block or no multiple).
   ``kimi_linear``: queries from one matrix, the shared channels carried
   without rotation (NoPE).  ``glm_moe_lite``: queries through a low-rank
   pair with a norm between (``q_lora_rank``), rotary positions on ``q_r``
@@ -507,11 +510,75 @@ def rotate(x, cos, sin):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
+_MLA_ATTENTION = telemetry.counter(
+    "gordo_mla_attention_total",
+    "Causal cores of latent attention traced, by the rule that gives them: "
+    "causal_blocks (query blocks against their key prefixes, _causal_core), "
+    "whole (one block: the whole square, masked)",
+    labels=("rule",),
+)
+#: query rows a block of the causal core takes.  Smaller is faster on the
+#: chip (the core alone at the GLM cell's shape: 11.5 ms whole, 8.0 in blocks
+#: of 1,024, 5.7 of 512, 4.7 of 256; scripts/mla_core_chip.py) and dearer to
+#: set up: every block is a shape of its own in each of the eleven places a
+#: program traces a core.  At 256 a build compiled anew took 20 s longer to
+#: its first program, at 512 ten (PERF.md section 6, PR 36)
+MLA_BLOCK = 512
+
+
+def _causal_core(cfg: BackboneConfig, q, k_n, k_r, v):
+    """Causal softmax attention ``(b, t, heads, dv)``, float32: queries ``q``
+    (b, t, heads, dn + dr), the keys' own channels ``k_n`` (b, t, heads, dn),
+    the channels all heads share ``k_r`` (b, t, dr), rotated here with the
+    queries' last ``dr`` where ``rope_theta`` is set, values ``v`` (b, t,
+    heads, dv); scores ``(q_n k_n + q_r k_r) / sqrt(dn + dr)``.
+
+    Computed in ``t // MLA_BLOCK`` query blocks, each against the prefix of
+    keys it may see: a block's scores are ``(b, heads, B, (i + 1) B)``, only
+    its last ``B`` columns hold masked pairs, and no fully masked block is
+    multiplied, exponentiated, stored or differentiated.  A row's softmax is
+    over exactly the entries it has in the whole square (the masked ones
+    weigh ``exp(-inf) = 0`` there), so the forward is the same arithmetic.
+    ``t <= MLA_BLOCK`` or ``t`` no multiple of it: one block, the whole
+    square, operation for operation the program it was before there were
+    blocks (which is why every block's first product comes before the
+    rotation).  Keys and values are cast to the compute dtype block by block,
+    so that the blocks' gradients for them are summed in float32."""
+    cd, dn, dr = cfg.compute_dtype, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    t = q.shape[1]
+    n = t // MLA_BLOCK if t % MLA_BLOCK == 0 else 1
+    spans = [(i * (t // n), (i + 1) * (t // n)) for i in range(n)]
+    # runs where the core is traced
+    _MLA_ATTENTION.inc(1.0, "causal_blocks" if n > 1 else "whole")
+    telemetry.add_to_span(
+        mla_attn_traces=1, mla_attn_blocks=n,
+        mla_attn_pairs_computed=n * (n + 1) // 2, mla_attn_pairs_square=n * n)
+    q_n = q[..., :dn]
+    own = [jnp.einsum("bthc,bshc->bhts", q_n[:, lo:hi].astype(cd), k_n[:, :hi].astype(cd),
+                      preferred_element_type=F32) for lo, hi in spans]
+    q_r = q[..., dn:]
+    if cfg.rope_theta:
+        cos, sin = rotary(t, dr, cfg.rope_theta)
+        q_r = rotate(q_r, cos[:, None, :], sin[:, None, :])
+        k_r = rotate(k_r, cos, sin)
+    blocks = []
+    for (lo, hi), scores in zip(spans, own):
+        scores += jnp.einsum("bthc,bsc->bhts", q_r[:, lo:hi].astype(cd), k_r[:, :hi].astype(cd),
+                             preferred_element_type=F32)
+        causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
+        scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        blocks.append(jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v[:, :hi].astype(cd),
+                                 preferred_element_type=F32))
+    return blocks[0] if n == 1 else jnp.concatenate(blocks, axis=1)
+
+
 def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
     """Latent attention.  Queries from one matrix, or (``q_lora_rank``) from
     a low-rank pair with a norm between; keys and values from a normalised
     latent, the decoupled key channels shared by all heads and, like the
-    queries', rotated where ``rope_theta`` is set; a causal softmax."""
+    queries', rotated where ``rope_theta`` is set; a causal softmax
+    (:func:`_causal_core`)."""
     cd, h = cfg.compute_dtype, cfg.num_heads
     dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                      cfg.v_head_dim, cfg.kv_lora_rank)
@@ -527,20 +594,7 @@ def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
     kv = _mm(c, p["mla_wkv_b"], cd).reshape(b, t, h, dn + dv)
     k_n, v = kv[..., :dn], kv[..., dn:]
     with jax.named_scope("backbone.mla.attn"):
-        scores = jnp.einsum("bthc,bshc->bhts", q[..., :dn].astype(cd), k_n.astype(cd),
-                            preferred_element_type=F32)
-        q_r = q[..., dn:]
-        if cfg.rope_theta:
-            cos, sin = rotary(t, dr, cfg.rope_theta)
-            q_r = rotate(q_r, cos[:, None, :], sin[:, None, :])
-            k_r = rotate(k_r, cos, sin)
-        scores += jnp.einsum("bthc,bsc->bhts", q_r.astype(cd), k_r.astype(cd),
-                             preferred_element_type=F32)
-        causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-        scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v.astype(cd),
-                       preferred_element_type=F32)
+        o = _causal_core(cfg, q, k_n, k_r, v)
     return _mm(o.reshape(b, t, h * dv), p["mla_wo"], cd)
 
 

@@ -1,0 +1,110 @@
+#!/usr/bin/env python
+"""Time latent attention's causal core ALONE on the chip, at a sequence
+cell's shape, for each candidate of ``backbone.MLA_BLOCK``: what chose the
+constant (PERF.md section 3).
+
+    python scripts/mla_core_chip.py [--workload glm-flash.build-horizons] \\
+        [--blocks 256,512,1024,0] [--repeats 5]
+
+For every block size (0: one block, the whole square) it sets the constant,
+compiles ``backbone._causal_core`` at the cell's shape (``mixer_group``
+sequences of ``context`` rows, the configuration's heads and widths, matmul
+operands bfloat16) as the forward alone and as the forward with its
+``jax.vjp`` for all four inputs, runs each ``--repeats`` times after a
+warm-up and prints the best wall milliseconds: ``forward_ms``,
+``forward_backward_ms`` and ``layer_ms``, their sum, which is what one
+attention block of an optimiser step costs (``_mixer_bwd`` recomputes the
+forward).  Chip only (exit 3 without one); leaves the compile cache alone.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+
+def core_shapes(model: dict):
+    """``(cfg, shapes)``: the backbone's configuration (matmul operands
+    bfloat16, as ``auto`` resolves on a TPU) and the shapes ``(q, k_n, k_r,
+    v)`` of one mixer call of ``model`` (a configuration's ``model`` object;
+    widths it leaves out are its kind's)."""
+    import jax.numpy as jnp
+
+    from gordo_tpu.models.factories import backbone
+
+    known = {k: v for k, v in model.items()
+             if k in backbone.BackboneConfig.__dataclass_fields__}
+    preset = backbone.GLM_MOE_LITE if model["kind"] == "glm_moe_lite" else {}
+    cfg = backbone.BackboneConfig(n_features=1, n_features_out=1, **{
+        **preset, **known, "compute_dtype": jnp.bfloat16})
+    b, t, h = cfg.mixer_group, int(model["context"]), cfg.num_heads
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    return cfg, ((b, t, h, dn + dr), (b, t, h, dn), (b, t, dr), (b, t, h, cfg.v_head_dim))
+
+
+def time_core(core, shapes, repeats: int, seed: int = 0):
+    """``{"forward_ms", "forward_backward_ms", "layer_ms", "compile_s"}`` of
+    ``core(q, k_n, k_r, v)`` on seeded normal inputs."""
+    import jax
+    import jax.numpy as jnp
+    from fit_step_chip import timed
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes) + 1)
+    args = [jax.random.normal(k, s, jnp.float32) for k, s in zip(keys, shapes)]
+    ct = jax.random.normal(keys[-1], shapes[3], jnp.float32)
+
+    def both(*inputs):
+        out, vjp = jax.vjp(core, *inputs)
+        return out, vjp(ct)
+
+    t0 = time.perf_counter()
+    forward = jax.jit(core).lower(*args).compile()
+    forward_backward = jax.jit(both).lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    f, fb = (timed(c, args, repeats) * 1e3 for c in (forward, forward_backward))
+    return {"forward_ms": f, "forward_backward_ms": fb, "layer_ms": f + fb,
+            "compile_s": round(compile_s, 1)}
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(prog="mla_core_chip")
+    parser.add_argument("--workload", default="glm-flash.build-horizons")
+    parser.add_argument("--blocks", default="256,512,1024,0")
+    parser.add_argument("--repeats", type=int, default=5)
+    args = parser.parse_args(argv)
+    import jax
+
+    from benchmark import device, manifest as manifest_mod
+
+    try:
+        device.require_chips(1)
+    except device.NoChip as e:
+        print(json.dumps({"error": str(e)}))
+        return 3
+    jax.config.update("jax_enable_compilation_cache", False)
+    from gordo_tpu.models.factories import backbone
+
+    manifest = manifest_mod.Manifest()
+    model = manifest.config(manifest.cell(args.workload)["config"])["model"]
+    cfg, shapes = core_shapes(model)
+    core = functools.partial(backbone._causal_core, cfg)
+    for block in (int(b) for b in args.blocks.split(",")):
+        backbone.MLA_BLOCK = block or shapes[0][1]
+        line = {"workload": args.workload, "block": block,
+                "device_kind": jax.devices()[0].device_kind,
+                "shape": [list(s) for s in shapes]}
+        line.update(time_core(core, shapes, args.repeats))
+        print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

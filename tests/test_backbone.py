@@ -336,8 +336,9 @@ def built(tmp_path_factory):
     return config, out, result, before, after
 
 
-def counter(snapshot, name):
-    return sum((snapshot.get(name) or {"series": {}})["series"].values())
+def counter(snapshot, name, *labels):
+    series = (snapshot.get(name) or {"series": {}})["series"]
+    return sum(v for k, v in series.items() if not labels or json.loads(k) == list(labels))
 
 
 def test_two_machines_build_in_one_chunk_and_match_the_reference(built):
@@ -409,6 +410,23 @@ def test_the_solve_is_counted_where_the_program_is_traced(built):
     assert counts["kda_solve_levels"] == 3 * traced
     (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
     assert "gordo_kda_solve_total" in json.dumps(snapshot)
+
+
+def test_the_attention_core_is_counted_as_one_block(built):
+    """``gordo_mla_attention_total{rule="whole"}``: the tiny preset's 32
+    rows are fewer than ``backbone.MLA_BLOCK``, so its one MLA layer's cores
+    (traced where the solves are) are one block each and none is
+    ``causal_blocks``; ``tests/test_backbone_glm.py`` has a build of four."""
+    _, out, result, before, after = built
+    traced = {rule: counter(after, "gordo_mla_attention_total", rule) - counter(
+        before, "gordo_mla_attention_total", rule) for rule in ("whole", "causal_blocks")}
+    assert traced["whole"] > 0 and traced["causal_blocks"] == 0
+    counts = result.timeline[0]["counts"]["enqueue"]
+    assert backbone.MLA_BLOCK > 32
+    assert counts["mla_attn_traces"] == counts["mla_attn_blocks"] == traced["whole"]
+    assert counts["mla_attn_pairs_computed"] == counts["mla_attn_pairs_square"] == traced["whole"]
+    (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
+    assert "gordo_mla_attention_total" in json.dumps(snapshot)
 
 
 def test_the_lowered_sequence_fit_holds_no_triangular_solve():

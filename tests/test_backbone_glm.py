@@ -19,7 +19,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark.kinds import sequence_build as kind  # noqa: E402  (the project and the read-back)
 from benchmark.reference import glm_moe_lite as reference  # noqa: E402
-from gordo_tpu import telemetry  # noqa: E402
+from gordo_tpu import compile as compile_plane, telemetry  # noqa: E402
 from gordo_tpu.models.estimator import SequenceForecast  # noqa: E402
 from gordo_tpu.models.factories import backbone  # noqa: E402
 from gordo_tpu.train.fit import make_loss_fn, training_pass  # noqa: E402
@@ -35,6 +35,7 @@ T = 32
 # float32 against float32 on the CPU (measured here: the two fits' changes
 # from the common start are 1e-4 of a change apart)
 UPDATE_GAP = 3e-3
+STEP = 1e-3     # the learning rate: what Adam moves a parameter by in a step
 
 
 def module_of(**over):
@@ -193,6 +194,126 @@ def test_the_mixer_rotates_where_the_configuration_says_so():
     # position 0 attends to itself alone, and a rotation by angle 0 is none
     np.testing.assert_allclose(rotated[:, 0], backbone.mla_mixer(plain.cfg, p, h)[:, 0],
                                atol=1e-6)
+
+
+# -- 2b. the causal core in query blocks ------------------------------------------
+
+BLOCK = 8       # what the tests put in ``backbone.MLA_BLOCK``: sequences of 32 are four blocks
+
+
+def whole_square_core(cfg, q, k_n, k_r, v):
+    """The core as it was before it was computed in blocks, and the
+    reference of the blocked one: every pair of the ``t x t`` square
+    multiplied, the upper triangle masked, one softmax over whole rows."""
+    cd, dn, dr = cfg.compute_dtype, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    t = q.shape[1]
+    scores = jnp.einsum("bthc,bshc->bhts", q[..., :dn].astype(cd), k_n.astype(cd),
+                        preferred_element_type=jnp.float32)
+    q_r = q[..., dn:]
+    if cfg.rope_theta:
+        cos, sin = backbone.rotary(t, dr, cfg.rope_theta)
+        q_r = backbone.rotate(q_r, cos[:, None, :], sin[:, None, :])
+        k_r = backbone.rotate(k_r, cos, sin)
+    scores += jnp.einsum("bthc,bsc->bhts", q_r.astype(cd), k_r.astype(cd),
+                         preferred_element_type=jnp.float32)
+    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v.astype(cd),
+                      preferred_element_type=jnp.float32)
+
+
+#: the two kinds' latent attention: rotary with low-rank queries and values
+#: wider than the keys' own channels, and NoPE with queries from one matrix
+SETTINGS = {
+    "rotary": lambda dtype: backbone.glm_moe_lite(F, F, compute_dtype=dtype, **TINY),
+    "nope": lambda dtype: backbone.kimi_linear(
+        F, F, compute_dtype=dtype, hidden_size=64, num_heads=2, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, num_layers=4),
+}
+
+
+def mixer_inputs(setting, dtype, t):
+    """One latent-attention layer's parameters and a group of two sequences."""
+    cfg = SETTINGS[setting](dtype).cfg
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+    p = {name: backbone._initializer(init)(next(keys), shape[1:])
+         for name, shape, init in backbone.param_specs(cfg)
+         if name.startswith("mla_")}
+    return cfg, p, jax.random.normal(next(keys), (2, t, 64))
+
+
+def mixer_and_gradients(cfg, p, h):
+    ct = jax.random.normal(jax.random.PRNGKey(8), h.shape)
+
+    @jax.jit        # traced here, with whatever the test has put in the module
+    def both(p, h):
+        out, vjp = jax.vjp(lambda p, h: backbone.mla_mixer(cfg, p, h), p, h)
+        return out, vjp(ct)
+
+    out, (dp, dh) = both(p, h)
+    return out, {**dp, "input": dh}
+
+
+@pytest.mark.parametrize("t", [4 * BLOCK, BLOCK])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("setting", ["rotary", "nope"])
+def test_the_blocked_core_is_the_whole_square_one(setting, dtype, t, monkeypatch):
+    """Forward: a row's softmax is over the entries it had, so the blocks
+    repeat the square's arithmetic (measured here: equal to the bit with
+    bfloat16 operands, to float32 rounding with float32 ones, whose matmuls
+    sum a prefix in another order).  Gradients: float32 operands to float32
+    rounding; with bfloat16 operands a block's share of ``dk`` and ``dv`` is
+    rounded to bfloat16 (8 bits: 2^-8 = 0.4 % of an entry) once a block
+    before the float32 sum where the square rounds the whole sum once, so
+    every gradient is held to 2 % of its largest entry: five such roundings'
+    worth, and far under what a misplaced block or mask moves (order 1)."""
+    cfg, p, h = mixer_inputs(setting, dtype, t)
+    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    made, made_grads = mixer_and_gradients(cfg, p, h)
+    monkeypatch.setattr(backbone, "_causal_core", whole_square_core)
+    ref, ref_grads = mixer_and_gradients(cfg, p, h)
+    assert relative(made, ref) < 1e-6
+    assert set(made_grads) == set(p) | {"input"}
+    for name, g in ref_grads.items():
+        assert float(jnp.abs(g).max()) > 0, name
+        assert relative(made_grads[name], g) < (1e-5 if dtype == "float32" else 2e-2), name
+
+
+@pytest.mark.parametrize("t,rule", [(4 * BLOCK, "causal_blocks"), (BLOCK + 4, "whole"),
+                                    (BLOCK, "whole"), (BLOCK // 2, "whole")])
+def test_a_length_the_block_does_not_divide_is_one_block(t, rule, monkeypatch):
+    cfg, p, h = mixer_inputs("rotary", "float32", t)
+    lower = lambda: jax.jit(  # noqa: E731
+        lambda p, h: backbone.mla_mixer(cfg, p, h)).lower(p, h).as_text()
+    counted = telemetry.REGISTRY.get("gordo_mla_attention_total")
+    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    before = {r: counted.value(r) for r in ("causal_blocks", "whole")}
+    with telemetry.span("gordo.test.trace") as attrs:
+        text = lower()
+    assert {r: counted.value(r) - before[r] for r in before} == {
+        rule: 1, "whole" if rule == "causal_blocks" else "causal_blocks": 0}
+    n = 4 if rule == "causal_blocks" else 1
+    assert attrs["mla_attn_traces"] == 1 and attrs["mla_attn_blocks"] == n
+    assert attrs["mla_attn_pairs_computed"] == n * (n + 1) // 2
+    assert attrs["mla_attn_pairs_square"] == n * n
+    assert text.count("stablehlo.exponential") == n
+    # one block is the whole square's program, operation for operation
+    monkeypatch.setattr(backbone, "_causal_core", whole_square_core)
+    assert (text == lower()) == (n == 1)
+
+
+@pytest.mark.parametrize("setting", ["rotary", "nope"])
+def test_no_row_sees_a_later_one_across_a_block_boundary(setting, monkeypatch):
+    cfg, p, h = mixer_inputs(setting, "float32", 4 * BLOCK)
+    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    mixer = jax.jit(lambda h: backbone.mla_mixer(cfg, p, h))
+    out = mixer(h)
+    for first in (2 * BLOCK, 2 * BLOCK + 3):   # a block's first row, and one inside it
+        later = h.at[:, first:].add(1.0)
+        moved = mixer(later)
+        np.testing.assert_array_equal(moved[:, :first], out[:, :first])
+        assert float(jnp.abs(moved[:, first:] - out[:, first:]).min(axis=-1).max()) > 1e-4
 
 
 # -- 3. the share --------------------------------------------------------------------
@@ -364,8 +485,31 @@ def built(tmp_path_factory):
     return config, out, result, before, after
 
 
-def counter(snapshot, name):
-    return sum((snapshot.get(name) or {"series": {}})["series"].values())
+@pytest.fixture(scope="module")
+def built_in_blocks(built, tmp_path_factory):
+    """The same project with sequences of four blocks (``built``'s are one:
+    32 rows are fewer than ``backbone.MLA_BLOCK``)."""
+    from gordo_tpu.builder.fleet_build import build_project
+    from gordo_tpu.workflow.config import NormalizedConfig
+
+    out = str(tmp_path_factory.mktemp("glm-project-blocks"))
+    machines = NormalizedConfig(kind.project_doc(built[0], SEED, 2), "glm-test").machines
+    patch = pytest.MonkeyPatch()
+    patch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    # the fleet program is cached by module and config, not by the block
+    compile_plane.REGISTRY.clear()
+    before = telemetry.REGISTRY.snapshot()["metrics"]
+    try:
+        result = build_project(machines, out, artifact_format="v2")
+    finally:
+        patch.undo()
+        compile_plane.REGISTRY.clear()
+    return built[0], out, result, before, telemetry.REGISTRY.snapshot()["metrics"]
+
+
+def counter(snapshot, name, *labels):
+    series = (snapshot.get(name) or {"series": {}})["series"]
+    return sum(v for k, v in series.items() if not labels or json.loads(k) == list(labels))
 
 
 def test_two_machines_build_in_one_chunk_and_match_the_reference(built):
@@ -381,6 +525,49 @@ def test_two_machines_build_in_one_chunk_and_match_the_reference(built):
         assert far["loss"] < 1e-5 and far["update"] < UPDATE_GAP
         if i == 0:
             assert far["threshold"] < 1e-4
+
+
+def test_a_fit_in_four_blocks_writes_the_packs_of_the_whole_square(built, built_in_blocks):
+    """Through ``_sequence_fits``: three folds, the final fit, thresholds.
+    Float32 on the CPU, where the blocks sum a prefix's products in another
+    order than the square.  Adam divides a gradient by its own size, so
+    rounding shows where a gradient is next to nothing: measured here, 20 of
+    a leaf's 5,760 entries end more than a thousandth of a step apart (the
+    farthest 0.07 of one) and every other leaf's all end closer."""
+    config, out, result, _, _ = built_in_blocks
+    assert not result.summary()["failed"] and len(result.timeline) == 1
+    for i, name in enumerate(kind.machine_names(SEED, 2)):
+        made, whole = kind.produced(out, name), kind.produced(built[1], name)
+        far = gaps(made, reference_of(config, kind.reference_rows(config, name), folds=i == 0))
+        assert far["loss"] < 1e-5 and far["update"] < UPDATE_GAP
+        np.testing.assert_allclose(made["history"], whole["history"], rtol=1e-6)
+        np.testing.assert_allclose(made["thresholds"], whole["thresholds"], rtol=1e-5)
+        assert set(made["params"]) == set(whole["params"])
+        for leaf, value in made["params"].items():
+            apart = np.abs(value - whole["params"][leaf])
+            assert apart.max() < 0.1 * STEP and np.mean(apart > 1e-3 * STEP) < 0.01, leaf
+
+
+@pytest.mark.parametrize("blocks,rule", [(4, "causal_blocks"), (1, "whole")])
+def test_the_cores_are_counted_where_the_program_is_traced(
+        blocks, rule, built, built_in_blocks):
+    """``gordo_mla_attention_total{rule}`` and the span's counts: every core
+    of a build is of one rule, 10 of 16 block pairs where a sequence is four
+    blocks and 1 of 1 where it is one."""
+    _, out, result, before, after = built_in_blocks if blocks == 4 else built
+    other = "whole" if rule == "causal_blocks" else "causal_blocks"
+    traced = counter(after, "gordo_mla_attention_total", rule) - counter(
+        before, "gordo_mla_attention_total", rule)
+    assert traced > 0
+    assert counter(after, "gordo_mla_attention_total", other) == counter(
+        before, "gordo_mla_attention_total", other)
+    counts = result.timeline[0]["counts"]["enqueue"]
+    assert counts["mla_attn_traces"] == traced
+    assert counts["mla_attn_blocks"] == blocks * traced
+    assert counts["mla_attn_pairs_computed"] == blocks * (blocks + 1) // 2 * traced
+    assert counts["mla_attn_pairs_square"] == blocks * blocks * traced
+    (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
+    assert "gordo_mla_attention_total" in json.dumps(snapshot)
 
 
 @pytest.fixture(scope="module")

@@ -49,10 +49,16 @@ def counted(before):
     return {k: v - before[k] for k, v in layout_counts().items()}
 
 
-def kda_solves():
-    """``gordo_kda_solve_total``: only a ``kimi_linear`` module counts there."""
-    series = telemetry.REGISTRY.get("gordo_kda_solve_total")
-    return series.value("block_inverse") if series is not None else 0.0
+def backbone_traces():
+    """``gordo_kda_solve_total`` + ``gordo_mla_attention_total``: only a
+    sequence backbone counts there."""
+    total = 0.0
+    for name, rules in (("gordo_kda_solve_total", ("block_inverse",)),
+                        ("gordo_mla_attention_total", ("causal_blocks", "whole"))):
+        series = telemetry.REGISTRY.get(name)
+        if series is not None:
+            total += sum(series.value(rule) for rule in rules)
+    return total
 
 
 def public_only(module, cfg):
@@ -253,7 +259,7 @@ def build(out, log):
     patch.setenv("GORDO_SPAN_LOG", str(log))
     # the fleet program is cached by module and config, not by layout
     compile_plane.REGISTRY.clear()
-    before, solves_before = layout_counts(), kda_solves()
+    before, traces_before = layout_counts(), backbone_traces()
     try:
         result = build_project(
             lstm_machines("fl"), str(out), max_bucket_size=2,
@@ -266,7 +272,7 @@ def build(out, log):
     with open(log) as f:
         spans = [json.loads(line) for line in f]
     return {"result": result, "out": out, "counted": counted(before),
-            "kda_solves": kda_solves() - solves_before,
+            "backbone_traces": backbone_traces() - traces_before,
             "enqueues": [s for s in spans if s["span"] == "gordo.build.enqueue"]}
 
 
@@ -311,7 +317,8 @@ def test_the_layout_is_counted_where_the_program_is_traced(builds, layout, leave
     # one trace of fleet.exact: three folds and the final fit
     other = "public" if layout == "packed" else "packed"
     assert built["counted"] == {layout: 4, other: 0}
-    assert built["kda_solves"] == 0       # and no `kda_solve_traces` below
+    # no KDA solve, no attention core: and no `kda_solve_*`, `mla_attn_*` below
+    assert built["backbone_traces"] == 0
     carry = 4 * (3 * leaves + 1)  # parameters, Adam's mu and nu, its count
     traced = [s for s in built["enqueues"] if "carry_leaves" in s]
     assert [(s["chunk"], s["fit_traces"], s["carry_leaves"]) for s in traced] == [
