@@ -1,4 +1,6 @@
-from gordo_tpu.models.factories.backbone import glm_moe_lite, kimi_linear  # noqa: F401
+from gordo_tpu.models.factories.backbone import (  # noqa: F401
+    glm_moe_lite, kimi_linear, lfm2_moe,
+)
 from gordo_tpu.models.factories.feedforward import (  # noqa: F401
     feedforward_hourglass,
     feedforward_model,

@@ -1,23 +1,29 @@
-"""Sequence backbones from current open models' blocks: ``kimi_linear`` and
-``glm_moe_lite``.
+"""Sequence backbones from current open models' blocks: ``kimi_linear``,
+``glm_moe_lite`` and ``lfm2_moe``.
 
 No reference equivalent: upstream's factories are Keras feed-forward and
 LSTM stacks.  These are the blocks of Kimi-Linear-48B-A3B (``model_type``
-``kimi_linear``, arXiv:2510.26692) and of GLM-4.7-Flash (``model_type``
-``glm4_moe_lite``) as the encoder of a per-machine forecaster: input
-``(S, T, F)`` scaled sensor rows, output ``(S, T, F_out)`` where position t
-forecasts row t + 1.  The token embedding and the language model head have
-no counterpart for real-valued rows, so ``h_0 = X W_in`` and ``Y =
-RMSNorm(h_L) W_out + b``.  One module (:class:`SequenceBackbone`), one
-:class:`BackboneConfig` and one :func:`forward` serve both kinds; a kind is
-a preset of the configuration's keywords and nothing selects a path.
+``kimi_linear``, arXiv:2510.26692), of GLM-4.7-Flash (``model_type``
+``glm4_moe_lite``) and of LFM2-24B-A2B (``model_type`` ``lfm2_moe``) as the
+encoder of a per-machine forecaster: input ``(S, T, F)`` scaled sensor rows,
+output ``(S, T, F_out)`` where position t forecasts row t + 1.  The token
+embedding and the language model head have no counterpart for real-valued
+rows, so ``h_0 = X W_in`` and ``Y = RMSNorm(h_L) W_out + b``.  One module
+(:class:`SequenceBackbone`), one :class:`BackboneConfig` and one
+:func:`forward` serve every kind; a kind is a preset of the configuration's
+keywords and nothing selects a path.
 
 Every block is pre-norm residual: ``h += Mixer(RMSNorm(h))``, ``h +=
-FFN(RMSNorm(h))``.  Layers are numbered from 1 here, for both kinds (GLM's
-source numbers its own from 0).  ``kimi_linear``: every fourth layer's
-mixer is MLA, the others' KDA.  ``glm_moe_lite``: every layer's is MLA.  The
-leading ``first_k_dense_replace`` layers' feed-forward is dense, the others'
-is the expert layer.
+FFN(RMSNorm(h))``.  Layers are numbered from 1 here, for every kind (GLM's
+and LFM2's sources number their own from 0).  Which mixer a layer has is
+data: ``BackboneConfig.pattern``, one of ``MIXER_KINDS`` a layer.
+``kimi_linear``: every fourth layer's mixer is MLA, the others' KDA.
+``glm_moe_lite``: every layer's is MLA (both derive the pattern from
+``full_attn_every``).  ``lfm2_moe``: the source's ``layer_types`` from its
+layer 1 on, a gated short convolution in three layers of four and
+grouped-query attention in the fourth (``layer_pattern``).  The leading
+``first_k_dense_replace`` layers' feed-forward is dense, the others' is the
+expert layer.
 
 - **KDA** (Kimi Delta Attention): ``q, k, v`` each through a depthwise causal
   convolution and SiLU, ``q, k`` L2-normalised per head; a per-channel
@@ -31,24 +37,38 @@ is the expert layer.
   ``lax.scan``.
 - **MLA** (:func:`mla_mixer`): keys and values from a normalised latent
   (``kv_lora_rank``), ``qk_rope_head_dim`` key channels shared by all heads,
-  a causal softmax over ``(q_n k_n + q_r k_r) / sqrt(d_nope + d_rope)``,
-  computed in query blocks of ``MLA_BLOCK`` rows, each against the prefix of
-  keys it may see (:func:`_causal_core`; one block, the whole masked
-  square, where the sequence is no longer than a block or no multiple).
-  ``kimi_linear``: queries from one matrix, the shared channels carried
-  without rotation (NoPE).  ``glm_moe_lite``: queries through a low-rank
-  pair with a norm between (``q_lora_rank``), rotary positions on ``q_r``
-  and the shared ``k_r`` (``rope_theta``; the position counted inside the
-  sequence, pairs half-split), values wider than the keys' own channels.
+  a causal softmax over ``(q_n k_n + q_r k_r) / sqrt(d_nope + d_rope)``
+  (:func:`_causal_core`).  ``kimi_linear``: queries from one matrix, the
+  shared channels carried without rotation (NoPE).  ``glm_moe_lite``:
+  queries through a low-rank pair with a norm between (``q_lora_rank``),
+  rotary positions on ``q_r`` and the shared ``k_r`` (``rope_theta``; the
+  position counted inside the sequence, pairs half-split), values wider than
+  the keys' own channels.
+- **Gated short convolution** (:func:`conv_mixer`, ``lfm2_moe``): ``[B ; C ;
+  X] = x W_in``, ``y = (C * conv(B * X)) W_out`` with a depthwise causal
+  convolution of ``short_conv_kernel_size`` taps (:func:`short_conv`, as
+  KDA's); no activation, no state beyond the taps' rows.  Half matrix
+  products, half element-wise traffic over ``(positions, 3 D)`` arrays.
+- **GQA** (:func:`gqa_mixer`, ``lfm2_moe``): ``num_heads`` query heads over
+  ``num_kv_heads`` key/value heads, queries and keys each through an RMSNorm
+  over a head's channels and then rotated on all of them; query head ``i``
+  reads key/value head ``i // (num_heads / num_kv_heads)``
+  (:func:`_grouped_core`, which contracts a key/value head against its group
+  of query heads without repeating keys or values).
+- **The causal cores**, latent and grouped, share ONE rule
+  (:func:`_query_blocks`, :func:`_attend`): query blocks of ``MLA_BLOCK``
+  rows, each against the prefix of keys it may see; one block, the whole
+  masked square, where the sequence is no longer than a block or no multiple.
 - **Expert layer** (:func:`expert_layer`): a sigmoid router over ALL the
   model's experts, the ``num_experts_per_token`` largest kept and
-  renormalised; the module is told which contiguous range of experts it
-  holds and computes the sum over the selected experts it holds (the absent
-  experts' terms are left out, as one chip of an expert-parallel deployment
-  would before the exchange), plus the shared expert.  Positions are sorted
-  by expert and multiplied through ``lax.ragged_dot``: no capacity, so no
-  pair is ever dropped.  The selection bias of the source is a buffer held
-  at 0 and is not stored.
+  renormalised (over their sum + ``route_eps``); the module is told which
+  contiguous range of experts it holds and computes the sum over the
+  selected experts it holds (the absent experts' terms are left out, as one
+  chip of an expert-parallel deployment would before the exchange), plus the
+  shared expert where the model has one (``num_shared_experts`` 0: none is
+  stored or computed).  Positions are sorted by expert and multiplied
+  through ``lax.ragged_dot``: no capacity, so no pair is ever dropped.  The
+  selection bias of the sources is a buffer held at 0 and is not stored.
 - **MTP module** (:func:`_mtp`, ``glm_moe_lite``; parameters ``mtp_*``,
   named scope ``backbone.mtp``): ``h'_i = W_eh [RMSNorm(h_{L,i});
   RMSNorm(h_{0,i+1})]``, one whole block of the expert-layer form with its
@@ -64,13 +84,15 @@ rotation, softmax, router scores, the KDA state and its decays, the loss and
 the optimiser are float32.
 
 Parameters of one kind of part are stacked over the layers that have it
-(``kda_wq`` is ``(KDA layers, D, H dk)``, ``moe_wg`` ``(expert layers, held,
-D, W)``, the two norms ``(layers, D)``, the module's ``(1, ...)``).  The
-leading dense layers are traced one by one and the expert layers run as ONE
-``lax.scan`` over their stacked parameters, whose body chooses its layer's
-mixer by ``lax.cond`` where the layers' mixers are of two kinds and traces
-the one kind where they are not: the program holds the expert layer, MLA and
-the scan's KDA once however many layers there are, which is what keeps a
+(``kda_wq`` is ``(KDA layers, D, H dk)``, ``conv_win`` ``(convolution layers,
+D, 3 D)``, ``moe_wg`` ``(expert layers, held, D, W)``, the two norms
+``(layers, D)``, the module's ``(1, ...)``).  The leading dense layers are
+traced one by one and the expert layers run as ONE ``lax.scan`` over their
+stacked parameters, whose body chooses its layer's mixer among the kinds
+those layers have (:func:`_choose`: a ``lax.cond`` where they are of two
+kinds) and traces the one kind where they are not: the program holds the
+expert layer and each of the scan's mixers once however many layers there
+are, which is what keeps a
 model of this size compilable in a build's set-up and its executable in a
 compile cache.  (A ``lax.cond`` between the dense and the expert
 feed-forward would put layer 1 into the scan too; the TPU compiler's
@@ -122,6 +144,10 @@ class BackboneConfig:
     kda_chunk: int = 64
     mixer_group: int = 2              # sequences a mixer reads at a time
     full_attn_every: int = 4          # layers 4, 8, ... are MLA
+    #: the mixer of every layer held, in order ("kda", "mla", "conv", "gqa");
+    #: empty: MLA at every ``full_attn_every``-th layer and KDA at the others
+    layer_pattern: Tuple[str, ...] = ()
+    num_kv_heads: int = 8             # GQA: key/value heads, a divisor of num_heads
     q_lora_rank: int = 0              # 0: queries from one matrix
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
@@ -135,6 +161,7 @@ class BackboneConfig:
     num_experts_per_token: int = 8
     num_shared_experts: int = 1
     routed_scaling_factor: float = 2.446
+    route_eps: float = 1e-20          # added to the selected scores' sum
     experts_held_from: int = 0
     experts_held: int = 8
     rms_norm_eps: float = 1e-5
@@ -142,8 +169,26 @@ class BackboneConfig:
     mtp_weight: float = 0.0           # lambda of the second loss term
     compute_dtype: Any = jnp.float32
 
+    @property
+    def pattern(self) -> Tuple[str, ...]:
+        """The mixer of layer 1, 2, ...: ``layer_pattern`` where it is given."""
+        return self.layer_pattern or tuple(
+            "mla" if layer % self.full_attn_every == 0 else "kda"
+            for layer in range(1, self.num_layers + 1))
+
+    @property
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """The kinds of mixer the pattern can give, sorted: those it names,
+        or both that ``full_attn_every``'s rule chooses between."""
+        return tuple(sorted(set(self.layer_pattern))) or ("kda", "mla")
+
+    @property
+    def gqa_head_dim(self) -> int:
+        """A grouped-query head's width: the source gives none of its own."""
+        return self.hidden_size // self.num_heads
+
     def mixer(self, layer: int) -> str:
-        return "mla" if layer % self.full_attn_every == 0 else "kda"
+        return self.pattern[layer - 1]
 
     def ffn(self, layer: int) -> str:
         return "dense" if layer <= self.first_k_dense_replace else "moe"
@@ -170,17 +215,20 @@ class BackboneConfig:
 # parameters: one flat dict, created in this order
 # ---------------------------------------------------------------------------
 
-#: the kinds of part a layer is made of: two mixers, two feed-forwards
-KINDS = ("kda", "mla", "dense", "moe")
+#: the kinds of mixer, and the kinds of part a layer is made of (a mixer and
+#: a feed-forward), in the order their parameters are created
+MIXER_KINDS = ("kda", "mla", "conv", "gqa")
+KINDS = MIXER_KINDS + ("dense", "moe")
 
 
 def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
     """``(name, shape, init)`` of every parameter, in creation order; the
-    leading axis of a ``kda_`` / ``mla_`` / ``dense_`` / ``moe_`` parameter
-    runs over the layers of that kind (:meth:`BackboneConfig.layers_of`), a
-    kind no layer has is left out; the ``mtp_`` parameters (the module's two
-    input norms and ``W_eh``, one whole MLA + expert block, its output norm)
-    are stacked over the MTP modules.  ``init``: ``fan_in`` (normal, std
+    leading axis of a ``kda_`` / ``mla_`` / ``conv_`` / ``gqa_`` / ``dense_``
+    / ``moe_`` parameter runs over the layers of that kind
+    (:meth:`BackboneConfig.layers_of`), a kind no layer has is left out, and
+    so are the shared expert's three matrices where the model has none; the
+    ``mtp_`` parameters (the module's two input norms and ``W_eh``, one whole
+    MLA + expert block, its output norm) are stacked over the MTP modules.  ``init``: ``fan_in`` (normal, std
     ``shape[-2] ** -0.5``; a convolution's fan-in is its width), ``ones``,
     ``zeros``, ``a_log`` (log of uniform(1, 16)), ``dt_bias`` (inverse
     softplus of a step drawn log-uniformly from [1e-3, 1e-1])."""
@@ -190,6 +238,12 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
     conv = cfg.short_conv_kernel_size
     w, e = cfg.moe_intermediate_size, cfg.experts_held
     ws = w * cfg.num_shared_experts
+    kv, hd = cfg.num_kv_heads, cfg.gqa_head_dim
+    shared = [
+        ("moe_shared_wg", (d, ws), "fan_in"),
+        ("moe_shared_wu", (d, ws), "fan_in"),
+        ("moe_shared_wd", (ws, d), "fan_in"),
+    ] if ws else []
     # the queries: one matrix, or a low-rank pair with a norm between
     queries = [("mla_wq", (d, h * qk), "fan_in")] if not qr else [
         ("mla_wq_a", (d, qr), "fan_in"),
@@ -221,6 +275,19 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
                            h * (cfg.qk_nope_head_dim + cfg.v_head_dim)), "fan_in"),
             ("mla_wo", (h * cfg.v_head_dim, d), "fan_in"),
         ],
+        "conv": [
+            ("conv_win", (d, 3 * d), "fan_in"),        # [B ; C ; X]
+            ("conv_taps", (conv, d), "fan_in"),
+            ("conv_wout", (d, d), "fan_in"),
+        ],
+        "gqa": [
+            ("gqa_wq", (d, h * hd), "fan_in"),
+            ("gqa_wk", (d, kv * hd), "fan_in"),
+            ("gqa_wv", (d, kv * hd), "fan_in"),
+            ("gqa_q_norm", (hd,), "ones"),
+            ("gqa_k_norm", (hd,), "ones"),
+            ("gqa_wo", (h * hd, d), "fan_in"),
+        ],
         "dense": [
             ("dense_wg", (d, cfg.intermediate_size), "fan_in"),
             ("dense_wu", (d, cfg.intermediate_size), "fan_in"),
@@ -228,9 +295,7 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
         ],
         "moe": [
             ("moe_router", (d, cfg.num_experts), "fan_in"),
-            ("moe_shared_wg", (d, ws), "fan_in"),
-            ("moe_shared_wu", (d, ws), "fan_in"),
-            ("moe_shared_wd", (ws, d), "fan_in"),
+            *shared,
             ("moe_wg", (e, d, w), "fan_in"),
             ("moe_wu", (e, d, w), "fan_in"),
             ("moe_wd", (e, w, d), "fan_in"),
@@ -526,6 +591,47 @@ _MLA_ATTENTION = telemetry.counter(
 MLA_BLOCK = 512
 
 
+_GQA_ATTENTION = telemetry.counter(
+    "gordo_gqa_attention_total",
+    "Causal cores of grouped-query attention traced, by the rule that gives "
+    "them: causal_blocks (query blocks against their key prefixes, "
+    "_grouped_core), whole (one block: the whole square, masked)",
+    labels=("rule",),
+)
+
+
+def _query_blocks(t: int, counter, prefix: str) -> List[Tuple[int, int]]:
+    """The block rule of every causal core, latent or grouped: the ``(lo,
+    hi)`` rows of each query block; block ``i`` attends to the keys before
+    ``hi``.  ``t // MLA_BLOCK`` blocks; one, the whole square, where ``t`` is
+    no longer than a block or no multiple of one.  Counts the core on
+    ``counter`` and on the enclosing span (``<prefix>_attn_*``): runs where
+    the core is traced."""
+    n = t // MLA_BLOCK if t % MLA_BLOCK == 0 else 1
+    counter.inc(1.0, "causal_blocks" if n > 1 else "whole")
+    telemetry.add_to_span(**{
+        f"{prefix}_attn_traces": 1, f"{prefix}_attn_blocks": n,
+        f"{prefix}_attn_pairs_computed": n * (n + 1) // 2,
+        f"{prefix}_attn_pairs_square": n * n})
+    return [(i * (t // n), (i + 1) * (t // n)) for i in range(n)]
+
+
+def _attend(spans, scores, scale: float, values):
+    """The causal softmax of every query block and its product with the
+    values: ``scores`` yields block ``(lo, hi)``'s unscaled scores ``(...,
+    hi - lo, hi)``, of which only the last ``hi - lo`` columns hold masked
+    pairs; ``values(probs, hi)`` multiplies a block's weights with the first
+    ``hi`` values and returns ``(b, hi - lo, ...)``.  A row's softmax is over
+    exactly the entries it has in the whole square (the masked ones weigh
+    ``exp(-inf) = 0`` there), so the blocks are the square's arithmetic."""
+    blocks = []
+    for (lo, hi), block in zip(spans, scores):
+        causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
+        block = jnp.where(causal, block * scale, -jnp.inf)
+        blocks.append(values(jax.nn.softmax(block, axis=-1), hi))
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
 def _causal_core(cfg: BackboneConfig, q, k_n, k_r, v):
     """Causal softmax attention ``(b, t, heads, dv)``, float32: queries ``q``
     (b, t, heads, dn + dr), the keys' own channels ``k_n`` (b, t, heads, dn),
@@ -533,44 +639,56 @@ def _causal_core(cfg: BackboneConfig, q, k_n, k_r, v):
     queries' last ``dr`` where ``rope_theta`` is set, values ``v`` (b, t,
     heads, dv); scores ``(q_n k_n + q_r k_r) / sqrt(dn + dr)``.
 
-    Computed in ``t // MLA_BLOCK`` query blocks, each against the prefix of
-    keys it may see: a block's scores are ``(b, heads, B, (i + 1) B)``, only
-    its last ``B`` columns hold masked pairs, and no fully masked block is
-    multiplied, exponentiated, stored or differentiated.  A row's softmax is
-    over exactly the entries it has in the whole square (the masked ones
-    weigh ``exp(-inf) = 0`` there), so the forward is the same arithmetic.
-    ``t <= MLA_BLOCK`` or ``t`` no multiple of it: one block, the whole
-    square, operation for operation the program it was before there were
-    blocks (which is why every block's first product comes before the
-    rotation).  Keys and values are cast to the compute dtype block by block,
-    so that the blocks' gradients for them are summed in float32."""
+    Computed in query blocks, each against the prefix of keys it may see
+    (:func:`_query_blocks`, :func:`_attend`): a block's scores are ``(b,
+    heads, B, (i + 1) B)``, and no fully masked block is multiplied,
+    exponentiated, stored or differentiated.  One block is the whole square,
+    operation for operation the program it was before there were blocks
+    (which is why every block's first product comes before the rotation).
+    Keys and values are cast to the compute dtype block by block, so that
+    the blocks' gradients for them are summed in float32."""
     cd, dn, dr = cfg.compute_dtype, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    t = q.shape[1]
-    n = t // MLA_BLOCK if t % MLA_BLOCK == 0 else 1
-    spans = [(i * (t // n), (i + 1) * (t // n)) for i in range(n)]
-    # runs where the core is traced
-    _MLA_ATTENTION.inc(1.0, "causal_blocks" if n > 1 else "whole")
-    telemetry.add_to_span(
-        mla_attn_traces=1, mla_attn_blocks=n,
-        mla_attn_pairs_computed=n * (n + 1) // 2, mla_attn_pairs_square=n * n)
+    spans = _query_blocks(q.shape[1], _MLA_ATTENTION, "mla")
     q_n = q[..., :dn]
     own = [jnp.einsum("bthc,bshc->bhts", q_n[:, lo:hi].astype(cd), k_n[:, :hi].astype(cd),
                       preferred_element_type=F32) for lo, hi in spans]
     q_r = q[..., dn:]
     if cfg.rope_theta:
-        cos, sin = rotary(t, dr, cfg.rope_theta)
+        cos, sin = rotary(q.shape[1], dr, cfg.rope_theta)
         q_r = rotate(q_r, cos[:, None, :], sin[:, None, :])
         k_r = rotate(k_r, cos, sin)
-    blocks = []
-    for (lo, hi), scores in zip(spans, own):
-        scores += jnp.einsum("bthc,bsc->bhts", q_r[:, lo:hi].astype(cd), k_r[:, :hi].astype(cd),
-                             preferred_element_type=F32)
-        causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
-        scores = jnp.where(causal, scores * ((dn + dr) ** -0.5), -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        blocks.append(jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v[:, :hi].astype(cd),
-                                 preferred_element_type=F32))
-    return blocks[0] if n == 1 else jnp.concatenate(blocks, axis=1)
+    scores = (
+        first + jnp.einsum("bthc,bsc->bhts", q_r[:, lo:hi].astype(cd), k_r[:, :hi].astype(cd),
+                           preferred_element_type=F32)
+        for (lo, hi), first in zip(spans, own))
+    return _attend(
+        spans, scores, (dn + dr) ** -0.5,
+        lambda probs, hi: jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v[:, :hi].astype(cd),
+                                     preferred_element_type=F32))
+
+
+def _grouped_core(cfg: BackboneConfig, q, k, v):
+    """Causal softmax attention ``(b, t, heads, hd)``, float32, for grouped
+    keys and values: queries ``q`` (b, t, heads, hd), ``k`` and ``v`` (b, t,
+    kv, hd), all rotated and normalised already; query head ``i`` reads
+    key/value head ``i // (heads / kv)``; scores ``q k / sqrt(hd)``.  The
+    block rule is :func:`_causal_core`'s (:func:`_query_blocks`,
+    :func:`_attend`).  A key/value head is contracted against its group of
+    query heads in one product: keys and values are never repeated."""
+    cd = cfg.compute_dtype
+    b, t, h, hd = q.shape
+    kv = k.shape[2]
+    spans = _query_blocks(t, _GQA_ATTENTION, "gqa")
+    q = q.reshape(b, t, kv, h // kv, hd)
+    scores = (
+        jnp.einsum("btkgc,bskc->bkgts", q[:, lo:hi].astype(cd), k[:, :hi].astype(cd),
+                   preferred_element_type=F32)
+        for lo, hi in spans)
+    o = _attend(
+        spans, scores, hd ** -0.5,
+        lambda probs, hi: jnp.einsum("bkgts,bskv->btkgv", probs.astype(cd), v[:, :hi].astype(cd),
+                                     preferred_element_type=F32))
+    return o.reshape(b, t, h, hd)
 
 
 def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
@@ -596,6 +714,38 @@ def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
     with jax.named_scope("backbone.mla.attn"):
         o = _causal_core(cfg, q, k_n, k_r, v)
     return _mm(o.reshape(b, t, h * dv), p["mla_wo"], cd)
+
+
+def gqa_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    """Grouped-query attention: ``num_heads`` query heads over
+    ``num_kv_heads`` key/value heads; queries and keys each through an
+    RMSNorm over a head's channels (one weight vector for all query heads,
+    one for all key heads), then rotary positions on all of a head's
+    channels; a causal softmax (:func:`_grouped_core`)."""
+    cd, h, kv, hd = cfg.compute_dtype, cfg.num_heads, cfg.num_kv_heads, cfg.gqa_head_dim
+    b, t, _ = x.shape
+    q = _mm(x, p["gqa_wq"], cd).reshape(b, t, h, hd)
+    k = _mm(x, p["gqa_wk"], cd).reshape(b, t, kv, hd)
+    v = _mm(x, p["gqa_wv"], cd).reshape(b, t, kv, hd)
+    with jax.named_scope("backbone.gqa.attn"):
+        cos, sin = rotary(t, hd, cfg.rope_theta)
+        q, k = (rotate(rms_norm(a, p[f"gqa_{n}_norm"], cfg.rms_norm_eps),
+                       cos[:, None, :], sin[:, None, :]) for a, n in ((q, "q"), (k, "k")))
+        o = _grouped_core(cfg, q, k, v)
+    return _mm(o.reshape(b, t, h * hd), p["gqa_wo"], cd)
+
+
+def conv_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+    """The gated short convolution: ``[B ; C ; X] = x W_in``, ``y = (C *
+    conv(B * X)) W_out`` with a depthwise causal convolution over time
+    (:func:`short_conv`, ``short_conv_kernel_size`` taps).  No activation,
+    no state beyond the taps' rows."""
+    cd, d = cfg.compute_dtype, cfg.hidden_size
+    bcx = _mm(x, p["conv_win"], cd)
+    with jax.named_scope("backbone.conv.gate"):
+        gate_in, gate_out, inner = bcx[..., :d], bcx[..., d:2 * d], bcx[..., 2 * d:]
+        gated = gate_out * short_conv(gate_in * inner, p["conv_taps"])
+    return _mm(gated, p["conv_wout"], cd)
 
 
 @jax.custom_vjp
@@ -624,14 +774,14 @@ def route(cfg: BackboneConfig, router, x):
         x.astype(F32), router, precision=jax.lax.Precision.HIGHEST))
     top, experts = jax.lax.top_k(scores, cfg.num_experts_per_token)
     weights = cfg.routed_scaling_factor * top / (
-        jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        jnp.sum(top, axis=-1, keepdims=True) + cfg.route_eps)
     return experts, weights
 
 
 def expert_layer(cfg: BackboneConfig, p: Dict[str, Any], x):
     """``(y, tokens)``: the held experts' part of the routed sum plus the
-    shared expert, for ``x`` (N, D); ``tokens`` (held,) counts the positions
-    each held expert computed."""
+    shared expert (where the model has one), for ``x`` (N, D); ``tokens``
+    (held,) counts the positions each held expert computed."""
     cd, k, held = cfg.compute_dtype, cfg.num_experts_per_token, cfg.experts_held
     n, d = x.shape
     with jax.named_scope("backbone.moe.route"):
@@ -659,7 +809,8 @@ def expert_layer(cfg: BackboneConfig, p: Dict[str, Any], x):
         mid = jax.nn.silu(rd(xs, p["moe_wg"]).astype(F32)) * rd(xs, p["moe_wu"])
         ys = _permute(rd(mid, p["moe_wd"]), inverse, order).reshape(n, k, d)
         y = jnp.sum(ys * jnp.where(mine, weights, 0.0)[..., None], axis=1)
-        y += swiglu(x, p["moe_shared_wg"], p["moe_shared_wu"], p["moe_shared_wd"], cd)
+        if cfg.num_shared_experts:
+            y += swiglu(x, p["moe_shared_wg"], p["moe_shared_wu"], p["moe_shared_wd"], cd)
     return y, tokens
 
 
@@ -672,11 +823,21 @@ def _slice(stack: Dict[str, Any], slot) -> Dict[str, Any]:
             for name, a in stack.items()}
 
 
+MIXERS = {"kda": kda_mixer, "mla": mla_mixer, "conv": conv_mixer, "gqa": gqa_mixer}
+_MIXERS_TRACED = telemetry.counter(
+    "gordo_backbone_mixers_total",
+    "Mixers of the sequence backbone traced (forward, recomputation and "
+    "held-out forecast each trace theirs), by kind: kda, mla, conv, gqa",
+    labels=("kind",),
+)
+
+
 def _mixer_of(cfg: BackboneConfig, kind: str, p: Dict[str, Any], norm, h):
     """``Mixer(RMSNorm(h))`` of one kind for a group of sequences (G, T, D)."""
     x = rms_norm(h, norm, cfg.rms_norm_eps)
+    _MIXERS_TRACED.inc(1.0, kind)  # runs where the mixer is traced
     with jax.named_scope("backbone." + kind):
-        return (kda_mixer if kind == "kda" else mla_mixer)(cfg, p, x)
+        return MIXERS[kind](cfg, p, x)
 
 
 def _groups(cfg: BackboneConfig, h):
@@ -686,12 +847,37 @@ def _groups(cfg: BackboneConfig, h):
     return h.reshape((b // group, group) + h.shape[1:])
 
 
+def _which(cfg: BackboneConfig, mixers, stacked: bool = True) -> Dict[str, Any]:
+    """What tells layers' mixers apart inside a program: for the layers whose
+    ``(kind, slot in that kind's stack)`` are ``mixers``, ``is_<kind>`` for
+    every kind the model's pattern can give but the first, and per kind the
+    layer's slot in its stack (0 where it has none).  Arrays over the layers;
+    scalars for the one layer where not ``stacked``."""
+    kinds = cfg.mixer_kinds
+    marks = {"is_" + kind: [of == kind for of, _ in mixers] for kind in kinds[1:]}
+    slots = {kind: [slot if of == kind else 0 for of, slot in mixers] for kind in kinds}
+    pick = (lambda v: v) if stacked else (lambda v: v[0])  # noqa: E731
+    return {**{k: jnp.asarray(pick(v)) for k, v in marks.items()},
+            **{k: jnp.asarray(pick(v), jnp.int32) for k, v in slots.items()}}
+
+
+def _choose(which, kinds, branch):
+    """``branch(kind)`` for the one of ``kinds`` that ``which`` marks: the
+    kind itself where there is one, else a ``lax.cond`` on the last kind's
+    mark whose other side chooses among the rest."""
+    *rest, last = kinds
+    if not rest:
+        return branch(last)
+    return jax.lax.cond(which["is_" + last], lambda: branch(last),
+                        lambda: _choose(which, rest, branch))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _mixer(cfg: BackboneConfig, mixers, norm, which, h):
     """``Mixer(RMSNorm(h))`` on the stream ``h`` (B, T, D) for one layer of
-    the stacks ``mixers`` (``{"kda": ..., "mla": ...}`` or one of them):
-    ``which`` says whether the layer's mixer is MLA and its slot in each
-    stack (0 where it has none), as traced values.
+    the stacks ``mixers`` (kind -> that kind's stacked parameters, for the
+    kinds the layers at hand have): ``which`` (:func:`_which`) says which
+    kind the layer's mixer is and its slot in each stack, as traced values.
 
     A mixer reads ``mixer_group`` sequences at a time (sequences do not see
     each other, and a mixer's intermediates are what fills the memory).
@@ -700,18 +886,12 @@ def _mixer(cfg: BackboneConfig, mixers, norm, which, h):
     group once inside the branch of its kind, and sums the groups' gradients
     at the size of one layer before it puts them into the stack's shape.
     Left to ``jax.checkpoint`` around a ``lax.cond``, every intermediate of
-    both kinds crosses from the forward conditional to the backward one, and
+    every kind crosses from the forward conditional to the backward one, and
     a gradient in the stack's shape is added up once a group."""
     p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
 
     def one(hg):
-        if len(p) == 1:
-            (kind,) = p
-            return _mixer_of(cfg, kind, p[kind], norm, hg)
-        return jax.lax.cond(
-            which["is_mla"],
-            lambda: _mixer_of(cfg, "mla", p["mla"], norm, hg),
-            lambda: _mixer_of(cfg, "kda", p["kda"], norm, hg))
+        return _choose(which, sorted(p), lambda kind: _mixer_of(cfg, kind, p[kind], norm, hg))
 
     groups = _groups(cfg, h)
     out = one(groups[0])[None] if groups.shape[0] == 1 else jax.lax.map(one, groups)
@@ -736,13 +916,7 @@ def _mixer_bwd(cfg, res, ct):
 
     def one(acc, pair):
         hg, ctg = pair
-        if len(p) == 1:
-            (kind,) = p
-            d, dhg = grads(kind, hg, ctg)
-        else:
-            d, dhg = jax.lax.cond(
-                which["is_mla"],
-                lambda: grads("mla", hg, ctg), lambda: grads("kda", hg, ctg))
+        d, dhg = _choose(which, sorted(p), lambda kind: grads(kind, hg, ctg))
         return jax.tree.map(jnp.add, acc, d), dhg
 
     zero = (jax.tree.map(jnp.zeros_like, p), jnp.zeros_like(norm))
@@ -798,10 +972,9 @@ def _mtp(cfg: BackboneConfig, params: Dict[str, Any], h0, h):
     both = jnp.concatenate([rms_norm(h, own["norm_h"][0], eps),
                             rms_norm(ahead, own["norm_e"][0], eps)], axis=-1)
     h = _mm(both, own["weh"][0], cd)
-    first = {"is_mla": jnp.asarray(True), "kda": jnp.asarray(0, jnp.int32),
-             "mla": jnp.asarray(0, jnp.int32)}
     mla = {k: v for k, v in own.items() if k.startswith("mla_")}
-    h = h + _mixer(cfg, {"mla": mla}, own["mixer_norm"][0], first, h)
+    h = h + _mixer(cfg, {"mla": mla}, own["mixer_norm"][0],
+                   _which(cfg, [("mla", 0)], stacked=False), h)
     y, tokens = jax.checkpoint(functools.partial(_expert_ffn, cfg))(
         {k: v[0] for k, v in own.items() if k.startswith("moe_")},
         own["ffn_norm"][0], h)
@@ -828,16 +1001,12 @@ def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False
     h = h0 = _mm(x, params["in_proj"], cfg.compute_dtype)
     stack = lambda kind: {  # noqa: E731
         k: v for k, v in params.items() if k.startswith(kind + "_")}
-    mixers = {kind: stack(kind) for kind in ("kda", "mla") if cfg.layers_of(kind)}
-    slot = lambda kind, l: (  # noqa: E731
-        cfg.layers_of(kind).index(l) if l in cfg.layers_of(kind) else 0)
-    which = lambda layers: {  # noqa: E731
-        "is_mla": jnp.asarray([cfg.mixer(l) == "mla" for l in layers]),
-        "kda": jnp.asarray([slot("kda", l) for l in layers], jnp.int32),
-        "mla": jnp.asarray([slot("mla", l) for l in layers], jnp.int32),
-    }
-    of_kinds = lambda layers: {  # noqa: E731  (one kind: no conditional is traced)
-        kind: mixers[kind] for kind in sorted({cfg.mixer(l) for l in layers})}
+    # the mixers of some layers: the stacks of the kinds they have, and what
+    # tells them apart (one kind: no conditional is traced)
+    of_kinds = lambda layers: {  # noqa: E731
+        kind: stack(kind) for kind in sorted({cfg.mixer(l) for l in layers})}
+    which = lambda layers: _which(cfg, [  # noqa: E731
+        (cfg.mixer(l), cfg.layers_of(cfg.mixer(l)).index(l)) for l in layers])
     n_dense = len(cfg.layers_of("dense"))
     for l in range(1, n_dense + 1):
         h = h + _mixer(cfg, of_kinds([l]), params["mixer_norm"][l - 1],
@@ -919,6 +1088,8 @@ def _backbone(kind: str, n_features, n_features_out, compute_dtype, preset, widt
     unknown = sorted(set(widths) - known)
     if unknown:
         raise TypeError(f"{kind} got unknown arguments {unknown}")
+    if "layer_pattern" in widths:  # a YAML list: the configuration is hashed
+        widths = {**widths, "layer_pattern": tuple(widths["layer_pattern"])}
     cfg = BackboneConfig(**{
         **preset, **widths,
         "n_features": int(n_features),
@@ -931,6 +1102,15 @@ def _backbone(kind: str, n_features, n_features_out, compute_dtype, preset, widt
         raise ValueError("one multi-token-prediction module at most")
     if cfg.rope_theta and cfg.qk_rope_head_dim % 2:
         raise ValueError("rotary positions pair the channels: qk_rope_head_dim is odd")
+    if len(cfg.pattern) != cfg.num_layers or set(cfg.pattern) - set(MIXER_KINDS):
+        raise ValueError(
+            f"layer_pattern names one mixer of {MIXER_KINDS} for each of the "
+            f"{cfg.num_layers} layers; it is {cfg.pattern}")
+    if "gqa" in cfg.pattern and (
+            cfg.num_heads % cfg.num_kv_heads or cfg.gqa_head_dim % 2 or not cfg.rope_theta):
+        raise ValueError(
+            "grouped-query attention needs num_kv_heads a divisor of num_heads, "
+            "an even hidden_size / num_heads and a rope_theta")
     return SequenceBackbone(cfg)
 
 
@@ -985,3 +1165,44 @@ def glm_moe_lite(
     del context, stride, seed
     return _backbone("glm_moe_lite", n_features, n_features_out, compute_dtype,
                      GLM_MOE_LITE, widths)
+
+
+#: LFM2-24B-A2B's config.json as :class:`BackboneConfig` keywords.  The cut
+#: starts at the source's layer 1 (its two leading dense layers, both
+#: convolutions, are counted once), so ``layer_pattern`` is the source's
+#: ``layer_types`` from there on: attention at the source's layers 2, 6, ...
+LFM2_MOE = dict(
+    hidden_size=2048, num_heads=32, num_kv_heads=8,
+    short_conv_kernel_size=3, rope_theta=1e6, intermediate_size=11776,
+    first_k_dense_replace=1, moe_intermediate_size=1536, num_experts=64,
+    num_experts_per_token=4, num_shared_experts=0, routed_scaling_factor=1.0,
+    route_eps=1e-6, experts_held=8, rms_norm_eps=1e-5,
+)
+LFM2_LAYER_TYPES = ("conv", "conv", "full_attention", "conv") * 10
+
+
+@register_model_builder(type="SequenceForecast")
+def lfm2_moe(
+    n_features: int,
+    n_features_out: int = None,
+    compute_dtype: str = "auto",
+    context: int = None,
+    stride: int = None,
+    seed: int = 0,
+    **widths,
+) -> nn.Module:
+    """LFM2-24B-A2B's block at its published widths (``model_type``
+    ``lfm2_moe``): gated short convolutions in three layers of four and
+    grouped-query attention with normalised rotary heads in the fourth; the
+    leading layer's feed-forward is dense, the others' the 64-way expert
+    layer, which has no shared expert.  ``num_layers`` layers from the
+    source's layer 1 on (``layer_pattern`` follows the source's
+    ``layer_types`` unless given), ``experts_held`` routed experts from
+    ``experts_held_from``.  Every width is a keyword of
+    :class:`BackboneConfig`; tests pass a tiny preset."""
+    del context, stride, seed
+    n = int(widths.get("num_layers", BackboneConfig.num_layers))
+    source = tuple("gqa" if kind == "full_attention" else "conv"
+                   for kind in LFM2_LAYER_TYPES[1:1 + n])
+    return _backbone("lfm2_moe", n_features, n_features_out, compute_dtype,
+                     {**LFM2_MOE, "layer_pattern": source}, widths)

@@ -1504,6 +1504,9 @@ def _exact_fleet_program(
                 **({"mtp_depth": module.cfg.mtp_depth,
                     "mtp_weight": module.cfg.mtp_weight}
                    if getattr(module.cfg, "mtp_depth", 0) else {}),
+                # the layer pattern: how many layers have each kind of mixer
+                **{f"layers_{kind}": len(module.cfg.layers_of(kind))
+                   for kind in set(getattr(module.cfg, "pattern", ()))},
             )
         if sequence:
             params0 = None  # drawn where each fit begins, see _sequence_fits

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Time latent attention's causal core ALONE on the chip, at a sequence
+"""Time an attention layer's causal core ALONE on the chip, at a sequence
 cell's shape, for each candidate of ``backbone.MLA_BLOCK``: what chose the
 constant (PERF.md section 3).
 
@@ -7,10 +7,12 @@ constant (PERF.md section 3).
         [--blocks 256,512,1024,0] [--repeats 5]
 
 For every block size (0: one block, the whole square) it sets the constant,
-compiles ``backbone._causal_core`` at the cell's shape (``mixer_group``
-sequences of ``context`` rows, the configuration's heads and widths, matmul
-operands bfloat16) as the forward alone and as the forward with its
-``jax.vjp`` for all four inputs, runs each ``--repeats`` times after a
+compiles the core of the cell's attention layers (``backbone._causal_core``
+where they are latent, ``backbone._grouped_core`` where they are grouped: the
+preset is looked up by the configuration's ``kind``) at the cell's shape
+(``mixer_group`` sequences of ``context`` rows, the configuration's heads and
+widths, matmul operands bfloat16) as the forward alone and as the forward
+with its ``jax.vjp`` for all its inputs, runs each ``--repeats`` times after a
 warm-up and prints the best wall milliseconds: ``forward_ms``,
 ``forward_backward_ms`` and ``layer_ms``, their sum, which is what one
 attention block of an optimiser step costs (``_mixer_bwd`` recomputes the
@@ -32,34 +34,36 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def core_shapes(model: dict):
-    """``(cfg, shapes)``: the backbone's configuration (matmul operands
-    bfloat16, as ``auto`` resolves on a TPU) and the shapes ``(q, k_n, k_r,
-    v)`` of one mixer call of ``model`` (a configuration's ``model`` object;
-    widths it leaves out are its kind's)."""
-    import jax.numpy as jnp
-
+    """``(cfg, core, shapes)``: the backbone's configuration (the preset of
+    ``model["kind"]``, matmul operands bfloat16, as ``auto`` resolves on a
+    TPU), the causal core of its attention layers and the shapes of the
+    core's inputs for one mixer call of ``model`` (a configuration's
+    ``model`` object; widths it leaves out are its kind's): ``(q, k_n, k_r,
+    v)`` for latent attention, ``(q, k, v)`` for grouped."""
     from gordo_tpu.models.factories import backbone
 
     known = {k: v for k, v in model.items()
-             if k in backbone.BackboneConfig.__dataclass_fields__}
-    preset = backbone.GLM_MOE_LITE if model["kind"] == "glm_moe_lite" else {}
-    cfg = backbone.BackboneConfig(n_features=1, n_features_out=1, **{
-        **preset, **known, "compute_dtype": jnp.bfloat16})
+             if k in backbone.BackboneConfig.__dataclass_fields__ and k != "compute_dtype"}
+    cfg = getattr(backbone, model["kind"])(1, 1, compute_dtype="bfloat16", **known).cfg
     b, t, h = cfg.mixer_group, int(model["context"]), cfg.num_heads
+    if "gqa" in cfg.pattern:
+        hd, kv = cfg.gqa_head_dim, cfg.num_kv_heads
+        return cfg, backbone._grouped_core, ((b, t, h, hd), (b, t, kv, hd), (b, t, kv, hd))
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    return cfg, ((b, t, h, dn + dr), (b, t, h, dn), (b, t, dr), (b, t, h, cfg.v_head_dim))
+    return cfg, backbone._causal_core, (
+        (b, t, h, dn + dr), (b, t, h, dn), (b, t, dr), (b, t, h, cfg.v_head_dim))
 
 
 def time_core(core, shapes, repeats: int, seed: int = 0):
     """``{"forward_ms", "forward_backward_ms", "layer_ms", "compile_s"}`` of
-    ``core(q, k_n, k_r, v)`` on seeded normal inputs."""
+    ``core(*inputs)`` on seeded normal inputs of ``shapes``."""
     import jax
     import jax.numpy as jnp
     from fit_step_chip import timed
 
     keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes) + 1)
     args = [jax.random.normal(k, s, jnp.float32) for k, s in zip(keys, shapes)]
-    ct = jax.random.normal(keys[-1], shapes[3], jnp.float32)
+    ct = jax.random.normal(keys[-1], jax.eval_shape(core, *args).shape, jnp.float32)
 
     def both(*inputs):
         out, vjp = jax.vjp(core, *inputs)
@@ -94,8 +98,8 @@ def main(argv=None) -> int:
 
     manifest = manifest_mod.Manifest()
     model = manifest.config(manifest.cell(args.workload)["config"])["model"]
-    cfg, shapes = core_shapes(model)
-    core = functools.partial(backbone._causal_core, cfg)
+    cfg, core, shapes = core_shapes(model)
+    core = functools.partial(core, cfg)
     for block in (int(b) for b in args.blocks.split(",")):
         backbone.MLA_BLOCK = block or shapes[0][1]
         line = {"workload": args.workload, "block": block,
