@@ -58,6 +58,7 @@ can never reference a pack that a crash kept off disk.
 
 from __future__ import annotations
 
+import contextlib
 import fcntl
 import hashlib
 import io
@@ -67,7 +68,7 @@ import os
 import pickle
 import struct
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -118,6 +119,76 @@ _PACK_BYTES_TOTAL = telemetry.counter(
     "Bytes written to pack files, by operation (written | delta)",
     labels=("op",),
 )
+#: the stages of one pack write: ``gordo.build.write.<stage>`` spans and
+#: ``gordo_build_pipeline_stage_seconds{stage="write.<stage>"}``
+WRITE_SPAN_PREFIX = "gordo.build.write."
+
+
+class _WriteClock:
+    """Where one pack write's seconds go: ``serialize`` (the
+    ``leaf.tobytes()`` copies), ``file`` (the ``write`` and ``flush`` calls)
+    and ``fsync`` (the pack's, the meta file's, the directory's and the
+    index's), each summed over the write.  :meth:`close` gives each stage
+    one ``gordo.build.write.<stage>`` span record (first entry to last
+    exit, ``seconds`` the busy sum), one observation of
+    ``gordo_build_pipeline_stage_seconds{stage="write.<stage>"}`` and, on
+    whatever span encloses the write (the builder's ``gordo.build.write``,
+    which the chunk timeline keeps), the attribute ``<stage>_s``.  With
+    telemetry off it reads no clock and records nothing."""
+
+    def __init__(self, on: bool = True) -> None:
+        self.on = on and telemetry.enabled()
+        self._wall = time.time() - time.perf_counter()
+        # stage -> [busy seconds, first entry, last exit]; a write enters
+        # all three: serialize, file, fsync
+        self._stages: Dict[str, List[float]] = {}
+
+    def add(self, stage: str, t0: float, t1: float) -> None:
+        held = self._stages.setdefault(stage, [0.0, t0, t1])
+        held[0] += t1 - t0
+        held[2] = t1
+
+    @contextlib.contextmanager
+    def stage(self, name: str) -> Iterator[None]:
+        if not self.on:
+            yield
+            return
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.add(name, t0, time.perf_counter())
+
+    def write(self, fh, leaf: np.ndarray) -> None:
+        """``fh.write(leaf.tobytes())``, the copy and the write apart."""
+        if not self.on:
+            fh.write(leaf.tobytes())
+            return
+        t0 = time.perf_counter()
+        data = leaf.tobytes()
+        t1 = time.perf_counter()
+        fh.write(data)
+        self.add("serialize", t0, t1)
+        self.add("file", t1, time.perf_counter())
+
+    def close(self) -> None:
+        if not self.on:
+            return
+        # the builder's own series; imported here because the builder
+        # imports this module
+        from gordo_tpu.builder.timeline import STAGE_SECONDS
+
+        for name, (busy, first, last) in self._stages.items():
+            telemetry.record_span(WRITE_SPAN_PREFIX + name, self._wall + first,
+                                  self._wall + last, seconds=busy)
+            STAGE_SECONDS.observe(busy, "write." + name)
+            telemetry.add_to_span(**{name + "_s": busy})
+
+
+#: for the index updates that belong to no pack write (a generation stamp)
+_NO_CLOCK = _WriteClock(on=False)
+
+
 _PACK_DEVICE_PUTS = telemetry.counter(
     "gordo_artifact_pack_device_puts_total",
     "Whole-pack host->device transfers (the v2 load contract: exactly "
@@ -268,6 +339,7 @@ def _locked_index_update(
     directory: str,
     mutate: Callable[[Dict[str, Any]], None],
     after: Optional[Callable[[Dict[str, Any]], None]] = None,
+    clock: Optional[_WriteClock] = None,
 ) -> Dict[str, Any]:
     """Read-modify-write the index under an exclusive flock, swapping the
     new index in atomically (tmp + rename + dir fsync).  The lock
@@ -275,7 +347,8 @@ def _locked_index_update(
     disjoint chunks into ONE shared index.  ``after`` runs with the lock
     STILL HELD once the new index is durable (the generation sidecar
     write rides here, so two concurrent stamps can't publish sidecars
-    out of order)."""
+    out of order).  ``clock``: a pack write's, for the two fsyncs."""
+    clock = clock or _NO_CLOCK
     os.makedirs(directory, exist_ok=True)
     with open(os.path.join(directory, ".lock"), "a+") as lock:
         fcntl.flock(lock.fileno(), fcntl.LOCK_EX)
@@ -288,9 +361,11 @@ def _locked_index_update(
         with open(tmp, "w") as fh:
             json.dump(doc, fh, indent=1, sort_keys=True)
             fh.flush()
-            os.fsync(fh.fileno())
+            with clock.stage("fsync"):
+                os.fsync(fh.fileno())
         os.replace(tmp, path)
-        fsync_dir(directory)
+        with clock.stage("fsync"):
+            fsync_dir(directory)
         if after is not None:
             after(doc)
         return doc
@@ -448,6 +523,7 @@ def write_pack(
 
     tensors: List[Dict[str, Any]] = []
     skeletons: List[Tuple[int, int]] = []
+    clock = _WriteClock()
     tmp = os.path.join(directory, f"{pack_file}.tmp.{os.getpid()}")
     with open(tmp, "wb") as fh:
         fh.write(PACK_MAGIC + struct.pack("<I", PACK_VERSION))
@@ -455,7 +531,7 @@ def write_pack(
             offset = -(-fh.tell() // PAGE) * PAGE  # next page boundary
             fh.seek(offset)
             for _, leaves in flat:
-                fh.write(leaves[leaf_idx].tobytes())
+                clock.write(fh, leaves[leaf_idx])
             tensors.append(
                 {
                     "offset": offset,
@@ -463,11 +539,13 @@ def write_pack(
                     "dtype": dtype,
                 }
             )
-        for skeleton, _ in flat:
-            skeletons.append((fh.tell(), len(skeleton)))
-            fh.write(skeleton)
-        fh.flush()
-        os.fsync(fh.fileno())
+        with clock.stage("file"):
+            for skeleton, _ in flat:
+                skeletons.append((fh.tell(), len(skeleton)))
+                fh.write(skeleton)
+            fh.flush()
+        with clock.stage("fsync"):
+            os.fsync(fh.fileno())
         n_bytes = fh.tell()
     # injection seam: "enospc" surfaces as OSError to the caller, "crash"
     # aborts between the durable tmp write and the rename — exactly the
@@ -485,9 +563,11 @@ def write_pack(
     with open(tmp, "w") as fh:
         json.dump(meta_doc, fh, default=str)
         fh.flush()
-        os.fsync(fh.fileno())
+        with clock.stage("fsync"):
+            os.fsync(fh.fileno())
     os.replace(tmp, os.path.join(directory, meta_file))
-    fsync_dir(directory)  # both renames durable before the index names them
+    with clock.stage("fsync"):
+        fsync_dir(directory)  # both renames durable before the index names them
 
     entry = {
         "file": pack_file,
@@ -517,7 +597,8 @@ def write_pack(
             doc["machines"][name] = row
         _gc_dead_packs(directory, doc)
 
-    _locked_index_update(directory, mutate)
+    _locked_index_update(directory, mutate, clock=clock)
+    clock.close()
     _PACKS_TOTAL.inc(1.0, "written")
     _PACK_BYTES_TOTAL.inc(float(n_bytes), "written")
     return pack_id
@@ -552,6 +633,7 @@ def delta_write(
 
     new_skeletons: Dict[str, Dict[int, Tuple[int, int]]] = {}
     delta_bytes = 0
+    clock = _WriteClock()
     for pack_id, pack_names in by_pack.items():
         entry = doc["packs"][pack_id]
         sig = [
@@ -569,16 +651,19 @@ def delta_write(
                 slot = doc["machines"][name]["slot"]
                 for tensor, leaf in zip(entry["tensors"], leaves):
                     fh.seek(tensor["offset"] + slot * leaf.nbytes)
-                    fh.write(leaf.tobytes())
+                    clock.write(fh, leaf)
                     delta_bytes += leaf.nbytes
                 fh.seek(0, os.SEEK_END)
                 new_skeletons.setdefault(pack_id, {})[slot] = (
                     fh.tell(), len(skeleton),
                 )
-                fh.write(skeleton)
+                with clock.stage("file"):
+                    fh.write(skeleton)
                 delta_bytes += len(skeleton)
-            fh.flush()
-            os.fsync(fh.fileno())
+            with clock.stage("file"):
+                fh.flush()
+            with clock.stage("fsync"):
+                os.fsync(fh.fileno())
             entry["bytes"] = fh.seek(0, os.SEEK_END)
 
         if metadatas:
@@ -595,7 +680,8 @@ def delta_write(
             with open(tmp, "w") as fh:
                 json.dump(meta_doc, fh, default=str)
                 fh.flush()
-                os.fsync(fh.fileno())
+                with clock.stage("fsync"):
+                    os.fsync(fh.fileno())
             os.replace(tmp, meta_path)
 
     def mutate(idx: Dict[str, Any]) -> None:
@@ -619,7 +705,9 @@ def delta_write(
         after=lambda idx: _write_generation_file(
             directory, int(idx["generation"])
         ),
+        clock=clock,
     )
+    clock.close()
     _PACKS_TOTAL.inc(float(len(by_pack)), "delta")
     _PACK_BYTES_TOTAL.inc(float(delta_bytes), "delta")
     return sorted(models)

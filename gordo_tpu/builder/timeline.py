@@ -20,7 +20,11 @@ program_wait watcher      enqueued to the program's smallest output ready
 fetch        drive        blocking D2H of a chunk's results
 assemble     drive        per-machine detectors from the fetched tree
 handoff      drive        manifest, baselines, metadata, writer hand-off
-write        writer pool  one pack (v2) or one artifact (v1)
+write        writer pool  one pack (v2) or one artifact (v1); a pack's
+                          stages are span records inside it,
+                          ``gordo.build.write.<stage>``, and the
+                          row's ``counts.write`` (``serialize_s``,
+                          ``file_s``, ``fsync_s``)
 ============ ============ =============================================
 
 The device's side is worked out from two host stamps per fleet program:
@@ -66,7 +70,9 @@ STAGE_SECONDS = telemetry.histogram(
     "dispatch, fetch, assemble, handoff, device, fetch_exposed: one chunk; "
     "program, device_gap: one fleet program, which is one chunk unless its "
     "machines differ in length; load with the ingest plane off: one "
-    "machine; write: one pack or artifact)",
+    "machine; write: one pack or artifact; write.serialize, write.file, "
+    "write.fsync: one pack's seconds in each stage of its write, observed "
+    "by artifacts/pack.py inside or outside a build)",
     labels=("stage",),
 )
 DEVICE_IDLE_SECONDS = telemetry.counter(

@@ -50,9 +50,11 @@ behavior, bit for bit); the registry then only counts.
 
 from __future__ import annotations
 
+import functools
 import inspect
 import logging
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -320,6 +322,24 @@ class Program:
         return dt
 
 
+def _named_for(fn: Callable, name: str) -> Callable:
+    """``fn`` under its registry name.  jax names a jit's XLA module for the
+    function (``jit_<name>``), and every fleet closure is a local
+    ``program``: as ``fleet.exact`` -> ``jit_fleet_exact`` the profiler's
+    ``XLA Modules`` line, an ``--xla_dump_to`` directory and the largest
+    loaded program say which program they hold.  The module's name is part
+    of the persistent compile cache's key (debug information is not: a
+    program that differs from a cached one by ``jax.named_scope``s alone
+    loads the cached executable, with the cached names)."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = re.sub(r"\W", "_", name)
+    return program
+
+
 class ClosureProgram:
     """A per-configuration jitted CLOSURE with the :class:`Program`
     warm/bind surface.
@@ -350,7 +370,7 @@ class ClosureProgram:
         import jax
 
         self.name = name
-        self._jitted = jax.jit(fn, **jit_kwargs)
+        self._jitted = jax.jit(_named_for(fn, name), **jit_kwargs)
         with REGISTRY._lock:
             REGISTRY._jits[name] = self._jitted
         self._exes: Dict[Any, Any] = {}

@@ -112,6 +112,19 @@ mixer's backward is written out (:func:`_mixer`), so backward keeps the
 residual stream alone and recomputes each part once; a mixer reads
 ``mixer_group`` sequences at a time.
 
+Every part names its operations for the device trace with a
+``jax.named_scope`` (docs/observability.md has the list).  A mixer's and the
+feed-forwards' names are theirs alone; what the module does outside them has
+leaf names, each around arithmetic and none around a loop that holds a
+mixer: ``backbone.embed`` (the input projection), ``backbone.norm`` (the
+RMS norm before a mixer or a feed-forward), ``backbone.residual`` (a
+layer's output joining the stream), ``backbone.head`` (final norm, output
+projection and bias) and ``backbone.stack`` (a layer's slice out of its
+kind's stacked parameters, and in the backward the groups' gradient sums and
+their way back into the stack's shape).  The layer scan's own slices and
+writes, and what the compiler makes without an ``op_name`` (zero fills,
+conversions, the loops' copies), can take no name from here.
+
 The module has no packed layout (``train.fit.packed_layout`` is False for
 it: it has no ``pack``), and it asks the fleet program to run machines one
 after another (``fleet_axis = "map"``): one model fills the chip.
@@ -1030,7 +1043,8 @@ _MIXERS_TRACED = telemetry.counter(
 
 def _mixer_of(cfg: BackboneConfig, kind: str, p: Dict[str, Any], norm, h):
     """``Mixer(RMSNorm(h))`` of one kind for a group of sequences (G, T, D)."""
-    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    with jax.named_scope("backbone.norm"):
+        x = rms_norm(h, norm, cfg.rms_norm_eps)
     _MIXERS_TRACED.inc(1.0, kind)  # runs where the mixer is traced
     with jax.named_scope("backbone." + kind):
         return MIXERS[kind](cfg, p, x)
@@ -1084,7 +1098,8 @@ def _mixer(cfg: BackboneConfig, mixers, norm, which, h):
     Left to ``jax.checkpoint`` around a ``lax.cond``, every intermediate of
     every kind crosses from the forward conditional to the backward one, and
     a gradient in the stack's shape is added up once a group."""
-    p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
+    with jax.named_scope("backbone.stack"):
+        p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
 
     def one(hg):
         return _choose(which, sorted(p), lambda kind: _mixer_of(cfg, kind, p[kind], norm, hg))
@@ -1100,29 +1115,34 @@ def _mixer_fwd(cfg, mixers, norm, which, h):
 
 def _mixer_bwd(cfg, res, ct):
     mixers, norm, which, h = res
-    p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
+    with jax.named_scope("backbone.stack"):
+        p = {kind: _slice(stack, which[kind]) for kind, stack in mixers.items()}
 
     def grads(kind, hg, ctg):
         """This group's gradient for every kind's slice (zeros for the
         kinds the layer is not), the norm and the group's input."""
         _, vjp = jax.vjp(functools.partial(_mixer_of, cfg, kind), p[kind], norm, hg)
         dp, dnorm, dhg = vjp(ctg)
-        return ({k: dp if k == kind else jax.tree.map(jnp.zeros_like, p[k]) for k in p},
-                dnorm), dhg
+        with jax.named_scope("backbone.stack"):
+            others = {k: dp if k == kind else jax.tree.map(jnp.zeros_like, p[k]) for k in p}
+        return (others, dnorm), dhg
 
     def one(acc, pair):
         hg, ctg = pair
         d, dhg = _choose(which, sorted(p), lambda kind: grads(kind, hg, ctg))
-        return jax.tree.map(jnp.add, acc, d), dhg
+        with jax.named_scope("backbone.stack"):
+            return jax.tree.map(jnp.add, acc, d), dhg
 
-    zero = (jax.tree.map(jnp.zeros_like, p), jnp.zeros_like(norm))
+    with jax.named_scope("backbone.stack"):
+        zero = (jax.tree.map(jnp.zeros_like, p), jnp.zeros_like(norm))
     (dp, dnorm), dh = jax.lax.scan(one, zero, (_groups(cfg, h), _groups(cfg, ct)))
-    d_mixers = {
-        kind: {name: jax.lax.dynamic_update_index_in_dim(
-            jnp.zeros_like(a), dp[kind][name], which[kind], 0)
-            for name, a in stack.items()}
-        for kind, stack in mixers.items()
-    }
+    with jax.named_scope("backbone.stack"):
+        d_mixers = {
+            kind: {name: jax.lax.dynamic_update_index_in_dim(
+                jnp.zeros_like(a), dp[kind][name], which[kind], 0)
+                for name, a in stack.items()}
+            for kind, stack in mixers.items()
+        }
     no_gradient = {k: np.zeros(v.shape, jax.dtypes.float0) for k, v in which.items()}
     return d_mixers, dnorm, no_gradient, dh.reshape(h.shape)
 
@@ -1132,7 +1152,8 @@ _mixer.defvjp(_mixer_fwd, _mixer_bwd)
 
 def _dense_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
     """``FFN(RMSNorm(h))`` of a leading dense layer, ``h`` (B, T, D)."""
-    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    with jax.named_scope("backbone.norm"):
+        x = rms_norm(h, norm, cfg.rms_norm_eps)
     with jax.named_scope("backbone.ffn"):
         return swiglu(x, p["dense_wg"], p["dense_wu"], p["dense_wd"], cfg.compute_dtype)
 
@@ -1141,15 +1162,23 @@ def _expert_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
     """``(FFN(RMSNorm(h)), tokens)`` of an expert layer for the whole batch
     ``h`` (B, T, D): an expert sees a step's positions at once."""
     b, t, d = h.shape
-    x = rms_norm(h, norm, cfg.rms_norm_eps)
+    with jax.named_scope("backbone.norm"):
+        x = rms_norm(h, norm, cfg.rms_norm_eps)
     y, tokens = expert_layer(cfg, p, x.reshape(b * t, d))
     return y.reshape(b, t, d), tokens
 
 
 def _head(cfg: BackboneConfig, params: Dict[str, Any], norm, h):
     """``RMSNorm(h) W_out + b``: the forecast of every position."""
-    return _mm(rms_norm(h, norm, cfg.rms_norm_eps),
-               params["out_proj"], cfg.compute_dtype) + params["out_bias"]
+    with jax.named_scope("backbone.head"):
+        return _mm(rms_norm(h, norm, cfg.rms_norm_eps),
+                   params["out_proj"], cfg.compute_dtype) + params["out_bias"]
+
+
+def _residual(h, y):
+    """``h + y``: a layer's output joins the stream."""
+    with jax.named_scope("backbone.residual"):
+        return h + y
 
 
 def _mtp(cfg: BackboneConfig, params: Dict[str, Any], h0, h):
@@ -1169,12 +1198,12 @@ def _mtp(cfg: BackboneConfig, params: Dict[str, Any], h0, h):
                             rms_norm(ahead, own["norm_e"][0], eps)], axis=-1)
     h = _mm(both, own["weh"][0], cd)
     mla = {k: v for k, v in own.items() if k.startswith("mla_")}
-    h = h + _mixer(cfg, {"mla": mla}, own["mixer_norm"][0],
-                   _which(cfg, [("mla", 0)], stacked=False), h)
+    h = _residual(h, _mixer(cfg, {"mla": mla}, own["mixer_norm"][0],
+                            _which(cfg, [("mla", 0)], stacked=False), h))
     y, tokens = jax.checkpoint(functools.partial(_expert_ffn, cfg))(
         {k: v[0] for k, v in own.items() if k.startswith("moe_")},
         own["ffn_norm"][0], h)
-    return _head(cfg, params, own["out_norm"][0], h + y), tokens
+    return _head(cfg, params, own["out_norm"][0], _residual(h, y)), tokens
 
 
 def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False,
@@ -1196,7 +1225,8 @@ def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False
     squeeze = x.ndim == 2
     if squeeze:
         x = x[None]
-    h = h0 = _mm(x, params["in_proj"], cfg.compute_dtype)
+    with jax.named_scope("backbone.embed"):
+        h = h0 = _mm(x, params["in_proj"], cfg.compute_dtype)
     stack = lambda kind: {  # noqa: E731
         k: v for k, v in params.items() if k.startswith(kind + "_")}
     # the mixers of some layers: the stacks of the kinds they have, and what
@@ -1207,20 +1237,20 @@ def forward(cfg: BackboneConfig, params: Dict[str, Any], x, counts: bool = False
         (cfg.mixer(l), cfg.layers_of(cfg.mixer(l)).index(l)) for l in layers])
     n_dense = len(cfg.layers_of("dense"))
     for l in range(1, n_dense + 1):
-        h = h + _mixer(cfg, of_kinds([l]), params["mixer_norm"][l - 1],
-                       jax.tree.map(lambda a: a[0], which([l])), h)
-        h = h + jax.checkpoint(functools.partial(_dense_ffn, cfg))(
-            _slice(stack("dense"), l - 1), params["ffn_norm"][l - 1], h)
+        h = _residual(h, _mixer(cfg, of_kinds([l]), params["mixer_norm"][l - 1],
+                                jax.tree.map(lambda a: a[0], which([l])), h))
+        h = _residual(h, jax.checkpoint(functools.partial(_dense_ffn, cfg))(
+            _slice(stack("dense"), l - 1), params["ffn_norm"][l - 1], h))
     tokens = jnp.zeros((0, cfg.experts_held), jnp.int32)
     if cfg.moe_layers:
         rest = cfg.moe_layers
         rest_mixers = of_kinds(rest)
 
         def expert_block(h, layer):
-            h = h + _mixer(cfg, rest_mixers, layer["mixer_norm"], layer["which"], h)
+            h = _residual(h, _mixer(cfg, rest_mixers, layer["mixer_norm"], layer["which"], h))
             y, tokens = jax.checkpoint(functools.partial(_expert_ffn, cfg))(
                 layer["moe"], layer["ffn_norm"], h)
-            return h + y, tokens
+            return _residual(h, y), tokens
 
         h, tokens = jax.lax.scan(expert_block, h, {
             "mixer_norm": params["mixer_norm"][n_dense:],

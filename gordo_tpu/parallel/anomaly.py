@@ -414,7 +414,10 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
         return jnp.stack(out)
 
     def forecast(params, x):
-        return module.apply({"params": params}, x)
+        # the one name that wraps mixers: it marks the pass (a held-out
+        # forecast's operations keep their innermost backbone.* names)
+        with jax.named_scope("fit.forecast"):
+            return module.apply({"params": params}, x)
 
     def machine(start_m, xs_m, ys_m, te_m, fit_key):
         def one_fit(_, fit):
@@ -426,10 +429,11 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
                     slot = slots_e[i]
                     bw = w[slot]
                     (loss, counts), grads = grad_fn(p, x[slot], y[slot], bw)
-                    updates, s = tx.update(grads, s, p)
-                    routed = jax.tree.map(jnp.add, routed, counts)
-                    return (optax.apply_updates(p, updates), s, routed,
-                            seen + loss * jnp.sum(bw))
+                    with jax.named_scope("fit.optimizer"):
+                        updates, s = tx.update(grads, s, p)
+                        routed = jax.tree.map(jnp.add, routed, counts)
+                        p = optax.apply_updates(p, updates)
+                    return p, s, routed, seen + loss * jnp.sum(bw)
 
                 # this fit's own number of steps: a loop with a bound read
                 # from the data, not steps that are skipped
@@ -441,9 +445,11 @@ def _sequence_fits(module, cfg: TrainConfig, context: int, stride: int,
             # every fit: the last fit leaves the final parameters (and what
             # its steps routed) in it and no fit's result is held beside the
             # next one's
-            params0_m = start(start_m)
+            with jax.named_scope("fit.draw"):
+                params0_m = start(start_m)
+                state0_m = tx.init(params0_m)
             (params, opt_state, routed), history = jax.lax.scan(
-                epoch, (params0_m, tx.init(params0_m), no_counts), slots)
+                epoch, (params0_m, state0_m, no_counts), slots)
             pred = jax.lax.cond(
                 fold, forecast,
                 lambda p, x: jnp.zeros(pred_shape.shape, pred_shape.dtype),

@@ -193,18 +193,22 @@ def span(name: str, trace_id: Optional[str] = None,
         _finish(name, tid, attrs)
 
 
-def record_span(name: str, start: float, end: float, **attrs: Any) -> None:
+def record_span(name: str, start: float, end: float,
+                seconds: Optional[float] = None, **attrs: Any) -> None:
     """A span whose interval was worked out after the fact (the device's
     idle gap between two programs): histogram and span log like any other,
     under the context's trace id and open span, but no profiler
-    annotation — that cannot be written backwards."""
+    annotation — that cannot be written backwards.  ``seconds`` where the
+    interval holds pauses (a stage that was entered many times between
+    ``start`` and ``end``: its busy seconds); ``end - start`` otherwise."""
     if not metrics.enabled():
         return
     enclosing = _open_span.get()
     attrs.update(
         id=uuid.uuid4().hex[:16],
         parent=enclosing["id"] if enclosing is not None else None,
-        start=start, end=end, seconds=end - start,
+        start=start, end=end,
+        seconds=end - start if seconds is None else seconds,
     )
     _finish(name, current_trace_id(), attrs)
 

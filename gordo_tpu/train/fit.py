@@ -162,21 +162,25 @@ def make_loss_fn(apply_fn: Callable, loss: str, aux: bool = False,
     def loss_fn(params, x, y, w):
         pred = apply_fn({"params": params}, x)
         pred, extra = pred if aux else (pred, None)
-        if not second:
-            value = mean(pred, y, w)
-            return (value, extra) if aux else value
-        if w.ndim != 2:
-            raise ValueError("a second horizon needs a weight for every position")
-        pred, ahead = pred
-        # position i's successor: its target and its weight, zeros at the end
-        y2, w2 = (jnp.concatenate([z[:, 1:], jnp.zeros_like(z[:, :1])], axis=1)
-                  for z in (y, w))
-        first, then = mean(pred, y, w), mean(ahead, y2, w2)
-        value = first + second * then
-        if not aux:
-            return value
-        terms = jnp.stack([first * jnp.sum(w), jnp.sum(w), then * jnp.sum(w2), jnp.sum(w2)])
-        return value, {**(extra or {}), "loss_terms": terms}
+        # a leaf name: what the loss does with the module's forecast, and
+        # the start of its backward (PERF.md section 3)
+        with jax.named_scope("fit.loss"):
+            if not second:
+                value = mean(pred, y, w)
+                return (value, extra) if aux else value
+            if w.ndim != 2:
+                raise ValueError("a second horizon needs a weight for every position")
+            pred, ahead = pred
+            # position i's successor: its target and its weight, zeros at the end
+            y2, w2 = (jnp.concatenate([z[:, 1:], jnp.zeros_like(z[:, :1])], axis=1)
+                      for z in (y, w))
+            first, then = mean(pred, y, w), mean(ahead, y2, w2)
+            value = first + second * then
+            if not aux:
+                return value
+            terms = jnp.stack(
+                [first * jnp.sum(w), jnp.sum(w), then * jnp.sum(w2), jnp.sum(w2)])
+            return value, {**(extra or {}), "loss_terms": terms}
 
     return loss_fn
 
@@ -249,8 +253,9 @@ def make_epoch_fn(loss_fn: Callable, tx: optax.GradientTransformation,
             p, s = c
             bx, by, bw = batch
             loss, grads = grad_fn(p, bx, by, bw)
-            updates, s = tx.update(grads, s, p)
-            p = optax.apply_updates(p, updates)
+            with jax.named_scope("fit.optimizer"):
+                updates, s = tx.update(grads, s, p)
+                p = optax.apply_updates(p, updates)
             return (p, s), loss * jnp.sum(bw)
 
         (params, opt_state), losses = jax.lax.scan(step, (params, opt_state), (xb, yb, wb))
