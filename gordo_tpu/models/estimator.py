@@ -252,14 +252,16 @@ class LSTMForecast(LSTMAutoEncoder):
 class SequenceForecast(BaseJaxEstimator):
     """Next-row forecast by a sequence backbone that reads the series once
     (``models/factories/backbone.py``): every position of a ``context``-row
-    sequence forecasts its next row.  Three kinds, presets of one module:
+    sequence forecasts its next row.  Four kinds, presets of one module:
     ``kimi_linear`` (delta-rule linear attention with latent attention every
     fourth layer), ``glm_moe_lite`` (rotary latent attention in every layer,
     and a multi-token-prediction module that is trained on the row after next
     beside the main head: the loss is the next row's error plus
-    ``mtp_weight`` times the module's) and ``lfm2_moe`` (gated short
+    ``mtp_weight`` times the module's), ``lfm2_moe`` (gated short
     convolutions in three layers of four, grouped-query attention in the
-    fourth, expert layers with no shared expert).  Prediction, held-out forecasts,
+    fourth, expert layers with no shared expert) and ``afmoe`` (gated
+    grouped-query attention over a window in three layers of four and over
+    the whole prefix in the fourth, every part's output normalised too).  Prediction, held-out forecasts,
     thresholds and scoring read the main head alone and do not run the
     module; the artifact keeps its weights (the ``mtp_`` parameters) and
     :meth:`get_metadata` says what they are.

@@ -1,5 +1,5 @@
 from gordo_tpu.models.factories.backbone import (  # noqa: F401
-    glm_moe_lite, kimi_linear, lfm2_moe,
+    afmoe, glm_moe_lite, kimi_linear, lfm2_moe,
 )
 from gordo_tpu.models.factories.feedforward import (  # noqa: F401
     feedforward_hourglass,

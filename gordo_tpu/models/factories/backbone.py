@@ -1,11 +1,11 @@
 """Sequence backbones from current open models' blocks: ``kimi_linear``,
-``glm_moe_lite`` and ``lfm2_moe``.
+``glm_moe_lite``, ``lfm2_moe`` and ``afmoe``.
 
 No reference equivalent: upstream's factories are Keras feed-forward and
 LSTM stacks.  These are the blocks of Kimi-Linear-48B-A3B (``model_type``
 ``kimi_linear``, arXiv:2510.26692), of GLM-4.7-Flash (``model_type``
-``glm4_moe_lite``) and of LFM2-24B-A2B (``model_type`` ``lfm2_moe``) as the
-encoder of a per-machine forecaster: input ``(S, T, F)`` scaled sensor rows,
+``glm4_moe_lite``), of LFM2-24B-A2B (``model_type`` ``lfm2_moe``) and of
+Trinity-Mini (``model_type`` ``afmoe``) as the encoder of a per-machine forecaster: input ``(S, T, F)`` scaled sensor rows,
 output ``(S, T, F_out)`` where position t forecasts row t + 1.  The token
 embedding and the language model head have no counterpart for real-valued
 rows, so ``h_0 = X W_in`` and ``Y = RMSNorm(h_L) W_out + b``.  One module
@@ -14,14 +14,19 @@ rows, so ``h_0 = X W_in`` and ``Y = RMSNorm(h_L) W_out + b``.  One module
 keywords and nothing selects a path.
 
 Every block is pre-norm residual: ``h += Mixer(RMSNorm(h))``, ``h +=
-FFN(RMSNorm(h))``.  Layers are numbered from 1 here, for every kind (GLM's
+FFN(RMSNorm(h))``; with ``post_norms`` (``afmoe``) a part's output goes through
+a norm of the part's own before it joins the stream, ``h +=
+RMSNorm(Mixer(RMSNorm(h)))`` (``<kind>_post_norm``).  Layers are numbered from 1 here, for every kind (GLM's
 and LFM2's sources number their own from 0).  Which mixer a layer has is
 data: ``BackboneConfig.pattern``, one of ``MIXER_KINDS`` a layer.
 ``kimi_linear``: every fourth layer's mixer is MLA, the others' KDA.
 ``glm_moe_lite``: every layer's is MLA (both derive the pattern from
 ``full_attn_every``).  ``lfm2_moe``: the source's ``layer_types`` from its
 layer 1 on, a gated short convolution in three layers of four and
-grouped-query attention in the fourth (``layer_pattern``).  The leading
+grouped-query attention in the fourth (``layer_pattern``).  ``afmoe``: the
+source's ``layer_types`` from its layer 1 on too, grouped-query attention over
+a window (``swa``) in three layers of four and over the whole prefix
+(``gqa``) in the fourth.  The leading
 ``first_k_dense_replace`` layers' feed-forward is dense, the others' is the
 expert layer.
 
@@ -54,11 +59,21 @@ expert layer.
   over a head's channels and then rotated on all of them; query head ``i``
   reads key/value head ``i // (num_heads / num_kv_heads)``
   (:func:`_grouped_core`, which contracts a key/value head against its group
-  of query heads without repeating keys or values).
+  of query heads without repeating keys or values).  ``afmoe`` has the same
+  mixer in two kinds of layer (its ``kind`` argument; parameters ``gqa_*`` and
+  ``swa_*``): ``swa``, a row against the last ``attn_window`` rows, rotated;
+  ``gqa``, against its whole prefix, with no position at all (``gqa_rotary``
+  false); heads of ``head_dim`` channels, and the heads' outputs times
+  ``sigmoid(x W_z)`` before the output projection (``attn_gate``).
 - **The causal cores**, latent and grouped, share ONE rule
   (:func:`_query_blocks`, :func:`_attend`): query blocks of ``MLA_BLOCK``
   rows, each against the prefix of keys it may see; one block, the whole
   masked square, where the sequence is no longer than a block or no multiple.
+  Under a window a block reads the keys of the whole blocks its window
+  reaches and no others: the blocks past that reach are one shape and run as
+  one loop.  A whole-prefix core has ``ATTN_MAX_BLOCKS`` blocks at most, and
+  one whose scores would not fit ``ATTN_KEEP_BYTES`` recomputes each block in
+  the backward pass.
 - **Expert layer** (:func:`expert_layer`): a sigmoid router over ALL the
   model's experts, the ``num_experts_per_token`` largest kept and
   renormalised (over their sum + ``route_eps``); the module is told which
@@ -167,10 +182,15 @@ class BackboneConfig:
     kda_chunk: int = 64
     mixer_group: int = 2              # sequences a mixer reads at a time
     full_attn_every: int = 4          # layers 4, 8, ... are MLA
-    #: the mixer of every layer held, in order ("kda", "mla", "conv", "gqa");
+    #: the mixer of every layer held, in order (one of ``MIXER_KINDS`` each);
     #: empty: MLA at every ``full_attn_every``-th layer and KDA at the others
     layer_pattern: Tuple[str, ...] = ()
     num_kv_heads: int = 8             # GQA: key/value heads, a divisor of num_heads
+    head_dim: int = 0                 # GQA: a head's width; 0: hidden_size / num_heads
+    attn_window: int = 0              # rows a "swa" layer's query sees, itself included
+    gqa_rotary: bool = True           # False: the "gqa" (whole-prefix) layers carry no position
+    attn_gate: bool = False           # GQA: o * sigmoid(x W_z) before W_o
+    post_norms: bool = False          # a mixer's and a feed-forward's output normalised too
     q_lora_rank: int = 0              # 0: queries from one matrix
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
@@ -211,8 +231,9 @@ class BackboneConfig:
 
     @property
     def gqa_head_dim(self) -> int:
-        """A grouped-query head's width: the source gives none of its own."""
-        return self.hidden_size // self.num_heads
+        """A grouped-query head's width: ``head_dim``, or the stream's width
+        over the heads where the source gives none of its own."""
+        return self.head_dim or self.hidden_size // self.num_heads
 
     def mixer(self, layer: int) -> str:
         return self.pattern[layer - 1]
@@ -244,7 +265,7 @@ class BackboneConfig:
 
 #: the kinds of mixer, and the kinds of part a layer is made of (a mixer and
 #: a feed-forward), in the order their parameters are created
-MIXER_KINDS = ("kda", "mla", "conv", "gqa")
+MIXER_KINDS = ("kda", "mla", "conv", "gqa", "swa")
 KINDS = MIXER_KINDS + ("dense", "moe")
 
 
@@ -277,6 +298,16 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
         ("mla_q_norm", (qr,), "ones"),
         ("mla_wq_b", (qr, h * qk), "fan_in"),
     ]
+    # grouped-query attention, over the whole prefix ("gqa") or a window ("swa")
+    grouped = lambda kind: [  # noqa: E731
+        (f"{kind}_wq", (d, h * hd), "fan_in"),
+        (f"{kind}_wk", (d, kv * hd), "fan_in"),
+        (f"{kind}_wv", (d, kv * hd), "fan_in"),
+        *([(f"{kind}_wz", (d, h * hd), "fan_in")] if cfg.attn_gate else []),
+        (f"{kind}_q_norm", (hd,), "ones"),
+        (f"{kind}_k_norm", (hd,), "ones"),
+        (f"{kind}_wo", (h * hd, d), "fan_in"),
+    ]
     by_kind = {
         "kda": [
             ("kda_wq", (d, h * dk), "fan_in"),
@@ -307,14 +338,8 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
             ("conv_taps", (conv, d), "fan_in"),
             ("conv_wout", (d, d), "fan_in"),
         ],
-        "gqa": [
-            ("gqa_wq", (d, h * hd), "fan_in"),
-            ("gqa_wk", (d, kv * hd), "fan_in"),
-            ("gqa_wv", (d, kv * hd), "fan_in"),
-            ("gqa_q_norm", (hd,), "ones"),
-            ("gqa_k_norm", (hd,), "ones"),
-            ("gqa_wo", (h * hd, d), "fan_in"),
-        ],
+        "gqa": grouped("gqa"),
+        "swa": grouped("swa"),
         "dense": [
             ("dense_wg", (d, cfg.intermediate_size), "fan_in"),
             ("dense_wu", (d, cfg.intermediate_size), "fan_in"),
@@ -336,7 +361,9 @@ def param_specs(cfg: BackboneConfig) -> List[Tuple[str, Tuple[int, ...], str]]:
     for kind in KINDS:
         n = len(cfg.layers_of(kind))
         if n:
-            specs += [(name, (n,) + shape, init) for name, shape, init in by_kind[kind]]
+            # the norm of the part's output is the part's own: last in its stack
+            post = [(kind + "_post_norm", (d,), "ones")] if cfg.post_norms else []
+            specs += [(name, (n,) + shape, init) for name, shape, init in by_kind[kind] + post]
     specs += [
         ("out_norm", (d,), "ones"),
         ("out_proj", (d, cfg.n_features_out), "fan_in"),
@@ -622,40 +649,80 @@ _GQA_ATTENTION = telemetry.counter(
     "gordo_gqa_attention_total",
     "Causal cores of grouped-query attention traced, by the rule that gives "
     "them: causal_blocks (query blocks against their key prefixes, "
-    "_grouped_core), whole (one block: the whole square, masked)",
+    "_grouped_core), window_blocks (query blocks against the keys their "
+    "window reaches: the leading ones against their prefixes, the others "
+    "in one loop), whole (one block: the whole square, masked)",
     labels=("rule",),
 )
+#: unrolled query blocks a core has at most: every one is a shape of its own
+#: wherever the program traces the core, so a sequence longer than
+#: ``ATTN_MAX_BLOCKS * MLA_BLOCK`` rows takes blocks of ``t / ATTN_MAX_BLOCKS``
+#: rows.  At 8,192 rows 16 blocks of 512 for 8 of 1,024 cost a build compiled
+#: anew 14 s of set-up and 5.8 MB of executable for a program 0.2 % shorter
+#: and 0.7 GB less memory (one machine, one seed: PERF.md section 6, PR 42).
+#: A windowed core's blocks stay ``MLA_BLOCK`` rows: all but its leading ones
+#: are ONE shape and run as a loop (:func:`_grouped_core`)
+ATTN_MAX_BLOCKS = 8
+#: a core whose blocks' float32 scores would together take more than this is
+#: recomputed block by block in the backward pass (``jax.checkpoint`` around
+#: each block) and keeps one block's scores at a time: 4.8 GB for a 32-head
+#: core over 8,192 rows, which a chip that holds the model has no room for;
+#: the cores over 2,048 rows and fewer take 0.7 GB at most and keep them
+ATTN_KEEP_BYTES = 1 << 30
 
 
-def _query_blocks(t: int, counter, prefix: str) -> List[Tuple[int, int]]:
+def _query_blocks(t: int, counter, prefix: str, window: int = 0) -> List[Tuple[int, int]]:
     """The block rule of every causal core, latent or grouped: the ``(lo,
     hi)`` rows of each query block; block ``i`` attends to the keys before
-    ``hi``.  ``t // MLA_BLOCK`` blocks; one, the whole square, where ``t`` is
-    no longer than a block or no multiple of one.  Counts the core on
-    ``counter`` and on the enclosing span (``<prefix>_attn_*``): runs where
-    the core is traced."""
-    n = t // MLA_BLOCK if t % MLA_BLOCK == 0 else 1
-    counter.inc(1.0, "causal_blocks" if n > 1 else "whole")
+    ``hi`` and, under a ``window``, no further back than the whole blocks
+    the window reaches (``ceil(window / rows)`` of them before its own).
+    Blocks of ``MLA_BLOCK`` rows (of ``t / ATTN_MAX_BLOCKS`` where that is
+    more and there is no window); one, the whole square, where ``t`` is no
+    longer than a block or no multiple of one.  Counts the core on
+    ``counter`` and on the enclosing span (``<prefix>_attn_*``: block pairs
+    computed and of the square; under a window also ``_pairs_in_window``, the
+    pairs inside window and causal mask in blocks' worth: the least any rule
+    could compute): runs where the core is traced."""
+    rows = MLA_BLOCK if window else max(MLA_BLOCK, t // ATTN_MAX_BLOCKS)
+    n = t // rows if t % rows == 0 else 1
+    rows, w = t // n, min(window, t)
+    reach = -(-window // rows) if window else n         # blocks before its own
+    counter.inc(1.0, "whole" if n == 1 else "window_blocks" if window else "causal_blocks")
     telemetry.add_to_span(**{
         f"{prefix}_attn_traces": 1, f"{prefix}_attn_blocks": n,
-        f"{prefix}_attn_pairs_computed": n * (n + 1) // 2,
-        f"{prefix}_attn_pairs_square": n * n})
-    return [(i * (t // n), (i + 1) * (t // n)) for i in range(n)]
+        f"{prefix}_attn_pairs_computed": sum(min(i, reach) + 1 for i in range(n)),
+        f"{prefix}_attn_pairs_square": n * n,
+        **({f"{prefix}_attn_pairs_in_window": (t * w - w * (w - 1) / 2) / rows ** 2}
+           if window else {})})
+    return [(i * rows, (i + 1) * rows) for i in range(n)]
 
 
-def _attend(spans, scores, scale: float, values):
-    """The causal softmax of every query block and its product with the
-    values: ``scores`` yields block ``(lo, hi)``'s unscaled scores ``(...,
-    hi - lo, hi)``, of which only the last ``hi - lo`` columns hold masked
-    pairs; ``values(probs, hi)`` multiplies a block's weights with the first
-    ``hi`` values and returns ``(b, hi - lo, ...)``.  A row's softmax is over
-    exactly the entries it has in the whole square (the masked ones weigh
-    ``exp(-inf) = 0`` there), so the blocks are the square's arithmetic."""
-    blocks = []
-    for (lo, hi), block in zip(spans, scores):
-        causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
-        block = jnp.where(causal, block * scale, -jnp.inf)
-        blocks.append(values(jax.nn.softmax(block, axis=-1), hi))
+def _attend(spans, scores, scale: float, values, window: int = 0, remat: bool = False):
+    """The causal softmax of every query block against its key prefix and
+    its product with the values, a list of ``(b, hi - lo, ...)``:
+    ``scores(lo, hi)`` gives block ``(lo, hi)``'s unscaled scores ``(..., hi -
+    lo, hi)``, of which only the last ``hi - lo`` columns hold masked pairs
+    (and, under a ``window``, the columns ``window`` or more rows back);
+    ``values(probs, hi)`` multiplies a block's weights with the first ``hi``
+    values.  A row's softmax is over exactly the entries it has in the whole
+    square (the masked ones weigh ``exp(-inf) = 0`` there), so the blocks are
+    the square's arithmetic.  With ``remat`` a block keeps nothing for the
+    backward pass but what it read."""
+    def one(lo, hi):
+        block = scores(lo, hi)
+        row, key = jnp.arange(lo, hi)[:, None], jnp.arange(hi)[None, :]
+        seen = row >= key
+        if window:
+            seen &= row - key < window
+        block = jnp.where(seen, block * scale, -jnp.inf)
+        return values(jax.nn.softmax(block, axis=-1), hi)
+
+    if remat:
+        one = jax.checkpoint(one, static_argnums=(0, 1))
+    return [one(lo, hi) for lo, hi in spans]
+
+
+def _join(blocks):
     return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
 
 
@@ -684,38 +751,72 @@ def _causal_core(cfg: BackboneConfig, q, k_n, k_r, v):
         cos, sin = rotary(q.shape[1], dr, cfg.rope_theta)
         q_r = rotate(q_r, cos[:, None, :], sin[:, None, :])
         k_r = rotate(k_r, cos, sin)
-    scores = (
-        first + jnp.einsum("bthc,bsc->bhts", q_r[:, lo:hi].astype(cd), k_r[:, :hi].astype(cd),
-                           preferred_element_type=F32)
-        for (lo, hi), first in zip(spans, own))
-    return _attend(
-        spans, scores, (dn + dr) ** -0.5,
+    first = dict(zip(spans, own))
+    return _join(_attend(
+        spans,
+        lambda lo, hi: first[lo, hi] + jnp.einsum(
+            "bthc,bsc->bhts", q_r[:, lo:hi].astype(cd), k_r[:, :hi].astype(cd),
+            preferred_element_type=F32),
+        (dn + dr) ** -0.5,
         lambda probs, hi: jnp.einsum("bhts,bshv->bthv", probs.astype(cd), v[:, :hi].astype(cd),
-                                     preferred_element_type=F32))
+                                     preferred_element_type=F32)))
 
 
-def _grouped_core(cfg: BackboneConfig, q, k, v):
+def _grouped_core(cfg: BackboneConfig, q, k, v, window: int = 0, prefix: str = "gqa"):
     """Causal softmax attention ``(b, t, heads, hd)``, float32, for grouped
     keys and values: queries ``q`` (b, t, heads, hd), ``k`` and ``v`` (b, t,
     kv, hd), all rotated and normalised already; query head ``i`` reads
-    key/value head ``i // (heads / kv)``; scores ``q k / sqrt(hd)``.  The
+    key/value head ``i // (heads / kv)``; scores ``q k / sqrt(hd)``; under a
+    ``window`` a row sees itself and the ``window - 1`` rows before it.  The
     block rule is :func:`_causal_core`'s (:func:`_query_blocks`,
     :func:`_attend`).  A key/value head is contracted against its group of
-    query heads in one product: keys and values are never repeated."""
+    query heads in one product: keys and values are never repeated.
+
+    Under a window, a block whose prefix is longer than the window's reach
+    (``ceil(window / rows)`` whole blocks) reads the keys of that many blocks
+    before its own and no others: those blocks are one shape and one mask,
+    and run as ONE loop (a ``dynamic_slice`` of keys and values a trip, each
+    trip recomputed in the backward pass); only the leading blocks, whose
+    prefix is shorter, are unrolled against it.  No block pair wholly
+    outside the window is multiplied, stored or differentiated.
+
+    The unrolled blocks keep their scores for the backward pass where those
+    fit ``ATTN_KEEP_BYTES`` together, and are recomputed one by one where
+    they do not."""
     cd = cfg.compute_dtype
     b, t, h, hd = q.shape
     kv = k.shape[2]
-    spans = _query_blocks(t, _GQA_ATTENTION, "gqa")
-    q = q.reshape(b, t, kv, h // kv, hd)
-    scores = (
-        jnp.einsum("btkgc,bskc->bkgts", q[:, lo:hi].astype(cd), k[:, :hi].astype(cd),
-                   preferred_element_type=F32)
-        for lo, hi in spans)
-    o = _attend(
-        spans, scores, hd ** -0.5,
-        lambda probs, hi: jnp.einsum("bkgts,bskv->btkgv", probs.astype(cd), v[:, :hi].astype(cd),
-                                     preferred_element_type=F32))
-    return o.reshape(b, t, h, hd)
+    spans = _query_blocks(t, _GQA_ATTENTION, prefix, window)
+    rows = spans[0][1] - spans[0][0]
+    reach = -(-window // rows) * rows if window else t
+    lead = [(lo, hi) for lo, hi in spans if lo < reach]
+    q, scale = q.reshape(b, t, kv, h // kv, hd), hd ** -0.5
+    score = lambda qs, ks: jnp.einsum(  # noqa: E731
+        "btkgc,bskc->bkgts", qs, ks, preferred_element_type=F32)
+    weigh = lambda probs, vs: jnp.einsum(  # noqa: E731
+        "bkgts,bskv->btkgv", probs, vs, preferred_element_type=F32)
+    blocks = _attend(
+        lead, lambda lo, hi: score(q[:, lo:hi].astype(cd), k[:, :hi].astype(cd)), scale,
+        lambda probs, hi: weigh(probs.astype(cd), v[:, :hi].astype(cd)), window,
+        remat=4 * b * h * sum((hi - lo) * hi for lo, hi in lead) > ATTN_KEEP_BYTES)
+    if len(lead) < len(spans):
+        # the one mask of every other block: row i of the block against
+        # column j of its keys, which start ``reach`` rows before it
+        apart = reach + jnp.arange(rows)[:, None] - jnp.arange(reach + rows)[None, :]
+        seen = (apart >= 0) & (apart < window)
+
+        @jax.checkpoint
+        def trip(lo):
+            cut = lambda a, at, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                a, at, n, axis=1).astype(cd)
+            block = score(cut(q, lo, rows), cut(k, lo - reach, reach + rows))
+            block = jnp.where(seen, block * scale, -jnp.inf)
+            return weigh(jax.nn.softmax(block, axis=-1).astype(cd),
+                         cut(v, lo - reach, reach + rows))
+
+        rest = jax.lax.map(trip, jnp.arange(spans[len(lead)][0], t, rows))
+        blocks.append(jnp.moveaxis(rest, 0, 1).reshape((b, -1) + rest.shape[3:]))
+    return _join(blocks).reshape(b, t, h, hd)
 
 
 def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
@@ -743,23 +844,36 @@ def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
     return _mm(o.reshape(b, t, h * dv), p["mla_wo"], cd)
 
 
-def gqa_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
+def gqa_mixer(cfg: BackboneConfig, p: Dict[str, Any], x, kind: str = "gqa"):
     """Grouped-query attention: ``num_heads`` query heads over
     ``num_kv_heads`` key/value heads; queries and keys each through an
     RMSNorm over a head's channels (one weight vector for all query heads,
     one for all key heads), then rotary positions on all of a head's
-    channels; a causal softmax (:func:`_grouped_core`)."""
+    channels; a causal softmax (:func:`_grouped_core`).  ``kind`` says which
+    of the two layers this is: ``"gqa"``, every row against its whole prefix
+    (rotated unless ``gqa_rotary`` is false), or ``"swa"``, against the last
+    ``attn_window`` rows (always rotated); their parameters are ``gqa_*`` and
+    ``swa_*``.  With ``attn_gate`` the heads' outputs are multiplied by
+    ``sigmoid(x W_z)`` before the output projection."""
     cd, h, kv, hd = cfg.compute_dtype, cfg.num_heads, cfg.num_kv_heads, cfg.gqa_head_dim
     b, t, _ = x.shape
-    q = _mm(x, p["gqa_wq"], cd).reshape(b, t, h, hd)
-    k = _mm(x, p["gqa_wk"], cd).reshape(b, t, kv, hd)
-    v = _mm(x, p["gqa_wv"], cd).reshape(b, t, kv, hd)
-    with jax.named_scope("backbone.gqa.attn"):
-        cos, sin = rotary(t, hd, cfg.rope_theta)
-        q, k = (rotate(rms_norm(a, p[f"gqa_{n}_norm"], cfg.rms_norm_eps),
-                       cos[:, None, :], sin[:, None, :]) for a, n in ((q, "q"), (k, "k")))
-        o = _grouped_core(cfg, q, k, v)
-    return _mm(o.reshape(b, t, h * hd), p["gqa_wo"], cd)
+    q = _mm(x, p[kind + "_wq"], cd).reshape(b, t, h, hd)
+    k = _mm(x, p[kind + "_wk"], cd).reshape(b, t, kv, hd)
+    v = _mm(x, p[kind + "_wv"], cd).reshape(b, t, kv, hd)
+    windowed = kind == "swa"
+    with jax.named_scope(f"backbone.{kind}.attn"):
+        if windowed or cfg.gqa_rotary:
+            cos, sin = rotary(t, hd, cfg.rope_theta)
+            turn = lambda a: rotate(a, cos[:, None, :], sin[:, None, :])  # noqa: E731
+        else:
+            turn = lambda a: a  # noqa: E731
+        q, k = (turn(rms_norm(a, p[f"{kind}_{n}_norm"], cfg.rms_norm_eps))
+                for a, n in ((q, "q"), (k, "k")))
+        o = _grouped_core(cfg, q, k, v, cfg.attn_window if windowed else 0, kind)
+    o = o.reshape(b, t, h * hd)
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(_mm(x, p[kind + "_wz"], cd))
+    return _mm(o, p[kind + "_wo"], cd)
 
 
 def conv_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
@@ -1032,13 +1146,23 @@ def _slice(stack: Dict[str, Any], slot) -> Dict[str, Any]:
             for name, a in stack.items()}
 
 
-MIXERS = {"kda": kda_mixer, "mla": mla_mixer, "conv": conv_mixer, "gqa": gqa_mixer}
+MIXERS = {"kda": kda_mixer, "mla": mla_mixer, "conv": conv_mixer, "gqa": gqa_mixer,
+          "swa": functools.partial(gqa_mixer, kind="swa")}
 _MIXERS_TRACED = telemetry.counter(
     "gordo_backbone_mixers_total",
     "Mixers of the sequence backbone traced (forward, recomputation and "
-    "held-out forecast each trace theirs), by kind: kda, mla, conv, gqa",
+    "held-out forecast each trace theirs), by kind: kda, mla, conv, gqa, swa",
     labels=("kind",),
 )
+
+
+def _post_norm(cfg: BackboneConfig, kind: str, p: Dict[str, Any], y):
+    """A part's output through the part's own norm (``<kind>_post_norm``,
+    the last of its stack) where the model has one (``post_norms``)."""
+    if not cfg.post_norms:
+        return y
+    with jax.named_scope("backbone.norm"):
+        return rms_norm(y, p[kind + "_post_norm"], cfg.rms_norm_eps)
 
 
 def _mixer_of(cfg: BackboneConfig, kind: str, p: Dict[str, Any], norm, h):
@@ -1047,7 +1171,8 @@ def _mixer_of(cfg: BackboneConfig, kind: str, p: Dict[str, Any], norm, h):
         x = rms_norm(h, norm, cfg.rms_norm_eps)
     _MIXERS_TRACED.inc(1.0, kind)  # runs where the mixer is traced
     with jax.named_scope("backbone." + kind):
-        return MIXERS[kind](cfg, p, x)
+        y = MIXERS[kind](cfg, p, x)
+    return _post_norm(cfg, kind, p, y)
 
 
 def _groups(cfg: BackboneConfig, h):
@@ -1155,7 +1280,8 @@ def _dense_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
     with jax.named_scope("backbone.norm"):
         x = rms_norm(h, norm, cfg.rms_norm_eps)
     with jax.named_scope("backbone.ffn"):
-        return swiglu(x, p["dense_wg"], p["dense_wu"], p["dense_wd"], cfg.compute_dtype)
+        y = swiglu(x, p["dense_wg"], p["dense_wu"], p["dense_wd"], cfg.compute_dtype)
+    return _post_norm(cfg, "dense", p, y)
 
 
 def _expert_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
@@ -1165,7 +1291,7 @@ def _expert_ffn(cfg: BackboneConfig, p: Dict[str, Any], norm, h):
     with jax.named_scope("backbone.norm"):
         x = rms_norm(h, norm, cfg.rms_norm_eps)
     y, tokens = expert_layer(cfg, p, x.reshape(b * t, d))
-    return y.reshape(b, t, d), tokens
+    return _post_norm(cfg, "moe", p, y.reshape(b, t, d)), tokens
 
 
 def _head(cfg: BackboneConfig, params: Dict[str, Any], norm, h):
@@ -1348,11 +1474,18 @@ def _backbone(kind: str, n_features, n_features_out, compute_dtype, preset, widt
         raise ValueError(
             f"layer_pattern names one mixer of {MIXER_KINDS} for each of the "
             f"{cfg.num_layers} layers; it is {cfg.pattern}")
-    if "gqa" in cfg.pattern and (
-            cfg.num_heads % cfg.num_kv_heads or cfg.gqa_head_dim % 2 or not cfg.rope_theta):
+    rotated = "swa" in cfg.pattern or ("gqa" in cfg.pattern and cfg.gqa_rotary)
+    if {"gqa", "swa"} & set(cfg.pattern) and (
+            cfg.num_heads % cfg.num_kv_heads or cfg.gqa_head_dim % 2
+            or (rotated and not cfg.rope_theta)):
         raise ValueError(
             "grouped-query attention needs num_kv_heads a divisor of num_heads, "
-            "an even hidden_size / num_heads and a rope_theta")
+            "an even head width (head_dim, or hidden_size / num_heads) and, "
+            "where a layer is rotated, a rope_theta")
+    if "swa" in cfg.pattern and cfg.attn_window < 1:
+        raise ValueError("a windowed attention layer needs an attn_window")
+    if cfg.post_norms and cfg.mtp_depth:
+        raise ValueError("the multi-token-prediction module has no output norms")
     return SequenceBackbone(cfg)
 
 
@@ -1461,3 +1594,51 @@ def lfm2_moe(
                    for kind in LFM2_LAYER_TYPES[1:1 + n])
     return _backbone("lfm2_moe", n_features, n_features_out, compute_dtype,
                      {**LFM2_MOE, "layer_pattern": source}, widths)
+
+
+#: Trinity-Mini's config.json (``model_type`` ``afmoe``) as
+#: :class:`BackboneConfig` keywords.  The cut starts at the source's layer 1
+#: (its two leading dense layers, both windowed, are counted once), so
+#: ``layer_pattern`` is the source's ``layer_types`` from there on: attention
+#: over the whole prefix at the source's layers 3, 7, ..., over a window of
+#: 2,048 rows at the others
+AFMOE = dict(
+    hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+    attn_window=2048, gqa_rotary=False, attn_gate=True, post_norms=True,
+    rope_theta=1e4, intermediate_size=6144, first_k_dense_replace=1,
+    moe_intermediate_size=1024, num_experts=128, num_experts_per_token=8,
+    num_shared_experts=1, routed_scaling_factor=2.826, experts_held=8,
+    rms_norm_eps=1e-5, mixer_group=1,
+)
+AFMOE_LAYER_TYPES = ("sliding_attention",) * 3 + ("full_attention",)
+
+
+@register_model_builder(type="SequenceForecast")
+def afmoe(
+    n_features: int,
+    n_features_out: int = None,
+    compute_dtype: str = "auto",
+    context: int = None,
+    stride: int = None,
+    seed: int = 0,
+    **widths,
+) -> nn.Module:
+    """Trinity-Mini's block at its published widths (``model_type``
+    ``afmoe``): grouped-query attention (32 query heads over 4 key/value
+    heads of 128, normalised per head) over a window of 2,048 rows with
+    rotary positions in three layers of four and over the whole prefix with
+    no positions in the fourth; the heads' outputs gated by ``sigmoid(x
+    W_z)``; every mixer's and feed-forward's output normalised before it
+    joins the stream; the leading layer's feed-forward dense, the others' a
+    128-way sigmoid-routed expert layer (8 a token, renormalised, scaled)
+    with one shared expert.  ``num_layers`` layers from the source's layer 1
+    on (``layer_pattern`` follows the source's ``layer_types`` unless
+    given), ``experts_held`` routed experts from ``experts_held_from``.
+    Every width is a keyword of :class:`BackboneConfig`; tests pass a tiny
+    preset."""
+    del context, stride, seed
+    n = int(widths.get("num_layers", BackboneConfig.num_layers))
+    source = tuple("gqa" if AFMOE_LAYER_TYPES[l % 4] == "full_attention" else "swa"
+                   for l in range(1, 1 + n))
+    return _backbone("afmoe", n_features, n_features_out, compute_dtype,
+                     {**AFMOE, "layer_pattern": source}, widths)

@@ -1525,6 +1525,9 @@ def _exact_fleet_program(
                 # the layer pattern: how many layers have each kind of mixer
                 **{f"layers_{kind}": len(module.cfg.layers_of(kind))
                    for kind in set(getattr(module.cfg, "pattern", ()))},
+                # the rows a windowed attention layer's query sees
+                **({"attn_window": module.cfg.attn_window}
+                   if getattr(module.cfg, "attn_window", 0) else {}),
             )
         if sequence:
             params0 = None  # drawn where each fit begins, see _sequence_fits

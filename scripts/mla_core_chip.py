@@ -4,7 +4,7 @@ cell's shape, for each candidate of ``backbone.MLA_BLOCK``: what chose the
 constant (PERF.md section 3).
 
     python scripts/mla_core_chip.py [--workload glm-flash.build-horizons] \\
-        [--blocks 256,512,1024,0] [--repeats 5]
+        [--blocks 256,512,1024,0] [--max-blocks 4,8,16] [--repeats 5]
 
 For every block size (0: one block, the whole square) it sets the constant,
 compiles the core of the cell's attention layers (``backbone._causal_core``
@@ -16,7 +16,12 @@ with its ``jax.vjp`` for all its inputs, runs each ``--repeats`` times after a
 warm-up and prints the best wall milliseconds: ``forward_ms``,
 ``forward_backward_ms`` and ``layer_ms``, their sum, which is what one
 attention block of an optimiser step costs (``_mixer_bwd`` recomputes the
-forward).  Chip only (exit 3 without one); leaves the compile cache alone.
+forward).  A preset with windowed layers (``afmoe``) has two cores, timed one
+after the other: the windowed one (``core`` ``swa``) for every ``--blocks``
+(a block of its loop), and the whole-prefix one (``gqa``) for every
+``--max-blocks``, the candidates of ``backbone.ATTN_MAX_BLOCKS`` that cut a
+sequence longer than ``MLA_BLOCK`` times it (8,192 rows: blocks of 2,048,
+1,024, 512).  Chip only (exit 3 without one); leaves the compile cache alone.
 """
 
 from __future__ import annotations
@@ -45,19 +50,24 @@ def config_of(model: dict):
 
 
 def core_shapes(model: dict):
-    """``(cfg, core, shapes)``: the backbone's configuration
-    (:func:`config_of`), the causal core of its attention layers and the
-    shapes of the core's inputs for one mixer call of ``model``: ``(q, k_n,
+    """``(cfg, cores, shapes)``: the backbone's configuration
+    (:func:`config_of`), the causal cores of its attention layers by kind
+    (``mla``; ``gqa``; ``swa`` and ``gqa`` where the pattern has both) and
+    the shapes of a core's inputs for one mixer call of ``model``: ``(q, k_n,
     k_r, v)`` for latent attention, ``(q, k, v)`` for grouped."""
     from gordo_tpu.models.factories import backbone
 
     cfg = config_of(model)
     b, t, h = cfg.mixer_group, int(model["context"]), cfg.num_heads
-    if "gqa" in cfg.pattern:
+    grouped = [kind for kind in ("swa", "gqa") if kind in cfg.pattern]
+    if grouped:
         hd, kv = cfg.gqa_head_dim, cfg.num_kv_heads
-        return cfg, backbone._grouped_core, ((b, t, h, hd), (b, t, kv, hd), (b, t, kv, hd))
+        cores = {kind: functools.partial(
+            backbone._grouped_core, window=cfg.attn_window if kind == "swa" else 0, prefix=kind)
+            for kind in grouped}
+        return cfg, cores, ((b, t, h, hd), (b, t, kv, hd), (b, t, kv, hd))
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    return cfg, backbone._causal_core, (
+    return cfg, {"mla": backbone._causal_core}, (
         (b, t, h, dn + dr), (b, t, h, dn), (b, t, dr), (b, t, h, cfg.v_head_dim))
 
 
@@ -89,6 +99,9 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="mla_core_chip")
     parser.add_argument("--workload", default="glm-flash.build-horizons")
     parser.add_argument("--blocks", default="256,512,1024,0")
+    parser.add_argument("--max-blocks", default="4,8,16",
+                        help="candidates of ATTN_MAX_BLOCKS, for a whole-prefix core "
+                             "beside a windowed one")
     parser.add_argument("--repeats", type=int, default=5)
     args = parser.parse_args(argv)
     import jax
@@ -105,15 +118,22 @@ def main(argv=None) -> int:
 
     manifest = manifest_mod.Manifest()
     model = manifest.config(manifest.cell(args.workload)["config"])["model"]
-    cfg, core, shapes = core_shapes(model)
-    core = functools.partial(core, cfg)
-    for block in (int(b) for b in args.blocks.split(",")):
-        backbone.MLA_BLOCK = block or shapes[0][1]
-        line = {"workload": args.workload, "block": block,
-                "device_kind": jax.devices()[0].device_kind,
-                "shape": [list(s) for s in shapes]}
-        line.update(time_core(core, shapes, args.repeats))
-        print(json.dumps(line), flush=True)
+    cfg, cores, shapes = core_shapes(model)
+    block_rows, max_blocks = backbone.MLA_BLOCK, backbone.ATTN_MAX_BLOCKS
+    for kind, core in cores.items():
+        # beside a windowed core the whole-prefix one is cut by the number of
+        # its blocks, at the block the module has
+        by_count = kind == "gqa" and "swa" in cores
+        for candidate in (int(c) for c in (
+                args.max_blocks if by_count else args.blocks).split(",")):
+            backbone.MLA_BLOCK = block_rows if by_count else candidate or shapes[0][1]
+            backbone.ATTN_MAX_BLOCKS = candidate if by_count else max_blocks
+            line = {"workload": args.workload, "core": kind,
+                    "max_blocks" if by_count else "block": candidate,
+                    "device_kind": jax.devices()[0].device_kind,
+                    "shape": [list(s) for s in shapes]}
+            line.update(time_core(functools.partial(core, cfg), shapes, args.repeats))
+            print(json.dumps(line), flush=True)
     return 0
 
 

@@ -19,7 +19,10 @@ under ``unscoped``; and under that path's last component, the primitive
 ``benchmark/readers/trace_named_seconds.py`` and its two siblings read from
 the same file under the specs of the cell's new metrics (the readings of
 ``lfm2-moe.build-fortnight``, whose cell lists none of them, come from
-here), and writes both to ``chiprun_out/trace_split/<label>.json``.
+here), then every metric that ``benchmark/pending/*.per_layer.json`` holds
+for the cell (the ``trinity.*`` nineteen, which ``BENCHMARK.json`` cannot
+list yet: that file says why), read as ``benchmark.run`` would read them,
+and writes all three to ``chiprun_out/trace_split/<label>.json``.
 ``--sources`` books the unscoped operations by their ``source`` field too
 (file:line of the innermost user frame) and prints the top rows: the tool
 that says which line of the program still has no name.  The benchmark's
@@ -130,6 +133,25 @@ def readings(record):
     return {name: readers.read(spec, record) for name, spec in specs.items()}
 
 
+def pending(record, workload):
+    """What the per-layer metrics read that wait under ``benchmark/pending``
+    for a place in ``BENCHMARK.json``: those that list ``workload``, each by
+    the reader its spec file names; ``None`` where it finds nothing."""
+    import glob
+
+    from benchmark import manifest as manifest_mod, readers
+
+    manifest = manifest_mod.Manifest()
+    found = {}
+    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "pending", "*.per_layer.json"))):
+        with open(path) as fh:
+            entries = json.load(fh)["per_layer"]
+        for metric in entries:
+            if workload in metric["workloads"]:
+                found[metric["name"]] = readers.read(manifest.metric_spec(metric["name"]), record)
+    return found
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--label", required=True)
@@ -151,6 +173,7 @@ def main() -> int:
         if path is not None:
             found = split(path, args.sources)
             found["readings"] = readings(record)
+            found["pending"] = pending(record, args.workload)
             os.makedirs(OUT, exist_ok=True)
             with open(os.path.join(OUT, args.label + ".json"), "w") as fh:
                 json.dump(found, fh, indent=1)
@@ -166,7 +189,7 @@ def main() -> int:
                 print(f"    {row['scope']:12s} {row['primitive']:28s} {row['hlo']:36s} "
                       f"{row['s']:.4f} s {100 * row['share']:5.2f} % x{row['events']} "
                       f"{row['source']}")
-            for name, value in found["readings"].items():
+            for name, value in {**found["readings"], **found["pending"]}.items():
                 print(f"  reading {name} = {value!r}")
             sys.stdout.flush()
         cleanup(record)

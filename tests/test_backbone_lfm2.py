@@ -222,10 +222,12 @@ def mixer_inputs(kind_, dtype, t):
     return cfg, p, jax.random.normal(next(keys), (2, t, 64))
 
 
-def whole_square_core(cfg, q, k, v):
+def whole_square_core(cfg, q, k, v, window=0, prefix="gqa"):
     """The grouped core's reference: keys and values repeated for every query
     head of their group, every pair of the ``t x t`` square multiplied, the
-    upper triangle masked, one softmax over whole rows."""
+    upper triangle masked, one softmax over whole rows (this preset has no
+    window: ``tests/test_backbone_afmoe.py`` has the windowed one)."""
+    assert not window
     cd = cfg.compute_dtype
     t, group = q.shape[1], q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
