@@ -69,11 +69,13 @@ expert layer.
   (:func:`_query_blocks`, :func:`_attend`): query blocks of ``MLA_BLOCK``
   rows, each against the prefix of keys it may see; one block, the whole
   masked square, where the sequence is no longer than a block or no multiple.
-  Under a window a block reads the keys of the whole blocks its window
-  reaches and no others: the blocks past that reach are one shape and run as
-  one loop.  A whole-prefix core has ``ATTN_MAX_BLOCKS`` blocks at most, and
-  one whose scores would not fit ``ATTN_KEEP_BYTES`` recomputes each block in
-  the backward pass.
+  Under a window (shorter than the sequence) a block reads the keys of the
+  whole blocks its window reaches and no others: every block is a trip of
+  ONE loop, its rows chosen from the window, the sequence, the batch and the
+  heads so that a trip's scores fit ``WINDOW_TRIP_BYTES``
+  (:func:`_window_rows`).  A whole-prefix core has ``ATTN_MAX_BLOCKS`` blocks
+  at most, and one whose scores would not fit ``ATTN_KEEP_BYTES`` recomputes
+  each block in the backward pass.
 - **Expert layer** (:func:`expert_layer`): a sigmoid router over ALL the
   model's experts, the ``num_experts_per_token`` largest kept and
   renormalised (over their sum + ``route_eps``); the module is told which
@@ -650,8 +652,8 @@ _GQA_ATTENTION = telemetry.counter(
     "Causal cores of grouped-query attention traced, by the rule that gives "
     "them: causal_blocks (query blocks against their key prefixes, "
     "_grouped_core), window_blocks (query blocks against the keys their "
-    "window reaches: the leading ones against their prefixes, the others "
-    "in one loop), whole (one block: the whole square, masked)",
+    "window reaches, every one a trip of one loop), whole (one block: the "
+    "whole square, masked)",
     labels=("rule",),
 )
 #: unrolled query blocks a core has at most: every one is a shape of its own
@@ -660,49 +662,93 @@ _GQA_ATTENTION = telemetry.counter(
 #: rows.  At 8,192 rows 16 blocks of 512 for 8 of 1,024 cost a build compiled
 #: anew 14 s of set-up and 5.8 MB of executable for a program 0.2 % shorter
 #: and 0.7 GB less memory (one machine, one seed: PERF.md section 6, PR 42).
-#: A windowed core's blocks stay ``MLA_BLOCK`` rows: all but its leading ones
-#: are ONE shape and run as a loop (:func:`_grouped_core`)
+#: A windowed core unrolls none: its blocks are the trips of one loop, and
+#: their rows are :func:`_window_rows`'
 ATTN_MAX_BLOCKS = 8
-#: a core whose blocks' float32 scores would together take more than this is
-#: recomputed block by block in the backward pass (``jax.checkpoint`` around
-#: each block) and keeps one block's scores at a time: 4.8 GB for a 32-head
-#: core over 8,192 rows, which a chip that holds the model has no room for;
-#: the cores over 2,048 rows and fewer take 0.7 GB at most and keep them
+#: a core whose unrolled blocks' float32 scores would together take more than
+#: this is recomputed block by block in the backward pass (``jax.checkpoint``
+#: around each block) and keeps one block's scores at a time: 4.8 GB for a
+#: 32-head core over 8,192 rows, which a chip that holds the model has no
+#: room for; the cores over 2,048 rows and fewer take 0.7 GB at most and keep
+#: them.  A windowed core keeps none: every trip of its loop is recomputed
 ATTN_KEEP_BYTES = 1 << 30
+#: query rows a trip of a windowed core may take, the largest first: a trip
+#: is ONE traced body whatever its rows, so the rows cost no shape and are
+#: priced by a trip's working set alone (:func:`_window_rows`)
+WINDOW_ROWS = (512, 256, 128, 64)
+#: the float32 scores ``(b, heads, rows, window + rows)`` of one trip of a
+#: windowed core (:func:`trip_score_bytes`) stay under this: the array crosses
+#: HBM as scores, masked scores, exponentials and weights in the forward, the
+#: recomputation and the backward.  Chosen on the chip at the Trinity cell's
+#: shape (1 x 8,192 rows x 32 / 4 heads of 128, window 2,048; PERF.md section
+#: 6, PR 44).  The core alone, ms a layer and step
+#: (scripts/mla_core_chip.py): trips of 512 rows (160 MiB of scores) 52.4,
+#: 256 (72 MiB) 20.2, 128 (34 MiB) 18.2, 64 (16.5 MiB) 19.5: a row costs 2.6
+#: times as much at 160 MiB as at 72, and under 72 a trip's fixed cost (the
+#: slices, the ``dk`` / ``dv`` sums' update) takes back what fewer surplus
+#: columns save.  The whole fleet program, s a machine: 2.868 at 256, 2.908 at
+#: 128, 2.994 at 64 (3.878 at 512 by PR 43's builder): 128 rows win alone and
+#: lose in the program, so the budget lies between the 72 MiB that win there
+#: and the 160 that lose; more heads or sequences a step take fewer rows
+WINDOW_TRIP_BYTES = 96 << 20
 
 
-def _query_blocks(t: int, counter, prefix: str, window: int = 0) -> List[Tuple[int, int]]:
+def trip_score_bytes(window: int, rows: int, lanes: int) -> int:
+    """The float32 scores of one trip of a windowed core: ``rows`` query rows
+    against the window's keys and their own, in ``lanes`` = batch x query
+    heads."""
+    return 4 * lanes * rows * (window + rows)
+
+
+def _window_rows(t: int, window: int, lanes: int) -> int:
+    """Query rows a trip of a windowed core takes, for ``lanes`` = batch x
+    query heads: the largest of ``WINDOW_ROWS`` that divides window and
+    sequence and whose trip's float32 scores fit ``WINDOW_TRIP_BYTES``; the
+    smallest that divides them where none fits; ``MLA_BLOCK`` where none
+    divides them (all ``t`` rows where that does not divide ``t`` either)."""
+    dividing = [r for r in WINDOW_ROWS if window % r == 0 and t % r == 0]
+    if not dividing:
+        return MLA_BLOCK if t % MLA_BLOCK == 0 else t
+    return next((r for r in dividing
+                 if trip_score_bytes(window, r, lanes) <= WINDOW_TRIP_BYTES), dividing[-1])
+
+
+def _query_blocks(t: int, counter, prefix: str, window: int = 0,
+                  lanes: int = 0) -> List[Tuple[int, int]]:
     """The block rule of every causal core, latent or grouped: the ``(lo,
-    hi)`` rows of each query block; block ``i`` attends to the keys before
-    ``hi`` and, under a ``window``, no further back than the whole blocks
-    the window reaches (``ceil(window / rows)`` of them before its own).
-    Blocks of ``MLA_BLOCK`` rows (of ``t / ATTN_MAX_BLOCKS`` where that is
-    more and there is no window); one, the whole square, where ``t`` is no
-    longer than a block or no multiple of one.  Counts the core on
-    ``counter`` and on the enclosing span (``<prefix>_attn_*``: block pairs
-    computed and of the square; under a window also ``_pairs_in_window``, the
-    pairs inside window and causal mask in blocks' worth: the least any rule
-    could compute): runs where the core is traced."""
-    rows = MLA_BLOCK if window else max(MLA_BLOCK, t // ATTN_MAX_BLOCKS)
+    hi)`` rows of each query block.  With no window block ``i`` attends to
+    the keys before ``hi``: blocks of ``MLA_BLOCK`` rows (of ``t /
+    ATTN_MAX_BLOCKS`` where that is more); one, the whole square, where ``t``
+    is no longer than a block or no multiple of one.  Under a ``window``
+    (shorter than ``t``) a block attends no further back than the whole
+    blocks the window reaches (``ceil(window / rows)`` of them before its
+    own) and its rows follow the shape (:func:`_window_rows`; ``lanes`` is
+    batch x query heads).  Counts the core on ``counter`` and on the
+    enclosing span (``<prefix>_attn_*``: block pairs computed and of the
+    square; under a window also ``_pairs_in_window``, the pairs inside window
+    and causal mask in blocks' worth, the least any rule could compute, and
+    ``_unrolled``, the blocks outside the loop): runs where the core is
+    traced."""
+    rows = _window_rows(t, window, lanes) if window else max(MLA_BLOCK, t // ATTN_MAX_BLOCKS)
     n = t // rows if t % rows == 0 else 1
     rows, w = t // n, min(window, t)
-    reach = -(-window // rows) if window else n         # blocks before its own
     counter.inc(1.0, "whole" if n == 1 else "window_blocks" if window else "causal_blocks")
     telemetry.add_to_span(**{
         f"{prefix}_attn_traces": 1, f"{prefix}_attn_blocks": n,
-        f"{prefix}_attn_pairs_computed": sum(min(i, reach) + 1 for i in range(n)),
+        # under a window every block reads as many keys as the last one
+        f"{prefix}_attn_pairs_computed": (
+            n * min(-(-window // rows) + 1, n) if window else n * (n + 1) // 2),
         f"{prefix}_attn_pairs_square": n * n,
-        **({f"{prefix}_attn_pairs_in_window": (t * w - w * (w - 1) / 2) / rows ** 2}
-           if window else {})})
+        **({f"{prefix}_attn_pairs_in_window": (t * w - w * (w - 1) / 2) / rows ** 2,
+            f"{prefix}_attn_unrolled": 0} if window else {})})
     return [(i * rows, (i + 1) * rows) for i in range(n)]
 
 
-def _attend(spans, scores, scale: float, values, window: int = 0, remat: bool = False):
+def _attend(spans, scores, scale: float, values, remat: bool = False):
     """The causal softmax of every query block against its key prefix and
     its product with the values, a list of ``(b, hi - lo, ...)``:
     ``scores(lo, hi)`` gives block ``(lo, hi)``'s unscaled scores ``(..., hi -
-    lo, hi)``, of which only the last ``hi - lo`` columns hold masked pairs
-    (and, under a ``window``, the columns ``window`` or more rows back);
+    lo, hi)``, of which only the last ``hi - lo`` columns hold masked pairs;
     ``values(probs, hi)`` multiplies a block's weights with the first ``hi``
     values.  A row's softmax is over exactly the entries it has in the whole
     square (the masked ones weigh ``exp(-inf) = 0`` there), so the blocks are
@@ -710,10 +756,7 @@ def _attend(spans, scores, scale: float, values, window: int = 0, remat: bool = 
     backward pass but what it read."""
     def one(lo, hi):
         block = scores(lo, hi)
-        row, key = jnp.arange(lo, hi)[:, None], jnp.arange(hi)[None, :]
-        seen = row >= key
-        if window:
-            seen &= row - key < window
+        seen = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
         block = jnp.where(seen, block * scale, -jnp.inf)
         return values(jax.nn.softmax(block, axis=-1), hi)
 
@@ -767,56 +810,55 @@ def _grouped_core(cfg: BackboneConfig, q, k, v, window: int = 0, prefix: str = "
     keys and values: queries ``q`` (b, t, heads, hd), ``k`` and ``v`` (b, t,
     kv, hd), all rotated and normalised already; query head ``i`` reads
     key/value head ``i // (heads / kv)``; scores ``q k / sqrt(hd)``; under a
-    ``window`` a row sees itself and the ``window - 1`` rows before it.  The
-    block rule is :func:`_causal_core`'s (:func:`_query_blocks`,
-    :func:`_attend`).  A key/value head is contracted against its group of
-    query heads in one product: keys and values are never repeated.
+    ``window`` a row sees itself and the ``window - 1`` rows before it.  A
+    key/value head is contracted against its group of query heads in one
+    product: keys and values are never repeated.
 
-    Under a window, a block whose prefix is longer than the window's reach
-    (``ceil(window / rows)`` whole blocks) reads the keys of that many blocks
-    before its own and no others: those blocks are one shape and one mask,
-    and run as ONE loop (a ``dynamic_slice`` of keys and values a trip, each
-    trip recomputed in the backward pass); only the leading blocks, whose
-    prefix is shorter, are unrolled against it.  No block pair wholly
-    outside the window is multiplied, stored or differentiated.
+    With no window, or one that covers the sequence, the block rule is
+    :func:`_causal_core`'s (:func:`_query_blocks`, :func:`_attend`): the
+    blocks are unrolled, each against its key prefix, and keep their scores
+    for the backward pass where those fit ``ATTN_KEEP_BYTES`` together
+    (recomputed one by one where they do not).
 
-    The unrolled blocks keep their scores for the backward pass where those
-    fit ``ATTN_KEEP_BYTES`` together, and are recomputed one by one where
-    they do not."""
+    Under a shorter window EVERY block is a trip of ONE loop, its rows chosen
+    from the shape (:func:`_window_rows`): a trip reads the keys and values
+    of the blocks its window reaches and its own, one ``dynamic_slice``
+    ending at its last row, and is recomputed in the backward pass.  The
+    leading trips' slices start at row 0 instead and so hold rows after
+    their own, which the mask by position hides like those a window or more
+    back; a row always sees itself.  No block pair
+    wholly outside the window is multiplied, stored or differentiated."""
     cd = cfg.compute_dtype
     b, t, h, hd = q.shape
     kv = k.shape[2]
-    spans = _query_blocks(t, _GQA_ATTENTION, prefix, window)
-    rows = spans[0][1] - spans[0][0]
-    reach = -(-window // rows) * rows if window else t
-    lead = [(lo, hi) for lo, hi in spans if lo < reach]
     q, scale = q.reshape(b, t, kv, h // kv, hd), hd ** -0.5
     score = lambda qs, ks: jnp.einsum(  # noqa: E731
         "btkgc,bskc->bkgts", qs, ks, preferred_element_type=F32)
     weigh = lambda probs, vs: jnp.einsum(  # noqa: E731
         "bkgts,bskv->btkgv", probs, vs, preferred_element_type=F32)
-    blocks = _attend(
-        lead, lambda lo, hi: score(q[:, lo:hi].astype(cd), k[:, :hi].astype(cd)), scale,
-        lambda probs, hi: weigh(probs.astype(cd), v[:, :hi].astype(cd)), window,
-        remat=4 * b * h * sum((hi - lo) * hi for lo, hi in lead) > ATTN_KEEP_BYTES)
-    if len(lead) < len(spans):
-        # the one mask of every other block: row i of the block against
-        # column j of its keys, which start ``reach`` rows before it
-        apart = reach + jnp.arange(rows)[:, None] - jnp.arange(reach + rows)[None, :]
-        seen = (apart >= 0) & (apart < window)
+    if not 0 < window < t:
+        spans = _query_blocks(t, _GQA_ATTENTION, prefix)
+        return _join(_attend(
+            spans, lambda lo, hi: score(q[:, lo:hi].astype(cd), k[:, :hi].astype(cd)), scale,
+            lambda probs, hi: weigh(probs.astype(cd), v[:, :hi].astype(cd)),
+            remat=4 * b * h * sum((hi - lo) * hi for lo, hi in spans) > ATTN_KEEP_BYTES,
+        )).reshape(b, t, h, hd)
+    spans = _query_blocks(t, _GQA_ATTENTION, prefix, window, b * h)
+    rows = spans[0][1]
+    held = min((-(-window // rows) + 1) * rows, t)      # keys a trip reads
 
-        @jax.checkpoint
-        def trip(lo):
-            cut = lambda a, at, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-                a, at, n, axis=1).astype(cd)
-            block = score(cut(q, lo, rows), cut(k, lo - reach, reach + rows))
-            block = jnp.where(seen, block * scale, -jnp.inf)
-            return weigh(jax.nn.softmax(block, axis=-1).astype(cd),
-                         cut(v, lo - reach, reach + rows))
+    @jax.checkpoint
+    def trip(lo):
+        first = jnp.maximum(lo + rows - held, 0)         # dynamic_slice would wrap a negative one
+        apart = lo - first + jnp.arange(rows)[:, None] - jnp.arange(held)[None, :]
+        cut = lambda a, at, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            a, at, n, axis=1).astype(cd)
+        scores = jnp.where((apart >= 0) & (apart < window),
+                           score(cut(q, lo, rows), cut(k, first, held)) * scale, -jnp.inf)
+        return weigh(jax.nn.softmax(scores, axis=-1).astype(cd), cut(v, first, held))
 
-        rest = jax.lax.map(trip, jnp.arange(spans[len(lead)][0], t, rows))
-        blocks.append(jnp.moveaxis(rest, 0, 1).reshape((b, -1) + rest.shape[3:]))
-    return _join(blocks).reshape(b, t, h, hd)
+    out = jax.lax.map(trip, jnp.arange(0, t, rows))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, hd)
 
 
 def mla_mixer(cfg: BackboneConfig, p: Dict[str, Any], x):
@@ -1437,6 +1479,17 @@ class SequenceBackbone(nn.Module):
 
     def param_count(self) -> int:
         return sum(math.prod(shape) for _, shape, _ in param_specs(self.cfg))
+
+    def window_trips(self, sequences: int, context: int) -> Dict[str, int]:
+        """What a build's tracing span says of the windowed cores' loops for
+        optimiser steps of ``sequences`` sequences of ``context`` rows:
+        ``swa_attn_rows``, the rows a trip takes (:func:`_window_rows`);
+        nothing where no layer has a window shorter than the sequence."""
+        cfg = self.cfg
+        if "swa" not in cfg.pattern or not 0 < cfg.attn_window < context:
+            return {}
+        return {"swa_attn_rows": _window_rows(
+            context, cfg.attn_window, sequences * cfg.num_heads)}
 
     def moe_blocks(self, positions: int) -> Dict[str, int]:
         """What a build's tracing span says of the expert layers' loops for

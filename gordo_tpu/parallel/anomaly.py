@@ -1519,6 +1519,8 @@ def _exact_fleet_program(
                 context=lookback,
                 **module.moe_blocks(
                     min(cfg.batch_size, inputs_full.shape[1]) * lookback),
+                **module.window_trips(
+                    min(cfg.batch_size, inputs_full.shape[1]), lookback),
                 **({"mtp_depth": module.cfg.mtp_depth,
                     "mtp_weight": module.cfg.mtp_weight}
                    if getattr(module.cfg, "mtp_depth", 0) else {}),

@@ -4,7 +4,8 @@ cell's shape, for each candidate of ``backbone.MLA_BLOCK``: what chose the
 constant (PERF.md section 3).
 
     python scripts/mla_core_chip.py [--workload glm-flash.build-horizons] \\
-        [--blocks 256,512,1024,0] [--max-blocks 4,8,16] [--repeats 5]
+        [--blocks 256,512,1024,0] [--max-blocks 4,8,16] [--rows 512,256,128,64] \\
+        [--repeats 5]
 
 For every block size (0: one block, the whole square) it sets the constant,
 compiles the core of the cell's attention layers (``backbone._causal_core``
@@ -17,11 +18,14 @@ warm-up and prints the best wall milliseconds: ``forward_ms``,
 ``forward_backward_ms`` and ``layer_ms``, their sum, which is what one
 attention block of an optimiser step costs (``_mixer_bwd`` recomputes the
 forward).  A preset with windowed layers (``afmoe``) has two cores, timed one
-after the other: the windowed one (``core`` ``swa``) for every ``--blocks``
-(a block of its loop), and the whole-prefix one (``gqa``) for every
-``--max-blocks``, the candidates of ``backbone.ATTN_MAX_BLOCKS`` that cut a
-sequence longer than ``MLA_BLOCK`` times it (8,192 rows: blocks of 2,048,
-1,024, 512).  Chip only (exit 3 without one); leaves the compile cache alone.
+after the other: the windowed one (``core`` ``swa``) for every ``--rows``,
+the candidates of ``backbone.WINDOW_ROWS`` (the rows a trip of its loop
+takes), each with ``trip_score_bytes``, the float32 scores of one trip, which
+``backbone.WINDOW_TRIP_BYTES`` bounds; and the whole-prefix one (``gqa``) for
+every ``--max-blocks``, the candidates of ``backbone.ATTN_MAX_BLOCKS`` that
+cut a sequence longer than ``MLA_BLOCK`` times it (8,192 rows: blocks of
+2,048, 1,024, 512).  Chip only (exit 3 without one); leaves the compile cache
+alone.
 """
 
 from __future__ import annotations
@@ -102,6 +106,8 @@ def main(argv=None) -> int:
     parser.add_argument("--max-blocks", default="4,8,16",
                         help="candidates of ATTN_MAX_BLOCKS, for a whole-prefix core "
                              "beside a windowed one")
+    parser.add_argument("--rows", default="512,256,128,64",
+                        help="candidates of WINDOW_ROWS, for a windowed core")
     parser.add_argument("--repeats", type=int, default=5)
     args = parser.parse_args(argv)
     import jax
@@ -122,16 +128,23 @@ def main(argv=None) -> int:
     block_rows, max_blocks = backbone.MLA_BLOCK, backbone.ATTN_MAX_BLOCKS
     for kind, core in cores.items():
         # beside a windowed core the whole-prefix one is cut by the number of
-        # its blocks, at the block the module has
+        # its blocks, at the block the module has; the windowed one by the
+        # rows of a trip, the one candidate the rule is left with
         by_count = kind == "gqa" and "swa" in cores
-        for candidate in (int(c) for c in (
-                args.max_blocks if by_count else args.blocks).split(",")):
-            backbone.MLA_BLOCK = block_rows if by_count else candidate or shapes[0][1]
-            backbone.ATTN_MAX_BLOCKS = candidate if by_count else max_blocks
-            line = {"workload": args.workload, "core": kind,
-                    "max_blocks" if by_count else "block": candidate,
+        what, given = (("rows", args.rows) if kind == "swa" else
+                       ("max_blocks", args.max_blocks) if by_count else ("block", args.blocks))
+        for candidate in (int(c) for c in given.split(",")):
+            line = {"workload": args.workload, "core": kind, what: candidate,
                     "device_kind": jax.devices()[0].device_kind,
                     "shape": [list(s) for s in shapes]}
+            if kind == "swa":
+                backbone.WINDOW_ROWS = (candidate,)
+                b, _, h, _ = shapes[0]
+                line["trip_score_bytes"] = backbone.trip_score_bytes(
+                    cfg.attn_window, candidate, b * h)
+            else:
+                backbone.MLA_BLOCK = block_rows if by_count else candidate or shapes[0][1]
+                backbone.ATTN_MAX_BLOCKS = candidate if by_count else max_blocks
             line.update(time_core(functools.partial(core, cfg), shapes, args.repeats))
             print(json.dumps(line), flush=True)
     return 0

@@ -607,6 +607,7 @@ def test_the_attention_core_is_counted_as_one_block(built):
     assert backbone.MLA_BLOCK > 32
     assert counts["mla_attn_traces"] == counts["mla_attn_blocks"] == traced["whole"]
     assert counts["mla_attn_pairs_computed"] == counts["mla_attn_pairs_square"] == traced["whole"]
+    assert not [name for name in counts if name.startswith("swa_attn")]   # no windowed core
     (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
     assert "gordo_mla_attention_total" in json.dumps(snapshot)
 

@@ -25,7 +25,7 @@ from gordo_tpu.models.estimator import SequenceForecast  # noqa: E402
 from gordo_tpu.models.factories import backbone  # noqa: E402
 from gordo_tpu.train.fit import make_loss_fn, training_pass  # noqa: E402
 
-WINDOW = 12     # no multiple of the tests' block: its reach is two whole blocks
+WINDOW = 12     # no multiple of the tests' block: a trip reaches two whole blocks back
 TINY = dict(hidden_size=64, num_heads=8, num_kv_heads=2, head_dim=16, attn_window=WINDOW,
             intermediate_size=128, moe_intermediate_size=32, num_experts=8,
             num_experts_per_token=2, experts_held=2, experts_held_from=0,
@@ -256,29 +256,47 @@ def mixer_and_gradients(cfg, kind_, p, h):
     return out, {**dp, "input": dh}
 
 
-@pytest.mark.parametrize("kind_,t,window,keep", [
-    ("swa", 4 * BLOCK, 12, True),       # W no multiple of the block: two leading blocks, two trips
-    ("swa", 4 * BLOCK, 2 * BLOCK, True),    # W a multiple of it
-    ("swa", 4 * BLOCK, 3, True),        # a window inside one block: one leading block, three trips
-    ("swa", 2 * BLOCK, 2 * BLOCK, True),    # T <= W: no block lies outside, no loop
-    ("swa", 4 * BLOCK, 5 * BLOCK, True),
-    ("swa", 4 * BLOCK + 4, 12, True),   # T no multiple of the block: the whole square, masked
-    ("swa", 4 * BLOCK, 12, False),      # the leading blocks recomputed in the backward pass
-    ("gqa", 4 * BLOCK, 12, False),      # and the whole-prefix core's
-    ("gqa", 4 * BLOCK, 12, True),
-    ("gqa", 16 * BLOCK, 12, True),      # past ATTN_MAX_BLOCKS blocks: eight of two blocks' rows
+ROWS = (2 * BLOCK, BLOCK, BLOCK // 2)    # what the tests put in ``backbone.WINDOW_ROWS``
+
+
+def trip_bytes(b, window, rows, heads=8):
+    """One trip's float32 scores, as ``backbone._window_rows`` counts them."""
+    return 4 * b * heads * rows * (window + rows)
+
+
+@pytest.fixture
+def trips(monkeypatch):
+    """Trips of 16, 8 or 4 rows (and the tests' block where none of them
+    divides window and sequence), under a budget that all of them fit."""
+    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    monkeypatch.setattr(backbone, "WINDOW_ROWS", ROWS)
+    monkeypatch.setattr(backbone, "WINDOW_TRIP_BYTES", 1 << 30)
+
+
+@pytest.mark.parametrize("kind_,t,window,tight", [
+    ("swa", 4 * BLOCK, 12, False),      # trips of 4 rows: 12 is no multiple of 8 or 16
+    ("swa", 4 * BLOCK, 2 * BLOCK, False),   # W is a trip's rows: two trips of 16
+    ("swa", 4 * BLOCK, 3, False),       # no candidate divides W: the block's rows, four trips
+    ("swa", 2 * BLOCK, 2 * BLOCK, False),   # T <= W: the window hides nothing, no loop
+    ("swa", 4 * BLOCK, 5 * BLOCK, False),
+    ("swa", 4 * BLOCK + 4, 12, False),  # T no multiple of the block: nine trips of 4
+    ("swa", 4 * BLOCK + 4, 5, False),   # nor of any candidate: one trip, the whole square
+    ("swa", 4 * BLOCK, 2 * BLOCK, True),    # no trip fits the budget: the smallest, 4 rows
+    ("gqa", 4 * BLOCK, 12, True),       # the whole-prefix core's blocks recomputed
+    ("gqa", 4 * BLOCK, 12, False),
+    ("gqa", 16 * BLOCK, 12, False),     # past ATTN_MAX_BLOCKS blocks: eight of two blocks' rows
 ])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_blocked_windowed_core_is_the_whole_masked_squares(
-        dtype, kind_, t, window, keep, monkeypatch):
+        dtype, kind_, t, window, tight, trips, monkeypatch):
     """The blocks repeat the masked square's arithmetic forward (a row's
     softmax is over exactly the entries it has there); with bfloat16 operands
     a block's share of ``dk`` and ``dv`` is rounded once a block before the
     float32 sum, so gradients are held to 2 % of their largest entry."""
     cfg, p, h = mixer_inputs(kind_, dtype, t, attn_window=window)
-    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
-    if not keep:
+    if tight:
         monkeypatch.setattr(backbone, "ATTN_KEEP_BYTES", 0)
+        monkeypatch.setattr(backbone, "WINDOW_TRIP_BYTES", 0)
     made, made_grads = mixer_and_gradients(cfg, kind_, p, h)
     monkeypatch.setattr(backbone, "_grouped_core", whole_square_core)
     ref, ref_grads = mixer_and_gradients(cfg, kind_, p, h)
@@ -289,70 +307,193 @@ def test_the_blocked_windowed_core_is_the_whole_masked_squares(
         assert relative(made_grads[name], g) < (1e-5 if dtype == "float32" else 2e-2), name
 
 
-@pytest.mark.parametrize("t,window,rule,lead,computed", [
-    (4 * BLOCK, 12, "window_blocks", 2, 1 + 2 + 3 + 3),
-    (4 * BLOCK, 2 * BLOCK, "window_blocks", 2, 1 + 2 + 3 + 3),
-    (4 * BLOCK, 3, "window_blocks", 1, 1 + 2 + 2 + 2),
-    (8 * BLOCK, 12, "window_blocks", 2, 1 + 2 + 6 * 3),
-    (2 * BLOCK, 5 * BLOCK, "window_blocks", 2, 1 + 2),
-    (BLOCK + 4, 3, "whole", 1, 1),
+def core_and_gradients(core, cfg, b, t, window):
+    """A grouped core's output and its gradients for ``q``, ``k``, ``v``."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v = (jax.random.normal(key, (b, t, heads, 16))
+               for key, heads in zip(keys, (8, 2, 2)))
+    ct = jax.random.normal(keys[3], q.shape)
+    out, vjp = jax.jit(lambda *a: jax.vjp(
+        lambda *a: core(cfg, *a, window, "swa"), *a))(q, k, v)
+    return out, vjp(ct)
+
+
+@pytest.mark.parametrize("t,window,b,rows,budget", [
+    (16 * BLOCK, 4 * BLOCK, 1, 4, trip_bytes(1, 4 * BLOCK, 4)),   # the cell's ratios: T = 4 W = 32 rows
+    (4 * BLOCK, 4 * BLOCK, 1, 0, 1 << 30),      # T = W
+    (2 * BLOCK, 5 * BLOCK, 1, 0, 1 << 30),      # T < W
+    (4 * BLOCK, 12, 1, 4, 1 << 30),             # W a multiple of the smallest candidate alone
+    (6 * BLOCK, 11, 1, BLOCK, 1 << 30),         # W no multiple of a trip's rows (the block's)
+    (3 * BLOCK, 2 * BLOCK - 2, 1, BLOCK, 1 << 30),  # a reach past the sequence's start in every trip
+    (4 * BLOCK + 2, 12, 1, 4 * BLOCK + 2, 1 << 30),     # T no multiple of them: one trip
+    (8 * BLOCK, 2 * BLOCK, 2, BLOCK, trip_bytes(1, 2 * BLOCK, 2 * BLOCK)),   # b = 2 halves the rows
+    (8 * BLOCK, 2 * BLOCK, 1, 2 * BLOCK, trip_bytes(1, 2 * BLOCK, 2 * BLOCK)),
+])
+def test_the_windowed_cores_trips_are_the_whole_masked_square(
+        t, window, b, rows, budget, trips, monkeypatch):
+    """Forward and the gradients for ``q``, ``k`` and ``v`` of the core alone,
+    every block a trip of the one loop (``rows`` 0: the window covers the
+    sequence and the core is the one with no window)."""
+    monkeypatch.setattr(backbone, "WINDOW_TRIP_BYTES", budget)
+    cfg = backbone.afmoe(F, F, compute_dtype="float32", **TINY).cfg
+    with telemetry.span("gordo.test.trace") as attrs:
+        made, made_grads = core_and_gradients(backbone._grouped_core, cfg, b, t, window)
+    if rows:
+        assert attrs["swa_attn_blocks"] == t // rows and attrs["swa_attn_unrolled"] == 0
+        assert backbone._window_rows(t, window, b * 8) == rows
+    else:
+        assert "swa_attn_unrolled" not in attrs and "swa_attn_pairs_in_window" not in attrs
+    ref, ref_grads = core_and_gradients(whole_square_core, cfg, b, t, window)
+    assert relative(made, ref) < 1e-6
+    for g, ref_g in zip(made_grads, ref_grads):
+        assert float(jnp.abs(ref_g).max()) > 0 and relative(g, ref_g) < 1e-5
+
+
+def test_a_leading_trips_later_rows_carry_no_weight_and_no_gradient(trips):
+    """A leading trip's slice of keys and values is clamped to start at row
+    0, so it holds rows after the trip's own: they weigh nothing in its rows'
+    softmax and take no gradient from them, whatever they hold."""
+    cfg = backbone.afmoe(F, F, compute_dtype="float32", **TINY).cfg
+    t, window, rows = 4 * BLOCK, BLOCK, BLOCK       # a trip reads 16 rows: trip 0 holds rows 8..15
+    keys = jax.random.split(jax.random.PRNGKey(12), 3)
+    q, k, v = (jax.random.normal(key, (2, t, heads, 16))
+               for key, heads in zip(keys, (8, 2, 2)))
+    assert backbone._window_rows(t, window, 16) == rows
+    first = jax.jit(lambda q, k, v: backbone._grouped_core(cfg, q, k, v, window, "swa")[:, :rows])
+    out, vjp = jax.vjp(first, q, k, v)
+    dq, dk, dv = vjp(jnp.ones_like(out))
+    assert float(jnp.abs(dk[:, :rows]).max()) > 0 and float(jnp.abs(dv[:, :rows]).max()) > 0
+    assert float(jnp.abs(dk[:, rows:]).max()) == 0 and float(jnp.abs(dv[:, rows:]).max()) == 0
+    assert float(jnp.abs(dq[:, rows:]).max()) == 0
+    loud = first(q, k.at[:, rows:].set(1e4), v.at[:, rows:].set(-1e4))
+    assert np.array_equal(np.asarray(loud), np.asarray(out))
+    # and the first row's softmax is itself alone
+    np.testing.assert_allclose(out[:, 0].reshape(2, 2, 4, 16),
+                               jnp.broadcast_to(v[:, 0, :, None], (2, 2, 4, 16)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("t,window,rule,rows,computed", [
+    (4 * BLOCK, 12, "window_blocks", 4, 8 * 4),
+    (4 * BLOCK, 2 * BLOCK, "window_blocks", 2 * BLOCK, 2 * 2),
+    (4 * BLOCK, 3, "window_blocks", BLOCK, 4 * 2),
+    (8 * BLOCK, 12, "window_blocks", 4, 16 * 4),
+    (2 * BLOCK, 5 * BLOCK, "causal_blocks", BLOCK, 1 + 2),
+    (BLOCK + 4, 3, "whole", BLOCK + 4, 1),
 ])
 def test_the_windowed_core_is_counted_by_the_one_block_rule(
-        t, window, rule, lead, computed, monkeypatch):
-    """The leading blocks are shapes of their own; every block past the
-    window's reach is ONE traced body however many there are."""
+        t, window, rule, rows, computed, trips):
+    """Every block of a windowed core is a trip of ONE traced body however
+    many there are: none is unrolled, none is a shape of its own.  A window
+    that covers the sequence is no window."""
     cfg, p, h = mixer_inputs("swa", "float32", t, attn_window=window)
     counted = telemetry.REGISTRY.get("gordo_gqa_attention_total")
-    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
     rules = ("causal_blocks", "window_blocks", "whole")
     before = {r: counted.value(r) for r in rules}
     with telemetry.span("gordo.test.trace") as attrs:
         text = jax.jit(lambda p, h: backbone.MIXERS["swa"](cfg, p, h)).lower(p, h).as_text()
     assert {r: counted.value(r) - before[r] for r in rules} == {
         r: float(r == rule) for r in rules}
-    n = t // BLOCK if rule != "whole" else 1
+    n = t // rows
     assert attrs["swa_attn_traces"] == 1 and attrs["swa_attn_blocks"] == n
     assert attrs["swa_attn_pairs_computed"] == computed
     assert attrs["swa_attn_pairs_square"] == n * n
-    w = min(window, t)
-    assert attrs["swa_attn_pairs_in_window"] == pytest.approx(
-        (t * w - w * (w - 1) / 2) / (t // n) ** 2)
-    assert attrs["swa_attn_pairs_in_window"] <= computed
     assert "gqa_attn_traces" not in attrs
-    looped = n > lead
-    assert text.count("stablehlo.while") == int(looped)
-    # two key/value heads stay two in every product
+    windowed = window < t
+    if windowed:
+        assert attrs["swa_attn_unrolled"] == 0
+        assert attrs["swa_attn_pairs_in_window"] == pytest.approx(
+            (t * window - window * (window - 1) / 2) / rows ** 2)
+        assert attrs["swa_attn_pairs_in_window"] <= computed
+    else:
+        assert "swa_attn_unrolled" not in attrs and "swa_attn_pairs_in_window" not in attrs
+    assert text.count("stablehlo.while") == int(windowed)
+    # two key/value heads stay two in every product: one trip's two products
+    # and one softmax whatever the trips, as many as blocks where they are
+    # unrolled
     q, k, v = (jnp.zeros((2, t, heads, 16)) for heads in (8, 2, 2))
-    jaxpr = jax.make_jaxpr(
-        lambda q, k, v: backbone._grouped_core(cfg, q, k, v, window, "swa"))(q, k, v)
-    assert len([e for e in jaxpr.eqns if e.primitive.name == "dot_general"]) == 2 * lead
-    # one softmax a leading block, and the loop's one body however many trips
-    monkeypatch.setattr(backbone, "MLA_BLOCK", 4 * t)
-    whole = jax.jit(lambda p, h: backbone.MIXERS["swa"](cfg, p, h)).lower(p, h).as_text()
-    exps = lambda program: program.count("stablehlo.exponential")  # noqa: E731
-    assert exps(text) - exps(whole) == lead + looped - 1
+    core = lambda q, k, v: backbone._grouped_core(cfg, q, k, v, window, "swa")  # noqa: E731
+    assert str(jax.make_jaxpr(core)(q, k, v)).count("dot_general") == (
+        2 if windowed else 2 * n)
+    assert jax.jit(core).lower(q, k, v).as_text().count("stablehlo.exponential") == (
+        1 if windowed else n)
 
 
-def test_at_the_cells_shape_less_than_half_the_square_is_computed(monkeypatch):
-    """8,192 rows in blocks of 512 under a window of 2,048: four leading
-    blocks, twelve trips of one loop, 70 of 256 block pairs for the 56 that
-    window and mask hold; the whole-prefix core takes 8 blocks of 1,024."""
+def test_at_the_cells_shape_less_than_half_the_square_is_computed():
+    """8,192 rows under a window of 2,048, 32 query heads, one sequence:
+    32 trips of 256 rows, each against 2,304 keys: 288 of 1,024 block pairs
+    for the 224 that window and mask hold, none outside the loop."""
     latent = telemetry.REGISTRY.get("gordo_mla_attention_total")
     with telemetry.span("gordo.test.trace") as attrs:
-        spans = backbone._query_blocks(8192, latent, "swa", 2048)
-        full = backbone._query_blocks(8192, latent, "gqa")
-        older = backbone._query_blocks(2048, latent, "mla")
-    assert len(spans) == 16 and spans[4] == (2048, 2560)
-    assert attrs["swa_attn_pairs_computed"] == 10 + 12 * 5 == 70
-    assert attrs["swa_attn_pairs_square"] == 256 and 70 < 256 / 2
+        spans = backbone._query_blocks(8192, latent, "swa", 2048, 32)
+    rows = backbone._window_rows(8192, 2048, 32)
+    assert rows == 256 and spans == [(lo, lo + rows) for lo in range(0, 8192, rows)]
+    assert 4 * 32 * rows * (2048 + rows) <= backbone.WINDOW_TRIP_BYTES < 4 * 32 * 512 * 2560
+    assert attrs["swa_attn_blocks"] == 32 and attrs["swa_attn_unrolled"] == 0
+    assert attrs["swa_attn_pairs_computed"] == 32 * 9 == 288
+    assert attrs["swa_attn_pairs_square"] == 1024 and 288 < 1024 / 2
     assert attrs["swa_attn_pairs_in_window"] == pytest.approx(
-        (8192 * 2048 - 2048 * 2047 / 2) / 512 ** 2)
-    assert len(full) == backbone.ATTN_MAX_BLOCKS == 8 and full[-1] == (7168, 8192)
-    assert attrs["gqa_attn_pairs_computed"] == 36 and attrs["gqa_attn_pairs_square"] == 64
-    assert len(older) == 4 and attrs["mla_attn_pairs_computed"] == 10
-    # what the whole-prefix core would keep for its backward pass, and may not
-    assert 4 * 32 * sum((hi - lo) * hi for lo, hi in full) > backbone.ATTN_KEEP_BYTES
-    assert 4 * 2 * 32 * sum((hi - lo) * hi for lo, hi in older) < backbone.ATTN_KEEP_BYTES
+        (8192 * 2048 - 2048 * 2047 / 2) / rows ** 2) == pytest.approx(224.0, abs=0.02)
+    assert attrs["swa_attn_pairs_computed"] / attrs["swa_attn_pairs_in_window"] < 1.29
+    # what a build's tracing span says of it, and of the three older presets
+    module = backbone.afmoe(50, 50)
+    assert module.window_trips(1, 8192) == {"swa_attn_rows": rows}
+    assert module.window_trips(1, 2048) == {}       # the window covers the sequence
+    for older in (backbone.kimi_linear, backbone.glm_moe_lite, backbone.lfm2_moe):
+        assert older(50, 50).window_trips(1, 8192) == {}
+
+
+@pytest.mark.parametrize("lanes,rows", [
+    (32, 256), (64, 128), (128, 64), (1024, 64), (8, 512)])
+def test_a_trips_rows_follow_the_batch_and_the_heads(lanes, rows):
+    """More sequences or more heads a step: fewer rows a trip, down to the
+    smallest candidate; fewer: the largest."""
+    assert backbone._window_rows(8192, 2048, lanes) == rows
+    assert rows in backbone.WINDOW_ROWS
+
+
+@pytest.mark.parametrize("t,prefix,spans", [
+    (2048, "mla", 4 * [512]),       # the GLM cell's latent cores
+    (1024, "mla", 2 * [512]),       # the Kimi cell's
+    (2048, "gqa", 4 * [512]),       # the LFM2 cell's grouped ones
+    (8192, "gqa", 8 * [1024]),      # the Trinity cell's full layer
+])
+def test_the_cores_with_no_window_take_the_spans_they_took(t, prefix, spans):
+    latent = telemetry.REGISTRY.get("gordo_mla_attention_total")
+    with telemetry.span("gordo.test.trace") as attrs:
+        made = backbone._query_blocks(t, latent, prefix)
+    assert [hi - lo for lo, hi in made] == spans and made[0][0] == 0 and made[-1][1] == t
+    n = len(spans)
+    assert attrs[f"{prefix}_attn_pairs_computed"] == n * (n + 1) // 2
+    assert attrs[f"{prefix}_attn_pairs_square"] == n * n
+    assert not {f"{prefix}_attn_unrolled", f"{prefix}_attn_pairs_in_window"} & set(attrs)
+    if prefix == "gqa":
+        # what the whole-prefix core would keep for its backward pass, and may not
+        kept = 4 * 32 * sum((hi - lo) * hi for lo, hi in made)
+        assert (kept > backbone.ATTN_KEEP_BYTES) == (t == 8192)
+
+
+@pytest.mark.parametrize("core", ["latent", "grouped"])
+def test_the_cores_with_no_window_do_not_read_the_trips_budget(core, monkeypatch):
+    """The jaxpr of ``_causal_core`` and of ``_grouped_core(window=0)`` is
+    the same text whatever the windowed rule's constants are."""
+    monkeypatch.setattr(backbone, "MLA_BLOCK", BLOCK)
+    t = 4 * BLOCK
+    if core == "latent":
+        cfg = backbone.kimi_linear(
+            F, F, compute_dtype="float32", hidden_size=64, num_heads=2, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, num_layers=4).cfg
+        shapes = [(2, t, 2, 24), (2, t, 2, 16), (2, t, 8), (2, t, 2, 16)]
+        traced = lambda *a: jax.vjp(lambda *a: backbone._causal_core(cfg, *a), *a)  # noqa: E731
+    else:
+        cfg = backbone.afmoe(F, F, compute_dtype="float32", **TINY).cfg
+        shapes = [(2, t, 8, 16), (2, t, 2, 16), (2, t, 2, 16)]
+        traced = lambda *a: jax.vjp(lambda *a: backbone._grouped_core(cfg, *a), *a)  # noqa: E731
+    texts = []
+    for budget, rows in ((0, (4,)), (1 << 40, (16, 8))):
+        monkeypatch.setattr(backbone, "WINDOW_TRIP_BYTES", budget)
+        monkeypatch.setattr(backbone, "WINDOW_ROWS", rows)
+        texts.append(str(jax.make_jaxpr(traced)(*(jnp.zeros(s) for s in shapes))))
+    assert texts[0] == texts[1] and texts[0].count("dot_general") >= 8
 
 
 def test_a_latent_core_past_the_most_blocks_takes_longer_ones(monkeypatch):
@@ -584,8 +725,8 @@ def gaps(made, ref):
 def built(tmp_path_factory):
     """Two machines through ``build_project`` with NO ``max_bucket_size``:
     the planner reads the parameter count and puts both in one chunk.  The
-    block is 8 rows, so a sequence is four blocks: two leading ones and two
-    trips of the windowed layers' loop."""
+    block is 8 rows and no candidate of ``WINDOW_ROWS`` divides the window, so
+    a sequence is four trips of the windowed layers' loop, 8 rows each."""
     from gordo_tpu.builder.fleet_build import build_project
     from gordo_tpu.workflow.config import NormalizedConfig
 
@@ -651,7 +792,9 @@ def test_the_counters_the_span_and_the_artifacts_metadata(built):
     assert delta("gordo_mla_attention_total") == 0
     counts = result.timeline[0]["counts"]["enqueue"]
     assert counts["swa_attn_traces"] == windowed and counts["swa_attn_blocks"] == 4 * windowed
-    assert counts["swa_attn_pairs_computed"] == 9 * windowed
+    # four trips, each against the two blocks before its own and its own
+    assert counts["swa_attn_pairs_computed"] == 4 * 3 * windowed
+    assert counts["swa_attn_unrolled"] == 0 and counts["swa_attn_rows"] == BLOCK
     assert counts["swa_attn_pairs_square"] == 16 * windowed
     assert counts["swa_attn_pairs_in_window"] == pytest.approx(
         windowed * (T * WINDOW - WINDOW * (WINDOW - 1) / 2) / BLOCK ** 2)

@@ -566,6 +566,7 @@ def test_the_cores_are_counted_where_the_program_is_traced(
     assert counts["mla_attn_blocks"] == blocks * traced
     assert counts["mla_attn_pairs_computed"] == blocks * (blocks + 1) // 2 * traced
     assert counts["mla_attn_pairs_square"] == blocks * blocks * traced
+    assert not [name for name in counts if name.startswith("swa_attn")]   # no windowed core
     (snapshot,) = telemetry.load_snapshot_dir(os.path.join(out, telemetry.SNAPSHOT_DIR))
     assert "gordo_mla_attention_total" in json.dumps(snapshot)
 

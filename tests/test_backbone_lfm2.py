@@ -540,6 +540,7 @@ def test_the_counters_the_span_and_the_artifacts_metadata(built):
     assert counts["gqa_attn_traces"] == cores and counts["gqa_attn_blocks"] == 4 * cores
     assert counts["gqa_attn_pairs_computed"] == 10 * cores
     assert counts["gqa_attn_pairs_square"] == 16 * cores
+    assert not [name for name in counts if name.startswith("swa_attn")]   # no windowed core
     assert counts["layers_conv"] == 4 and counts["layers_gqa"] == 1
     assert "layers_kda" not in counts and "mtp_depth" not in counts
     assert counts["context"] == T and counts["experts_held"] == 2
